@@ -76,6 +76,7 @@ from repro.core import scale_bank as sb
 from repro.core.quant import QTensor, QuantSpec
 from repro.core.scale_bank import ScaleBank
 from repro.kernels import ops
+from repro.launch.mesh import make_mesh
 from repro.models import registry
 from repro.serve import telemetry
 
@@ -135,9 +136,9 @@ def gemv_roofline(report, check: bool = False) -> bool:
     """Analytic bytes/token of the fused dequant GEMV + single-stream check.
 
     The decode GEMV is memory-bound: per token each packed weight word
-    crosses HBM exactly ONCE (grid (N/bn, K/bk); the qw BlockSpec tiles
-    the word array disjointly — checked below against the kernel's own
-    block arithmetic), plus one pass over the (N, G) scale/zero rows.
+    crosses HBM exactly ONCE (grid (N/bn,), each step one whole K row of
+    words; the qw BlockSpec tiles the word array disjointly — checked
+    below against the kernel's own block arithmetic), plus one pass over the (N, G) scale/zero rows.
     4-bit weights therefore move ~4/16 of the fp16 bytes; the gate
     requires ≥ 3.2× including the scale overhead at group 128.
 
@@ -148,8 +149,7 @@ def gemv_roofline(report, check: bool = False) -> bool:
     gates require the 3-bit plane ratio ≥ 4.2× vs fp16 and ≥ 1.25× fewer
     decode weight-bytes/token than the nibble layout.
     """
-    from repro.kernels.quant_matmul import (DEFAULT_BLOCK_K, DEFAULT_BLOCK_N,
-                                            PACK, PLANE_PACK, aligned_block_k)
+    from repro.kernels.quant_matmul import DEFAULT_BLOCK_N, PACK, PLANE_PACK
     from repro.kernels import quant_matmul as qm
     from repro.kernels import ref as kref
     from repro.core.quant import QuantSpec
@@ -168,10 +168,10 @@ def gemv_roofline(report, check: bool = False) -> bool:
         ratio = fp16_b / q_total
 
         # single-stream invariant from the kernel's own block arithmetic:
-        # the (N/bn, K/bk) grid loads bn*bk/PACK words per tile, disjoint
+        # the (N/bn,) grid loads bn*K/PACK words per step, disjoint
         # tiles, so total word-loads must equal the word count exactly
         bn = min(DEFAULT_BLOCK_N, nn)
-        bk, _, _ = aligned_block_k(kk, min(DEFAULT_BLOCK_K, kk), group)
+        bk = kk
         if nn % bn or kk % bk:
             report(f"kernel/gemv_roofline_{name}", 0.0,
                    f"FAIL blocks ({bn},{bk}) do not tile ({nn},{kk})")
@@ -195,8 +195,7 @@ def gemv_roofline(report, check: bool = False) -> bool:
         q3_total = qw3_b + sz_b + act_b
         ratio3 = fp16_b / q3_total
         plane_vs_nibble = q_total / q3_total
-        bk3, _, _ = aligned_block_k(kk, min(DEFAULT_BLOCK_K, kk), group,
-                                    pack=PLANE_PACK)
+        bk3 = kk
         loads3 = (nn // bn) * (kk // bk3) * (3 * bn * bk3 // PLANE_PACK)
         single3 = loads3 == 3 * nn * kk // PLANE_PACK
         if not single3:
@@ -233,9 +232,10 @@ def gemv_roofline(report, check: bool = False) -> bool:
         metric(f"kernel/gemv_plane_bytes_ratio_{name}", plane_vs_nibble,
                "x_vs_nibble", guard=("higher", 0.1))
 
-    # sanity: the GEMV kernel (interpret mode) is bit-exact vs the
-    # blocked-replay oracle at a small shape — the full sweep lives in
-    # tests/test_gemv.py; this keeps the bench self-checking
+    # sanity: the GEMV kernel (interpret mode) agrees with the
+    # blocked-replay oracle within the f32 summation-order bound at a small
+    # shape — the full sweep lives in tests/test_gemv.py; this keeps the
+    # bench self-checking
     rng = np.random.default_rng(0)
     m, n, k, grp = 4, 128, 256, 64
     spec = QuantSpec(bits=4, group_size=grp)
@@ -246,13 +246,16 @@ def gemv_roofline(report, check: bool = False) -> bool:
     x = jnp.asarray(rng.normal(size=(m, k)).astype(np.float32))
     got = qm.quant_gemv_pallas(x, qw, scale, zero, spec=spec, interpret=True)
     want = kref.quant_gemv_ref(x, qw, scale, zero, (n, k), spec)
-    exact = bool(np.array_equal(np.asarray(got), np.asarray(want)))
+    tol = kref.f32_order_tolerance(x, qw, scale, zero, (n, k), spec)
+    exact = bool(np.all(np.abs(np.asarray(got) - np.asarray(want))
+                        <= np.asarray(tol)))
     if not exact:
         report("kernel/gemv_bitexact", 0.0, "FAIL interpret GEMV != oracle")
         ok = False
     else:
         report("kernel/gemv_bitexact", 0.0,
-               f"interpret GEMV bit-exact vs oracle at ({m},{n},{k},g{grp})")
+               f"interpret GEMV == oracle within the f32 order bound at "
+               f"({m},{n},{k},g{grp})")
     metric("kernel/gemv_bitexact", int(exact), "bool",
            guard=("higher", 0.0))
     return ok
@@ -342,7 +345,7 @@ def sharded_serving(report, check: bool = False) -> bool:
                "--xla_force_host_platform_device_count=8)")
         return not check
     model = 4 if n % 4 == 0 else 2
-    mesh = jax.make_mesh((n // model, model), ("data", "model"))
+    mesh = make_mesh((n // model, model), ("data", "model"))
     ctx = dctx.make_ctx(mesh)
 
     cfg = _serving_cfg()
@@ -461,7 +464,7 @@ def continuous_serving(report, check: bool = False) -> bool:
                "--xla_force_host_platform_device_count=8)")
         return not check
     model = 4 if n % 4 == 0 else 2
-    mesh = jax.make_mesh((n // model, model), ("data", "model"))
+    mesh = make_mesh((n // model, model), ("data", "model"))
     ctx = dctx.make_ctx(mesh)
 
     cfg = _serving_cfg()
@@ -608,7 +611,7 @@ def mixed_task_serving(report, check: bool = False) -> bool:
     n = jax.device_count()
     if n >= 2:
         model = 4 if n % 4 == 0 else 2
-        mesh = jax.make_mesh((n // model, model), ("data", "model"))
+        mesh = make_mesh((n // model, model), ("data", "model"))
         ctx = dctx.make_ctx(mesh)
         mk = lambda: Engine(
             api, jax.device_put(p, shard_rules.named_shardings(ctx, p)),
@@ -766,7 +769,7 @@ def sharded_speculative(report, check: bool = False) -> bool:
                "skipped: 1 device (set XLA_FLAGS="
                "--xla_force_host_platform_device_count=8)")
         return not check
-    mesh = jax.make_mesh((n // 2, 2), ("data", "model"))
+    mesh = make_mesh((n // 2, 2), ("data", "model"))
     ctx = dctx.make_ctx(mesh)
 
     # d_ff=128 (not the off-mesh bench's 96): every quant-group extent must

@@ -11,7 +11,10 @@ Fault-tolerance contract (DESIGN.md §4):
     device_puts with the new shardings;
   * keep-k garbage collection;
   * optional async save (a worker thread serializes the host copy so the
-    train loop never blocks on disk).
+    train loop never blocks on disk);
+  * packed ``qw`` codes are stored with the packing layout version
+    (``core.quant.PACKING_VERSION``); restore refuses any other version —
+    the arrays would load with the right shapes into the wrong columns.
 """
 from __future__ import annotations
 
@@ -24,6 +27,8 @@ from typing import Any, Optional
 
 import jax
 import numpy as np
+
+from repro.core.quant import PACKING_VERSION
 
 
 def _flatten(tree, prefix=""):
@@ -91,7 +96,8 @@ class CheckpointManager:
         with open(payload, "rb") as f:
             checksum = zlib.crc32(f.read())
         manifest = {"step": step, "checksum": checksum,
-                    "keys": sorted(flat.keys()), "extra": extra}
+                    "keys": sorted(flat.keys()), "extra": extra,
+                    "qw_packing": PACKING_VERSION}
         mpath = os.path.join(tmp, "manifest.json")
         with open(mpath, "w") as f:
             json.dump(manifest, f)
@@ -143,6 +149,14 @@ class CheckpointManager:
         base = os.path.join(self.dir, f"step_{step:08d}")
         with open(os.path.join(base, "manifest.json")) as f:
             manifest = json.load(f)
+        # manifests from before the version was recorded used layout 1
+        packing = manifest.get("qw_packing", 1)
+        if packing != PACKING_VERSION and any(
+                k.rsplit("/", 1)[-1] == "qw" for k in manifest["keys"]):
+            raise ValueError(
+                f"checkpoint {base} stores packed qw codes in layout "
+                f"{packing}; this code reads layout {PACKING_VERSION} "
+                f"(core.quant) — quantize again from the float weights")
         arrays = dict(np.load(os.path.join(base, "arrays.npz")))
         tree = _unflatten_into(like, arrays)
         return tree, manifest["extra"] | {"step": manifest["step"]}

@@ -84,7 +84,7 @@ def _block_linear_inputs(layer_p: dict, h: jax.Array, cfg: ModelConfig):
     o = ops.attention(q, k, v, causal=True, window=cfg.swa_window)
     o = o.reshape(b, s, cfg.n_heads * cfg.d_head)
     captures["attn/wo"] = o
-    h = h + linear.apply(layer_p["attn"]["wo"], o, spec)
+    h = h + linear.apply(layer_p["attn"]["wo"], o, spec, row_parallel=True)
     hin = common.norm_apply(layer_p["ln2"], h, cfg)
     captures["mlp/up"] = captures["mlp/gate"] = hin
     up = linear.apply(layer_p["mlp"]["up"], hin, spec)
@@ -94,7 +94,7 @@ def _block_linear_inputs(layer_p: dict, h: jax.Array, cfg: ModelConfig):
     else:
         act = jax.nn.gelu(up)
     captures["mlp/down"] = act
-    h = h + linear.apply(layer_p["mlp"]["down"], act, spec)
+    h = h + linear.apply(layer_p["mlp"]["down"], act, spec, row_parallel=True)
     return captures, h
 
 

@@ -28,6 +28,18 @@ Packing — two layouts:
     bits/weight from HBM, and the top-p planes ``qw[:p]`` are, standing
     alone, the p-bit truncation of every code: a low-bit *draft* reads a
     contiguous prefix of the target's buffer — zero extra weight memory.
+
+Both layouts place codes in words by TILES along K (``tile_words``): a tile
+is ``tw`` consecutive words of a row, and field ``i`` of word ``w`` in tile
+``t`` holds column ``(t·F + i)·tw + w`` (F fields per word: 8 nibbles or 32
+bits).  So one field of a tile's words is a run of ``tw`` consecutive
+columns — the TPU kernel unpacks it with a shift and a mask into a
+lane-dense (N, tw) block, with no reshape across the lane dim.
+
+A shard of ``qw`` cut along its word axis holds a contiguous run of columns
+packed exactly as that narrower row would be only when it holds whole tiles
+(``splits_on_tiles``).  Code that reads such shards on their own (a K-split
+kernel, MoE's ``shard_map``) checks it first.
 """
 from __future__ import annotations
 
@@ -45,6 +57,23 @@ PACK = 8
 
 # Codes per uint32 word per bit-plane (one bit per code per plane).
 PLANE_PACK = 32
+
+# TPU vector lane width: a packing tile is this many words when a row's
+# word count divides into such tiles.
+LANES = 128
+
+# Version of the packed ``qw`` layout, recorded by checkpoints: 1 put codes
+# in words in column order (word w held columns F·w … F·w + F − 1); 2 is
+# the tile layout above.  Same shapes and dtypes, different columns.
+PACKING_VERSION = 2
+
+
+def tile_words(k: int, fields: int) -> int:
+    """Words per packing tile of a K-wide row with ``fields`` codes/word:
+    one lane width (128) when the row's K/fields words split into such
+    tiles, else the whole row."""
+    words = k // fields
+    return LANES if words % LANES == 0 else words
 
 
 @dataclasses.dataclass(frozen=True)
@@ -83,6 +112,12 @@ class QuantSpec:
             )
         return in_features // self.group_size
 
+    @property
+    def fields(self) -> int:
+        """Codes per stored ``qw`` element along K: 32 (plane), 8 (nibble)
+        or 1 (unpacked uint8 codes)."""
+        return PLANE_PACK if self.plane else PACK if self.packs else 1
+
     def validate(self, in_features: int) -> None:
         if not (2 <= self.bits <= 8):
             raise ValueError(f"bits must be in [2, 8], got {self.bits}")
@@ -101,21 +136,66 @@ class QuantSpec:
 # Pack / unpack (bijective on codes in [0, 15])
 # ---------------------------------------------------------------------------
 
+def pack_fields(q: jax.Array, fields: int, width: int, tw: int) -> jax.Array:
+    """Pack codes (…, K) into uint32 words (…, K // fields), tile by tile:
+    field ``i`` (bits ``[i·width, (i+1)·width)``) of word ``w`` in tile
+    ``t`` holds column ``(t·fields + i)·tw + w``.  ``K`` must be a multiple
+    of ``fields·tw``."""
+    lead, k = q.shape[:-1], q.shape[-1]
+    q = q.astype(jnp.uint32).reshape(*lead, k // (fields * tw), fields, tw)
+    shifts = (jnp.arange(fields, dtype=jnp.uint32) * width)[:, None]
+    words = jnp.sum(q << shifts, axis=-2, dtype=jnp.uint32)
+    return words.reshape(*lead, k // fields)
+
+
+def unpack_fields(words: jax.Array, fields: int, width: int,
+                  tw: int) -> jax.Array:
+    """Inverse of ``pack_fields``: uint32 (…, W) → uint32 codes
+    (…, W·fields)."""
+    lead, w = words.shape[:-1], words.shape[-1]
+    t = words.reshape(*lead, w // tw, 1, tw)
+    shifts = (jnp.arange(fields, dtype=jnp.uint32) * width)[:, None]
+    q = (t >> shifts) & jnp.uint32((1 << width) - 1)
+    return q.reshape(*lead, w * fields)
+
+
+def splits_on_tiles(k: int, spec: QuantSpec, parts: int) -> bool:
+    """Whether cutting the word axis of a K-wide ``qw`` into ``parts``
+    equal shards gives each shard whole tiles — i.e. shard j is exactly the
+    packed form of columns [j·K/parts, (j+1)·K/parts) as ``pack_codes``
+    would pack a K/parts-wide row."""
+    f = spec.fields
+    if (k // f) % parts:
+        return False
+    return f == 1 or (k // f // parts) % tile_words(k, f) == 0
+
+
+def local_columns(qw: jax.Array, spec: QuantSpec, index, parts: int):
+    """Columns [index·K/parts, (index+1)·K/parts) of whole packed rows
+    ``qw``, repacked as that narrower row would be packed.  ``index`` may
+    be traced (``lax.axis_index``) — for packed shards that do not split
+    on tiles (``splits_on_tiles``)."""
+    f = spec.fields
+    width = 1 if spec.plane else 4
+    k = qw.shape[-1] * f
+    kl = k // parts
+    cols = unpack_fields(qw, f, width, tile_words(k, f))
+    cols = jax.lax.dynamic_slice_in_dim(cols, index * kl, kl, cols.ndim - 1)
+    return pack_fields(cols, f, width, tile_words(kl, f))
+
+
 def pack_codes(q: jax.Array) -> jax.Array:
     """Pack uint codes (…, K) with values < 16 into uint32 (…, K // 8)."""
-    if q.shape[-1] % PACK:
-        raise ValueError(f"last dim {q.shape[-1]} not divisible by {PACK}")
-    q = q.astype(jnp.uint32)
-    q = q.reshape(*q.shape[:-1], q.shape[-1] // PACK, PACK)
-    shifts = jnp.arange(PACK, dtype=jnp.uint32) * 4
-    return jnp.sum(q << shifts, axis=-1, dtype=jnp.uint32)
+    k = q.shape[-1]
+    if k % PACK:
+        raise ValueError(f"last dim {k} not divisible by {PACK}")
+    return pack_fields(q, PACK, 4, tile_words(k, PACK))
 
 
 def unpack_codes(packed: jax.Array, k: Optional[int] = None) -> jax.Array:
     """Unpack uint32 (…, K//8) → uint8 codes (…, K)."""
-    shifts = jnp.arange(PACK, dtype=jnp.uint32) * 4
-    q = (packed[..., None] >> shifts) & jnp.uint32(0xF)
-    q = q.reshape(*packed.shape[:-1], packed.shape[-1] * PACK)
+    kk = packed.shape[-1] * PACK
+    q = unpack_fields(packed, PACK, 4, tile_words(kk, PACK))
     if k is not None:
         q = q[..., :k]
     return q.astype(jnp.uint8)
@@ -129,23 +209,20 @@ def pack_codes_planes(q: jax.Array, bits: int) -> jax.Array:
     """Pack uint codes (…, K) < 2**bits into uint32 planes (bits, …, K//32).
 
     Plane p holds bit ``bits-1-p`` of every code (most significant first),
-    32 codes per word, code ``i`` in bit ``i`` of its word.  The layout is
+    32 codes per word, placed by ``pack_fields`` tiles.  The layout is
     chosen so the top-p planes are a contiguous buffer prefix AND decode,
     on their own, to ``code >> (bits-p)`` — the p-bit truncation a low-bit
     draft serves under rescaled (scale, zero).
     """
-    if q.shape[-1] % PLANE_PACK:
-        raise ValueError(
-            f"last dim {q.shape[-1]} not divisible by {PLANE_PACK}")
+    k = q.shape[-1]
+    if k % PLANE_PACK:
+        raise ValueError(f"last dim {k} not divisible by {PLANE_PACK}")
     q = q.astype(jnp.uint32)
     # (bits, …, K): bit bits-1-p of each code
     sel = jnp.arange(bits, dtype=jnp.uint32)[::-1]
     sel = sel.reshape((bits,) + (1,) * q.ndim)
     planes = (q[None] >> sel) & jnp.uint32(1)
-    planes = planes.reshape(bits, *q.shape[:-1], q.shape[-1] // PLANE_PACK,
-                            PLANE_PACK)
-    shifts = jnp.arange(PLANE_PACK, dtype=jnp.uint32)
-    return jnp.sum(planes << shifts, axis=-1, dtype=jnp.uint32)
+    return pack_fields(planes, PLANE_PACK, 1, tile_words(k, PLANE_PACK))
 
 
 def unpack_codes_planes(packed: jax.Array, k: Optional[int] = None,
@@ -155,9 +232,9 @@ def unpack_codes_planes(packed: jax.Array, k: Optional[int] = None,
     ``bits`` (≤ bits') consumes only the top planes — the draft decode.
     """
     bits = packed.shape[0] if bits is None else bits
-    shifts = jnp.arange(PLANE_PACK, dtype=jnp.uint32)
-    b = (packed[:bits, ..., None] >> shifts) & jnp.uint32(1)
-    b = b.reshape(bits, *packed.shape[1:-1], packed.shape[-1] * PLANE_PACK)
+    kk = packed.shape[-1] * PLANE_PACK
+    b = unpack_fields(packed[:bits], PLANE_PACK, 1,
+                      tile_words(kk, PLANE_PACK))
     weight = jnp.arange(bits, dtype=jnp.uint32)[::-1]
     weight = weight.reshape((bits,) + (1,) * (b.ndim - 1))
     q = jnp.sum(b << weight, axis=0, dtype=jnp.uint32)

@@ -1,22 +1,24 @@
-"""XLA backend configuration: platform select, fake devices, overlap flags.
+"""XLA backend configuration: platform select, fake devices, overlap flags,
+and the persistent compilation cache.
 
 One place for the process-level knobs every launcher otherwise hand-rolls
 (the bayespec ``config.py`` pattern): pick the platform, fake a multi-device
-host mesh on CPU, and turn on the XLA flags that let collectives overlap
-with compute on GPU.  All of it is env-var plumbing that must land BEFORE
-the jax backend initializes (first ``jax.devices()``/computation), so this
-module imports jax lazily — importing it is always safe, even ahead of the
-env setup it performs.
+host mesh on CPU, turn on the XLA flags that let collectives overlap with
+compute on GPU, and place the compile cache.  The env-var plumbing must land
+BEFORE the jax backend initializes (first ``jax.devices()``/computation) —
+flags set later do not take effect — so this module imports jax lazily:
+importing it is always safe, even ahead of the env setup it performs.
 
 Typical launcher prologue::
 
     from repro.dist import backend
     backend.configure(fake_devices=os.environ.get("REPRO_FAKE_DEVICES"))
+    backend.enable_compile_cache()
 """
 from __future__ import annotations
 
 import os
-import warnings
+from pathlib import Path
 from typing import Dict, Optional
 
 # Latency-hiding flags for GPU meshes: run collectives on their own async
@@ -33,14 +35,8 @@ GPU_OVERLAP_FLAGS = (
 PLATFORMS = ("cpu", "gpu", "tpu")
 
 
-def _backend_initialized() -> bool:
-    """True once jax has brought a backend up (env flags no longer apply)."""
-    import jax
-
-    try:
-        return jax._src.xla_bridge._backends != {}  # noqa: SLF001
-    except AttributeError:  # jax moved the registry: be conservative
-        return False
+# the repository checkout this module lives in (src/repro/dist/backend.py)
+CHECKOUT = Path(__file__).resolve().parents[3]
 
 
 def _append_xla_flags(*flags: str) -> None:
@@ -49,11 +45,6 @@ def _append_xla_flags(*flags: str) -> None:
     fresh = [f for f in flags if f.split("=")[0] not in current]
     if not fresh:
         return
-    if _backend_initialized():
-        warnings.warn(
-            "XLA backend already initialized; flags "
-            f"{fresh} will not take effect this process",
-            RuntimeWarning, stacklevel=3)
     os.environ["XLA_FLAGS"] = " ".join(filter(None, [current, *fresh]))
 
 
@@ -101,6 +92,24 @@ def configure(*, platform: Optional[str] = None,
         fake_host_devices(int(fake_devices))
     if gpu_overlap if gpu_overlap is not None else platform == "gpu":
         enable_gpu_overlap()
+
+
+def enable_compile_cache() -> str:
+    """Keep compiled programs in a persistent cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing here overrides it.  Otherwise the cache is ``<checkout>/.jax_cache``
+    — a fixed path, since the cache key depends on it.  Call before the
+    first compile.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    path = str(CHECKOUT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def summary() -> Dict:

@@ -20,7 +20,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -95,4 +95,4 @@ def pipeline_apply(layer_fn, stacked_ws, x: jax.Array, mesh,
         lambda l: P(axis_name, *([None] * (l.ndim - 1))), stacked_ws)
     x_spec = P(*([None] * x.ndim))
     return shard_map(stage_fn, mesh=mesh, in_specs=(w_specs, x_spec),
-                     out_specs=x_spec, check_rep=False)(stacked_ws, x)
+                     out_specs=x_spec, check_vma=False)(stacked_ws, x)

@@ -35,7 +35,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -77,7 +77,7 @@ def shard_argmax(ctx, batch: int):
         return shard_map(
             partial(_local_argmax, axis=ctx.model_axis, vocab=lg.shape[-1]),
             mesh=ctx.mesh, in_specs=P(ba, ctx.model_axis),
-            out_specs=P(ba), check_rep=False)(lg)
+            out_specs=P(ba), check_vma=False)(lg)
     return sample
 
 
@@ -174,7 +174,7 @@ def shard_sample(ctx, batch: int, temperature: float):
                     vocab=lg.shape[-1], temperature=float(temperature)),
             mesh=ctx.mesh,
             in_specs=(P(ba, ctx.model_axis), P()),
-            out_specs=P(ba), check_rep=False)(lg, key)
+            out_specs=P(ba), check_vma=False)(lg, key)
     return sample
 
 
@@ -308,7 +308,7 @@ def shard_top_p(ctx, batch: int, p: float, temperature: float = 1.0):
                     temperature=float(temperature)),
             mesh=ctx.mesh,
             in_specs=(P(ba, ctx.model_axis), P()),
-            out_specs=P(ba), check_rep=False)(lg, key)
+            out_specs=P(ba), check_vma=False)(lg, key)
     return sample
 
 
@@ -325,4 +325,4 @@ def shard_topk(ctx, batch: int, k: int):
         mesh=ctx.mesh,
         in_specs=P(ba, ctx.model_axis),
         out_specs=(P(ba), P(ba)),
-        check_rep=False)
+        check_vma=False)

@@ -16,7 +16,12 @@ The PEQA-specific part (docs/DIST.md has the full table):
   * Row-parallel linears (wo/down/out_proj) shard the INPUT (contraction)
     dim — the last dim of both ``w`` (out, in) and ``qw`` (out, in/8)
     (4-bit codes pack 8-per-uint32 along the input dim, so the packed
-    extent still divides any axis the fp extent divides).  Their
+    extent still divides any axis the fp extent divides).  Codes are
+    packed in tiles along K (core.quant), so a ``qw`` shard is the packed
+    form of a contiguous column range only when it holds whole tiles
+    (``quant.splits_on_tiles``): GSPMD's global view does not care, but
+    code that reads shards on their own (``ops._kernel_matmul``, MoE's
+    ``shard_map``) checks it.  Their
     ``scale``/``zero`` however are (out, G) — per-OUTPUT-row groups with no
     input dim to slice — so they replicate; at G ≤ in/group_size per row
     this is the cheapest correct layout and keeps the dequant epilogue

@@ -29,9 +29,11 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
-from repro.core.quant import (PACK, QuantSpec, unpack_codes,
-                              unpack_codes_planes)
+from repro.core import quant
+from repro.core.quant import QuantSpec, unpack_codes, unpack_codes_planes
+from repro.dist import context as dctx
 from repro.kernels import ref as _ref
 
 _tls = threading.local()
@@ -100,22 +102,61 @@ def _dequant(qw, scale, zero, k, spec: QuantSpec, dtype):
     return w.reshape(n, k).astype(dtype)
 
 
+def _kernel_matmul(x2d, qw, scale, zero, task_ids, spec: QuantSpec,
+                   interpret: bool, row_parallel: bool = False):
+    """The Pallas path: GEMV for decode-shaped calls, GEMM otherwise.
+
+    Inside a mesh scope (``dist.context.use_mesh``) the call runs under
+    ``shard_map``, because XLA cannot partition a Mosaic kernel.  A
+    row-parallel weight (``wo``/``down``, stored K-sharded) is contracted
+    where it lies when its word shards hold whole packing tiles
+    (``quant.splits_on_tiles``): each model shard multiplies its activation
+    columns by its codes and one f32 ``psum`` adds the partial outputs.
+    Any other weight is split by output columns from the full activation
+    rows: column-parallel weights are stored that way, and a row-parallel
+    one whose K split would cut a tile is resharded to it on entry.
+    """
+    from repro.kernels import quant_matmul as _qm
+
+    def call(x, w, s, z, *tid, out_dtype=None):
+        kern = (_qm.quant_gemv_pallas if x.shape[0] <= GEMV_MAX_M
+                else _qm.quant_matmul_pallas)
+        return kern(x, w, s, z, task_ids=tid[0] if tid else None,
+                    spec=spec, out_dtype=out_dtype, interpret=interpret)
+
+    args = (x2d, qw, scale, zero) + (() if task_ids is None else (task_ids,))
+    ctx = dctx.current()
+    if ctx is None:
+        return call(*args)
+    m, parts = ctx.model_axis, ctx.model_size
+    rows = ctx.batch_axes(x2d.shape[0])
+    qlead = [None] * (qw.ndim - 2)
+    slead = [None] * (scale.ndim - 2)
+    groups = scale.shape[-1]
+    if (row_parallel and quant.splits_on_tiles(x2d.shape[-1], spec, parts)
+            and (groups == 1 or groups % parts == 0)):
+        sz = P(*slead, None, m if groups > 1 else None)
+        specs = (P(rows, m), P(*qlead, None, m), sz, sz, P(rows))
+
+        def partial_sums(*a):
+            return jax.lax.psum(call(*a, out_dtype=jnp.float32), m)
+        y = jax.shard_map(partial_sums, mesh=ctx.mesh,
+                          in_specs=specs[:len(args)], out_specs=P(rows, None),
+                          check_vma=False)(*args)
+        return y.astype(x2d.dtype)
+    cols = m if qw.shape[-2] % parts == 0 else None
+    sz = P(*slead, cols, None)
+    specs = (P(rows, None), P(*qlead, cols, None), sz, sz, P(rows))
+    return jax.shard_map(call, mesh=ctx.mesh, in_specs=specs[:len(args)],
+                         out_specs=P(rows, cols), check_vma=False)(*args)
+
+
 def _qmm_fwd_impl(x2d, qw, scale, zero, spec: QuantSpec, impl: str,
-                  bf16_reduce: bool = False):
+                  bf16_reduce: bool = False, row_parallel: bool = False):
     k = x2d.shape[-1]
     if impl in ("pallas", "interpret"):
-        from repro.kernels import quant_matmul as _qm
-
-        interp = impl == "interpret"
-        if x2d.shape[0] <= GEMV_MAX_M and (spec.packs or spec.plane):
-            return _qm.quant_gemv_pallas(
-                x2d, qw, scale.astype(jnp.float32), zero.astype(jnp.float32),
-                spec=spec, interpret=interp,
-            )
-        return _qm.quant_matmul_pallas(
-            x2d, qw, scale.astype(jnp.float32), zero.astype(jnp.float32),
-            spec=spec, interpret=interp,
-        )
+        return _kernel_matmul(x2d, qw, scale, zero, None, spec,
+                              impl == "interpret", row_parallel)
     if impl == "ref":
         n = qw.shape[1] if spec.plane else qw.shape[0]
         return _ref.quant_matmul_ref(x2d, qw, scale, zero, (n, k), spec)
@@ -127,17 +168,20 @@ def _qmm_fwd_impl(x2d, qw, scale, zero, spec: QuantSpec, impl: str,
     ).astype(x2d.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _qmm(x2d, qw, scale, zero, spec: QuantSpec, impl: str, bf16_reduce: bool):
-    return _qmm_fwd_impl(x2d, qw, scale, zero, spec, impl, bf16_reduce)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _qmm(x2d, qw, scale, zero, spec: QuantSpec, impl: str, bf16_reduce: bool,
+         row_parallel: bool):
+    return _qmm_fwd_impl(x2d, qw, scale, zero, spec, impl, bf16_reduce,
+                         row_parallel)
 
 
-def _qmm_fwd(x2d, qw, scale, zero, spec, impl, bf16_reduce):
-    y = _qmm_fwd_impl(x2d, qw, scale, zero, spec, impl, bf16_reduce)
+def _qmm_fwd(x2d, qw, scale, zero, spec, impl, bf16_reduce, row_parallel):
+    y = _qmm_fwd_impl(x2d, qw, scale, zero, spec, impl, bf16_reduce,
+                      row_parallel)
     return y, (x2d, qw, scale, zero)
 
 
-def _qmm_bwd(spec, impl, bf16_reduce, res, dy):
+def _qmm_bwd(spec, impl, bf16_reduce, row_parallel, res, dy):
     x2d, qw, scale, zero = res
     k = x2d.shape[-1]
     n = qw.shape[1] if spec.plane else qw.shape[0]
@@ -173,9 +217,12 @@ def quant_matmul(
     *,
     impl: Optional[str] = None,
     bf16_reduce: bool = False,
+    row_parallel: bool = False,
 ) -> jax.Array:
     """y = x @ Ŵᵀ for arbitrary leading batch dims on x.  Differentiable in
-    (x, scale, zero); integer codes are frozen."""
+    (x, scale, zero); integer codes are frozen.  ``row_parallel`` marks a
+    weight stored K-sharded on a mesh (``dist.sharding.ROW_PARALLEL``);
+    only the Pallas path reads it (``_kernel_matmul``)."""
     impl = _check_impl(impl or default_impl())
     lead = x.shape[:-1]
     k = x.shape[-1]
@@ -188,7 +235,8 @@ def quant_matmul(
             preferred_element_type=None if bf16_reduce else jnp.float32,
         ).astype(x2d.dtype)
     else:
-        y = _qmm(x2d, qw, scale, zero, spec, impl, bf16_reduce)
+        y = _qmm(x2d, qw, scale, zero, spec, impl, bf16_reduce,
+                 row_parallel)
     return y.reshape(*lead, y.shape[-1])
 
 
@@ -202,6 +250,7 @@ def quant_matmul_slotted(
     *,
     impl: Optional[str] = None,
     bf16_reduce: bool = False,
+    row_parallel: bool = False,
 ) -> jax.Array:
     """Mixed-task y[i] = x[i] @ Ŵ(task_ids[i])ᵀ — forward-only (serving).
 
@@ -221,13 +270,8 @@ def quant_matmul_slotted(
         raise ValueError(
             f"task_ids has {task_ids.shape[0]} rows for {x2d.shape[0]} slots")
     if impl in ("pallas", "interpret"):
-        from repro.kernels.quant_matmul import quant_gemv_pallas
-
-        y = quant_gemv_pallas(
-            x2d, qw, scale_stack.astype(jnp.float32),
-            zero_stack.astype(jnp.float32), task_ids=task_ids, spec=spec,
-            interpret=(impl == "interpret"),
-        )
+        y = _kernel_matmul(x2d, qw, scale_stack, zero_stack, task_ids, spec,
+                           impl == "interpret", row_parallel)
     elif impl == "ref":
         y = _ref.quant_matmul_tasks_ref(
             x2d, qw, scale_stack, zero_stack, task_ids, (n, k), spec)

@@ -5,31 +5,41 @@ layers are memory-bound at generation time; streaming b-bit codes instead of
 16-bit weights cuts HBM traffic ~16/b×.  GPU implementations (OPTQ, AWQ,
 LUT-GEMM) use CUDA GEMV kernels; the TPU-native adaptation is:
 
-  HBM → VMEM : packed uint32 code blocks (bn, bk/8) + per-group scales/zeros
-  VMEM → VREG: unpack nibbles with vector shifts/ands on the 8×128 VPU
-  VREG → MXU : dequantized f32 tile (bn, bk) feeds the 128×128 systolic MXU
+  HBM → VMEM : packed code words (bn, K/8) + per-group scales/zeros (G, bn)
+  VMEM → VREG: one shift + mask per field gives a lane-dense (bn, tw) block
+               of integer codes (core.quant tile layout — no lane reshape)
+  VREG → MXU : the codes, exact in the activation dtype, are contracted
+               with the matching activation columns; the per-group affine
+               map is applied to the dot's output (below)
 
-LUT-GEMM's warp-shuffle LUT broadcast has no TPU analogue — plain
-unpack+scale on the VPU is the idiomatic equivalent (DESIGN.md §3).
+Per group ``g`` of columns, with Ŵ = s·(q − z)::
 
-Two kernel shapes share the tile math (docs/KERNELS.md):
+    x·Ŵᵀ  =  Σ_g  s_g · (x_g·q_gᵀ − z_g · Σ_{k∈g} x_k)
 
-  * ``quant_matmul_pallas`` — GEMM, grid (M/bm, N/bn, K/bk), K innermost,
-    f32 accumulator in VMEM scratch across the K loop.
-  * ``quant_gemv_pallas``  — decode-shaped GEMV, grid (N/bn, K/bk): M is the
-    slot count (≤ ~32), so the whole (M, bk) activation block stays
-    VMEM-resident and each packed ``qw`` word is streamed from HBM exactly
-    once per token.  An optional ``task_ids: (M,) int32`` operand (scalar-
-    prefetched into SMEM) selects, per slot, one row of (T, N, G)-stacked
-    scales/zeros *inside* the tile loop — slots decoding different PEQA
-    tasks share one kernel launch.
+so the MXU only ever multiplies small integers (exact in bf16) by the
+activations, with f32 accumulation, and the scale/zero epilogue runs on
+(M, bn) outputs.  Scales and zeros enter transposed, (G, N), so a group's
+scale row is a lane-dense (1, bn) vector that broadcasts over the rows.
+With bf16 activations this is one MXU pass at full f32-accumulation
+accuracy; f32 activations take the f32 (HIGHEST) contraction.
 
-K blocks are picked by ``aligned_block_k``: the largest pack- and
-group-aligned divisor of K at most ``block_k``.  When a quant group itself
-exceeds ``block_k`` (per-channel scales on a large-K layer), the group is
-split across ``blocks_per_group`` K-blocks instead of blowing VMEM with a
-single K block — Ŵ = s·(q − z) is linear in the K-sum, so a group may
-straddle block boundaries exactly.
+One kernel body serves both shapes (docs/KERNELS.md):
+
+  * ``quant_matmul_pallas`` — GEMM, grid (M/bm, N/bn).
+  * ``quant_gemv_pallas``  — decode-shaped GEMV: a single M block, so the
+    whole (M, K) activation block stays VMEM-resident and each packed
+    ``qw`` word is streamed from HBM exactly once per call.
+
+Each grid step holds a whole K row of codes (no K grid axis): a (bn, K/8)
+word block is 512 KiB at K = 8192, and the codes are unpacked one
+(bn, tw) field at a time, so no (bn, K) tile is ever materialised.
+
+Both take an optional ``task_ids: (M,) int32``; scales/zeros are then
+(T, N, G)-stacked and row i of the output reads task ``task_ids[i]``'s
+scales — slots decoding different PEQA tasks share one launch, and a
+prompt admitted for a resident task prefills without a scale swap.  A row
+of the stacked call is bitwise the plain call under that task's scales:
+the codes' dot is shared and the epilogue's arithmetic is the same.
 
 Two storage layouts share the tile loop:
 
@@ -40,8 +50,7 @@ Two storage layouts share the tile loop:
     tensor streams exactly b bits/weight, and a ``spec.bits = p`` view of
     a wider buffer loads only the top-p planes (the BlockSpec's plane axis
     is a prefix slice) — the zero-copy low-bit DRAFT behind
-    self-speculative decoding.  The single-stream invariant holds per
-    plane: each consumed word crosses HBM exactly once.
+    self-speculative decoding.
 """
 from __future__ import annotations
 
@@ -51,323 +60,187 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.quant import PACK, PLANE_PACK, QuantSpec
+from repro.core.quant import PACK, PLANE_PACK, QuantSpec, tile_words
 
 DEFAULT_BLOCK_M = 128
 DEFAULT_BLOCK_N = 128
-DEFAULT_BLOCK_K = 512
 
 
-def aligned_block_k(k: int, block_k: int, group: int,
-                    packs: bool = True, pack: int | None = None) -> tuple:
-    """K-block size for the dequant kernels.
+def _rows(ref, g: int, tids, n_tasks: int):
+    """Group ``g``'s scale (or zero) row: (1, bn), or (bm, bn) with each
+    output row taking its task's row when ``tids`` is given."""
+    row = ref[0, g:g + 1, :]
+    if tids is None:
+        return row
+    sel = jnp.broadcast_to(row, (tids.shape[0], row.shape[1]))
+    for t in range(1, n_tasks):
+        sel = jnp.where(tids == t, ref[t, g:g + 1, :], sel)
+    return sel
 
-    Returns ``(bk, groups_per_blk, blocks_per_group)`` with ``bk | k`` and
-    ``bk`` a multiple of the pack word (``pack`` codes — 8 for nibbles,
-    32 for bit-planes, overriding the ``packs`` bool when given):
 
-      * group fits a block → bk = largest multiple of lcm(group, pack) that
-        divides k and is ≤ block_k (groups_per_blk ≥ 1, blocks_per_group 1);
-      * group exceeds block_k (per-channel scales, large K) → the group is
-        split: bk = largest pack-aligned divisor of the group ≤ block_k
-        (groups_per_blk 1, blocks_per_group = group // bk).
+def qmm_tile(x, qw, scale_t, zero_t, tids, *, group: int, plane_bits: int):
+    """One (bm, bn) output tile of x @ Ŵᵀ in f32.
 
-    The old behaviour — falling back to ``bk = k`` whenever ``k % bk`` —
-    made large-K layers allocate a full-K VMEM tile.
+    ``x``: (bm, K); ``qw``: int32 words (bn, K/8), or (bits, bn, K/32)
+    planes of which the first ``plane_bits`` are read; ``scale_t`` /
+    ``zero_t``: (T, G, bn); ``tids``: (bm, 1) int32 or None.  Every operand
+    may be a Pallas ref or a plain array — the kernel and the blocked
+    replay oracle (ref.quant_gemv_ref) run this same function.
     """
-    if pack is None:
-        pack = PACK if packs else 1
-    unit = group * pack // math.gcd(group, pack)         # lcm(group, pack)
-    if unit <= block_k:
-        bk = max(c for c in range(unit, block_k + 1, unit) if k % c == 0)
-        return bk, bk // group, 1
-    divs = [c for c in range(pack, block_k + 1, pack) if group % c == 0]
-    bk = max(divs) if divs else group
-    return bk, 1, group // bk
+    k = x.shape[1]
+    fields, width = (PLANE_PACK, 1) if plane_bits else (PACK, 4)
+    tw = tile_words(k, fields)
+    piece = math.gcd(group, tw)       # columns that share one group
+    mask = (1 << width) - 1
+    n_tasks = scale_t.shape[0]
+    xdt = x.dtype
+    prec = (jax.lax.Precision.HIGHEST if xdt == jnp.float32
+            else jax.lax.Precision.DEFAULT)
+    if tids is not None:
+        tids = tids[...]
+    bm = x.shape[0]
+    bn = qw.shape[-2]
+
+    y = jnp.zeros((bm, bn), jnp.float32)
+    acc = jnp.zeros((bm, bn), jnp.float32)
+    xs = jnp.zeros((bm, 1), jnp.float32)
+    cur = 0
+    for t in range(k // (fields * tw)):
+        if plane_bits:
+            words = [qw[p, :, t * tw:(t + 1) * tw] for p in range(plane_bits)]
+        else:
+            words = qw[:, t * tw:(t + 1) * tw]
+        for i in range(fields):
+            if plane_bits:
+                codes = sum(((w >> i) & 1) << (plane_bits - 1 - p)
+                            for p, w in enumerate(words))
+            else:
+                codes = (words >> (width * i)) & mask
+            codes = codes.astype(jnp.float32).astype(xdt)
+            col = (t * fields + i) * tw
+            for a in range(0, tw, piece):
+                g = (col + a) // group
+                if g != cur:
+                    y = y + _rows(scale_t, cur, tids, n_tasks) * (
+                        acc - _rows(zero_t, cur, tids, n_tasks) * xs)
+                    acc = jnp.zeros_like(acc)
+                    xs = jnp.zeros_like(xs)
+                    cur = g
+                xc = x[:, col + a:col + a + piece]
+                acc = acc + jax.lax.dot_general(
+                    xc, codes if piece == tw else codes[:, a:a + piece],
+                    dimension_numbers=(((1,), (1,)), ((), ())),  # x @ q.T
+                    preferred_element_type=jnp.float32, precision=prec)
+                xs = xs + jnp.sum(xc.astype(jnp.float32), axis=1,
+                                  keepdims=True)
+    return y + _rows(scale_t, cur, tids, n_tasks) * (
+        acc - _rows(zero_t, cur, tids, n_tasks) * xs)
 
 
-def _unpack_nibbles(words: jax.Array, bk: int) -> jax.Array:
-    """uint32 (bn, bk/8) → float32 codes (bn, bk)."""
-    shifts = jnp.arange(PACK, dtype=jnp.uint32) * 4
-    codes = (words[..., None] >> shifts) & jnp.uint32(0xF)
-    return codes.reshape(words.shape[0], bk).astype(jnp.float32)
+def _kernel(*refs, has_tids: bool, group: int, plane_bits: int):
+    if has_tids:
+        tid_ref, x_ref, qw_ref, s_ref, z_ref, o_ref = refs
+    else:
+        (x_ref, qw_ref, s_ref, z_ref, o_ref), tid_ref = refs, None
+    y = qmm_tile(x_ref, qw_ref, s_ref, z_ref, tid_ref, group=group,
+                 plane_bits=plane_bits)
+    o_ref[...] = y.astype(o_ref.dtype)
 
 
-def _unpack_planes(words: jax.Array, bk: int) -> jax.Array:
-    """uint32 planes (p, bn, bk/32) → float32 codes (bn, bk).
-
-    Plane 0 is the most significant of the p planes consumed, so the same
-    expression decodes both the full b-bit codes (p = b) and the p-bit
-    draft truncation (p < b, the BlockSpec having loaded only the prefix).
-    """
-    p, bn = words.shape[0], words.shape[1]
-    shifts = jnp.arange(PLANE_PACK, dtype=jnp.uint32)
-    bits = (words[..., None] >> shifts) & jnp.uint32(1)       # (p, bn, w, 32)
-    bits = bits.reshape(p, bn, bk)
-    weight = (jnp.uint32(1) << jnp.arange(p, dtype=jnp.uint32))[::-1]
-    codes = jnp.sum(bits * weight[:, None, None], axis=0, dtype=jnp.uint32)
-    return codes.astype(jnp.float32)
-
-
-def _qw_layout(spec: QuantSpec, bn: int, bk: int):
-    """(pack unit, words-per-block, unpack fn, BlockSpec block + index fn).
-
-    The returned index fn takes the (j, kk) tile coordinates; the plane
-    layout's leading axis always indexes block 0 — a ``spec.bits``-sized
-    prefix of however many planes the stored buffer holds.
-    """
-    if spec.plane:
-        blk = (spec.bits, bn, bk // PLANE_PACK)
-        return (PLANE_PACK, bk // PLANE_PACK, _unpack_planes, blk,
-                lambda j, kk: (0, j, kk))
-    blk = (bn, bk // PACK)
-    return PACK, bk // PACK, _unpack_nibbles, blk, lambda j, kk: (j, kk)
-
-
-def _dequant_tile(codes: jax.Array, scale: jax.Array, zero: jax.Array,
-                  groups_per_blk: int) -> jax.Array:
-    """(bn, bk) f32 codes + (bn, G_blk) scales/zeros → Ŵ tile (bn, bk) f32.
-
-    Groups are contiguous runs of bk/G_blk columns.  Shared by the GEMM and
-    GEMV kernels AND the blocked-replay oracle in ref.py — the bit-exactness
-    tests rely on all of them running this exact expression.
-    """
-    bn, bk = codes.shape
-    cg = codes.reshape(bn, groups_per_blk, bk // groups_per_blk)
-    return (scale[..., None] * (cg - zero[..., None])).reshape(bn, bk)
-
-
-def _qmm_kernel(x_ref, qw_ref, scale_ref, zero_ref, o_ref, acc_ref,
-                *, n_k: int, bk: int, groups_per_blk: int, out_dtype,
-                unpack=_unpack_nibbles):
-    """One (bm, bn) output tile; K-loop via grid dim 2 (innermost)."""
-    k_idx = pl.program_id(2)
-
-    @pl.when(k_idx == 0)
-    def _zero_acc():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    x = x_ref[...]                                  # (bm, bk)   bf16/f32
-    codes = unpack(qw_ref[...], bk)                 # (bn, bk)   f32
-    w = _dequant_tile(codes, scale_ref[...], zero_ref[...], groups_per_blk)
-    acc_ref[...] += jax.lax.dot_general(
-        x.astype(jnp.float32), w,
-        dimension_numbers=(((1,), (1,)), ((), ())),  # x @ w.T
-        preferred_element_type=jnp.float32,
-    )
-
-    @pl.when(k_idx == n_k - 1)
-    def _store():
-        o_ref[...] = acc_ref[...].astype(out_dtype)
+def operands(qw, scale, zero, task_ids, spec: QuantSpec, m: int):
+    """Kernel-side operand layout: int32 words, (T, G, N) scales/zeros,
+    (M, 1) task ids.  Shared with the replay oracle."""
+    if not (spec.packs or spec.plane):
+        raise NotImplementedError(
+            "the quant_matmul/quant_gemv Pallas kernels read nibble- or "
+            f"plane-packed codes; {spec} stores unpacked codes")
+    qw = jax.lax.bitcast_convert_type(qw, jnp.int32)
+    if task_ids is None:
+        scale, zero = scale[None], zero[None]
+        tids = None
+    else:
+        tids = task_ids.astype(jnp.int32).reshape(m, 1)
+    st = jnp.swapaxes(scale.astype(jnp.float32), -1, -2)
+    zt = jnp.swapaxes(zero.astype(jnp.float32), -1, -2)
+    return qw, st, zt, tids
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("spec", "block_m", "block_n", "block_k", "out_dtype", "interpret"),
-)
+    static_argnames=("spec", "block_m", "block_n", "out_dtype", "interpret",
+                     "name"))
+def _qmm_call(x, qw, scale, zero, task_ids, *, spec: QuantSpec, block_m: int,
+              block_n: int, out_dtype, interpret: bool, name: str):
+    m, k = x.shape
+    n = qw.shape[-2]
+    qw, st, zt, tids = operands(qw, scale, zero, task_ids, spec, m)
+    n_tasks, g, _ = st.shape
+    bm, bn = min(block_m, m), min(block_n, n)
+    if spec.plane:
+        qw_spec = pl.BlockSpec((spec.bits, bn, k // PLANE_PACK),
+                               lambda i, j: (0, j, 0))
+    else:
+        qw_spec = pl.BlockSpec((bn, k // PACK), lambda i, j: (j, 0))
+    sz_spec = pl.BlockSpec((n_tasks, g, bn), lambda i, j: (0, 0, j))
+    in_specs = [pl.BlockSpec((bm, k), lambda i, j: (i, 0)),
+                qw_spec, sz_spec, sz_spec]
+    args = [x, qw, st, zt]
+    if tids is not None:
+        in_specs.insert(0, pl.BlockSpec((bm, 1), lambda i, j: (i, 0)))
+        args.insert(0, tids)
+    return pl.pallas_call(
+        functools.partial(_kernel, has_tids=tids is not None, group=k // g,
+                          plane_bits=spec.bits if spec.plane else 0),
+        grid=(pl.cdiv(m, bm), pl.cdiv(n, bn)),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((m, n), out_dtype or x.dtype),
+        interpret=interpret,
+        name=name,
+    )(*args)
+
+
 def quant_matmul_pallas(
     x: jax.Array,           # (M, K)
-    qw: jax.Array,          # (N, K // 8) uint32 packed codes
-    scale: jax.Array,       # (N, G) f32
-    zero: jax.Array,        # (N, G) f32
+    qw: jax.Array,          # (N, K // 8) uint32 — or (bits', N, K // 32)
+    scale: jax.Array,       # (N, G) f32 — or (T, N, G) with task_ids
+    zero: jax.Array,        # same shape as scale
     *,
     spec: QuantSpec,
+    task_ids: jax.Array | None = None,   # (M,) int32 rows into the T stack
     block_m: int = DEFAULT_BLOCK_M,
     block_n: int = DEFAULT_BLOCK_N,
-    block_k: int = DEFAULT_BLOCK_K,
     out_dtype=None,
     interpret: bool = False,
 ) -> jax.Array:
-    """y = x @ Ŵᵀ with Ŵ = scale · (codes − zero);  returns (M, N)."""
-    m, k = x.shape
-    n = qw.shape[1] if spec.plane else qw.shape[0]
-    g = scale.shape[-1]
-    group = k // g
-    out_dtype = out_dtype or x.dtype
-
-    bm = min(block_m, m)
-    bn = min(block_n, n)
-    pack = PLANE_PACK if spec.plane else (PACK if spec.packs else 1)
-    bk, groups_per_blk, blocks_per_group = aligned_block_k(
-        k, min(block_k, k), group, spec.packs, pack=pack)
-    _, _, unpack, qw_blk, qw_idx = _qw_layout(spec, bn, bk)
-    n_k = k // bk
-
-    grid = (pl.cdiv(m, bm), pl.cdiv(n, bn), n_k)
-
-    return pl.pallas_call(
-        functools.partial(
-            _qmm_kernel, n_k=n_k, bk=bk,
-            groups_per_blk=groups_per_blk, out_dtype=out_dtype,
-            unpack=unpack,
-        ),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec(qw_blk, lambda i, j, kk, f=qw_idx: f(j, kk)),
-            pl.BlockSpec((bn, groups_per_blk),
-                         lambda i, j, kk, gd=blocks_per_group: (j, kk // gd)),
-            pl.BlockSpec((bn, groups_per_blk),
-                         lambda i, j, kk, gd=blocks_per_group: (j, kk // gd)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
-    )(x, qw, scale, zero)
+    """y = x @ Ŵᵀ with Ŵ = scale · (codes − zero);  returns (M, N).
+    Grid (M/bm, N/bn); ``block_m`` must be a multiple of the activation
+    dtype's sublane tile (8 for f32, 16 for bf16) when it is below M."""
+    return _qmm_call(x, qw, scale, zero, task_ids, spec=spec,
+                     block_m=block_m, block_n=block_n, out_dtype=out_dtype,
+                     interpret=interpret, name="quant_matmul")
 
 
-def _qgemv_kernel(x_ref, qw_ref, scale_ref, zero_ref, o_ref, acc_ref,
-                  *, n_k: int, bk: int, groups_per_blk: int, out_dtype,
-                  unpack=_unpack_nibbles):
-    """One (M, bn) output stripe; K-loop via grid dim 1 (innermost)."""
-    k_idx = pl.program_id(1)
-
-    @pl.when(k_idx == 0)
-    def _zero_acc():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    x = x_ref[...]                                  # (M, bk)  VMEM-resident
-    codes = unpack(qw_ref[...], bk)                 # (bn, bk) — one HBM visit
-    w = _dequant_tile(codes, scale_ref[...], zero_ref[...], groups_per_blk)
-    acc_ref[...] += jax.lax.dot_general(
-        x.astype(jnp.float32), w,
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-
-    @pl.when(k_idx == n_k - 1)
-    def _store():
-        o_ref[...] = acc_ref[...].astype(out_dtype)
-
-
-def _qgemv_tasks_kernel(tid_ref, x_ref, qw_ref, scale_ref, zero_ref,
-                        o_ref, acc_ref, *, n_k: int, bk: int,
-                        groups_per_blk: int, n_tasks: int, out_dtype,
-                        unpack=_unpack_nibbles):
-    """Task-stacked GEMV tile: per-slot scale rows selected in-kernel.
-
-    ``tid_ref`` is the scalar-prefetched slot→task map (SMEM); scale/zero
-    blocks carry the full task stack (T, bn, G_blk) in VMEM.  Each task's
-    dequant tile runs the SAME dot as the plain kernel over the full (M, bk)
-    activation block, then a per-slot select keeps the matching row — so a
-    slot's output is bitwise what the plain kernel yields under that task's
-    live scales (the drain/resident scheduler-equality keystone).  The codes
-    are unpacked once and reused across tasks: qw HBM traffic is unchanged.
-    """
-    k_idx = pl.program_id(1)
-
-    @pl.when(k_idx == 0)
-    def _zero_acc():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    x = x_ref[...]
-    m = x.shape[0]
-    codes = unpack(qw_ref[...], bk)
-    tids = tid_ref[...].reshape(m, 1)               # (M, 1) int32
-    y = jnp.zeros((m, codes.shape[0]), jnp.float32)
-    for t in range(n_tasks):                        # static unroll, T small
-        w_t = _dequant_tile(codes, scale_ref[t], zero_ref[t], groups_per_blk)
-        y_t = jax.lax.dot_general(
-            x.astype(jnp.float32), w_t,
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        y = jnp.where(tids == t, y_t, y)
-    acc_ref[...] += y
-
-    @pl.when(k_idx == n_k - 1)
-    def _store():
-        o_ref[...] = acc_ref[...].astype(out_dtype)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("spec", "block_n", "block_k", "out_dtype", "interpret"),
-)
 def quant_gemv_pallas(
     x: jax.Array,           # (M, K), M = n_slots (small)
-    qw: jax.Array,          # (N, K // 8) uint32 packed codes
+    qw: jax.Array,          # (N, K // 8) uint32 — or (bits', N, K // 32)
     scale: jax.Array,       # (N, G) f32 — or (T, N, G) with task_ids
     zero: jax.Array,        # same shape as scale
     *,
     task_ids: jax.Array | None = None,   # (M,) int32 rows into the T stack
     spec: QuantSpec,
     block_n: int = DEFAULT_BLOCK_N,
-    block_k: int = DEFAULT_BLOCK_K,
     out_dtype=None,
     interpret: bool = False,
 ) -> jax.Array:
-    """Decode-shaped y = x @ Ŵᵀ;  grid (N/bn, K/bk), activations resident.
-
-    Plain call (task_ids None): same math as quant_matmul_pallas with a
-    single M block.  Slotted call: scale/zero are (T, N, G) stacks and
-    ``task_ids[i]`` picks slot i's row inside the tile loop.
+    """Decode-shaped y = x @ Ŵᵀ;  grid (N/bn,), activations resident.
 
     Plane layout (``spec.plane``): ``qw`` is (bits', N, K/32) and only the
     top ``spec.bits`` planes are streamed — with ``bits' > spec.bits`` this
     is the draft decode reading a prefix of the target's buffer.
     """
-    if not (spec.packs or spec.plane):
-        raise NotImplementedError("quant_gemv_pallas needs packed codes")
-    m, k = x.shape
-    n = qw.shape[1] if spec.plane else qw.shape[0]
-    g = scale.shape[-1]
-    group = k // g
-    out_dtype = out_dtype or x.dtype
-
-    bn = min(block_n, n)
-    pack = PLANE_PACK if spec.plane else PACK
-    bk, groups_per_blk, blocks_per_group = aligned_block_k(
-        k, min(block_k, k), group, pack=pack)
-    _, _, unpack, qw_blk, qw_idx = _qw_layout(spec, bn, bk)
-    n_k = k // bk
-    grid = (pl.cdiv(n, bn), n_k)
-
-    x_spec = pl.BlockSpec((m, bk), lambda j, kk, *_: (0, kk))
-    qw_spec = pl.BlockSpec(qw_blk, lambda j, kk, *_, f=qw_idx: f(j, kk))
-    out_spec = pl.BlockSpec((m, bn), lambda j, kk, *_: (0, j))
-    scratch = [pltpu.VMEM((m, bn), jnp.float32)]
-    out_shape = jax.ShapeDtypeStruct((m, n), out_dtype)
-
-    if task_ids is None:
-        sz_spec = pl.BlockSpec(
-            (bn, groups_per_blk),
-            lambda j, kk, gd=blocks_per_group: (j, kk // gd))
-        return pl.pallas_call(
-            functools.partial(
-                _qgemv_kernel, n_k=n_k, bk=bk,
-                groups_per_blk=groups_per_blk, out_dtype=out_dtype,
-                unpack=unpack,
-            ),
-            grid=grid,
-            in_specs=[x_spec, qw_spec, sz_spec, sz_spec],
-            out_specs=out_spec,
-            out_shape=out_shape,
-            scratch_shapes=scratch,
-            interpret=interpret,
-        )(x, qw, scale, zero)
-
-    n_tasks = scale.shape[0]
-    sz_spec = pl.BlockSpec(
-        (n_tasks, bn, groups_per_blk),
-        lambda j, kk, *_, gd=blocks_per_group: (0, j, kk // gd))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[x_spec, qw_spec, sz_spec, sz_spec],
-        out_specs=out_spec,
-        scratch_shapes=scratch,
-    )
-    return pl.pallas_call(
-        functools.partial(
-            _qgemv_tasks_kernel, n_k=n_k, bk=bk,
-            groups_per_blk=groups_per_blk, n_tasks=n_tasks,
-            out_dtype=out_dtype, unpack=unpack,
-        ),
-        grid_spec=grid_spec,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(task_ids.astype(jnp.int32), x, qw, scale, zero)
+    return _qmm_call(x, qw, scale, zero, task_ids, spec=spec,
+                     block_m=x.shape[0], block_n=block_n,
+                     out_dtype=out_dtype, interpret=interpret,
+                     name="quant_gemv")

@@ -11,13 +11,17 @@ import jax.numpy as jnp
 from repro.core.quant import QuantSpec, unpack_codes, unpack_codes_planes
 
 
+def _codes(qw, m: int, spec: QuantSpec):
+    """(n, m) integer codes from the stored (possibly packed) form."""
+    if spec.plane:
+        return unpack_codes_planes(qw, m, spec.bits)
+    return unpack_codes(qw, m) if spec.packs else qw
+
+
 def dequant_ref(qw, scale, zero, shape, spec: QuantSpec, dtype=jnp.bfloat16):
     """Ŵ = s·(q−z) from (possibly packed) codes. shape = logical (n, m)."""
     n, m = shape
-    if spec.plane:
-        codes = unpack_codes_planes(qw, m, spec.bits)
-    else:
-        codes = unpack_codes(qw, m) if spec.packs else qw
+    codes = _codes(qw, m, spec)
     g = scale.shape[-1]
     qg = codes.reshape(n, g, m // g).astype(jnp.float32)
     w = scale[..., None].astype(jnp.float32) * (qg - zero[..., None].astype(jnp.float32))
@@ -51,58 +55,60 @@ def quant_matmul_tasks_ref(x, qw, scale_stack, zero_stack, task_ids, shape,
 
 
 def quant_gemv_ref(x, qw, scale, zero, shape, spec: QuantSpec, *,
-                   task_ids=None, block_n=None, block_k=None, out_dtype=None):
-    """Blocked REPLAY of quant_gemv_pallas: same tiling, same op order, in
-    plain jnp.  The interpret-mode kernel must match this BIT-EXACTLY (the
-    allclose cross-check against quant_matmul_ref guards the math itself).
+                   task_ids=None, block_n=None, out_dtype=None):
+    """Blocked REPLAY of quant_gemv_pallas: the same (N-block) tiling
+    running the kernel's own tile function (``quant_matmul.qmm_tile``) on
+    plain arrays, so any drift in tiling, field order or group epilogue
+    shows.  The interpreted kernel may still differ from this eager replay
+    in the last bits, because XLA is free to order an f32 dot's sum
+    differently inside the interpreter's loop: compare the two with
+    ``f32_order_tolerance``, not bit for bit.
 
     scale/zero: (N, G), or (T, N, G) stacks when task_ids is given.
     """
-    from repro.kernels.quant_matmul import (
-        DEFAULT_BLOCK_K, DEFAULT_BLOCK_N, PACK, PLANE_PACK, _dequant_tile,
-        _unpack_nibbles, _unpack_planes, aligned_block_k)
+    from repro.kernels.quant_matmul import DEFAULT_BLOCK_N, operands, qmm_tile
 
-    block_n = block_n or DEFAULT_BLOCK_N
-    block_k = block_k or DEFAULT_BLOCK_K
-    out_dtype = out_dtype or x.dtype
-    n, k = shape
-    m = x.shape[0]
-    group = k // scale.shape[-1]
-    bn = min(block_n, n)
-    pack = PLANE_PACK if spec.plane else PACK
-    bk, gpb, gdiv = aligned_block_k(k, min(block_k, k), group, pack=pack)
-    wpb = bk // pack
-
+    bn = min(block_n or DEFAULT_BLOCK_N, shape[0])
+    qw, st, zt, tids = operands(qw, scale, zero, task_ids, spec, x.shape[0])
+    if spec.plane:
+        qw = qw[:spec.bits]
+    group = shape[1] // st.shape[1]
     cols = []
-    for j in range((n + bn - 1) // bn):
-        nsl = slice(j * bn, min((j + 1) * bn, n))
-        acc = jnp.zeros((m, nsl.stop - nsl.start), jnp.float32)
-        for kk in range(k // bk):
-            if spec.plane:
-                codes = _unpack_planes(
-                    qw[:spec.bits, nsl, kk * wpb:(kk + 1) * wpb], bk)
-            else:
-                codes = _unpack_nibbles(qw[nsl, kk * wpb:(kk + 1) * wpb], bk)
-            gsl = slice((kk // gdiv) * gpb, (kk // gdiv) * gpb + gpb)
-            xb = x[:, kk * bk:(kk + 1) * bk].astype(jnp.float32)
+    for j0 in range(0, shape[0], bn):
+        nsl = slice(j0, min(j0 + bn, shape[0]))
+        cols.append(qmm_tile(x, qw[..., nsl, :], st[..., nsl], zt[..., nsl],
+                             tids, group=group,
+                             plane_bits=spec.bits if spec.plane else 0))
+    return jnp.concatenate(cols, axis=1).astype(out_dtype or x.dtype)
 
-            def dot(s, z):
-                w = _dequant_tile(codes, s, z, gpb)
-                return jax.lax.dot_general(
-                    xb, w, dimension_numbers=(((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32)
 
-            if task_ids is None:
-                acc = acc + dot(scale[nsl, gsl], zero[nsl, gsl])
-            else:
-                y = jnp.zeros_like(acc)
-                for t in range(scale.shape[0]):
-                    y = jnp.where(jnp.asarray(task_ids)[:, None] == t,
-                                  dot(scale[t, nsl, gsl], zero[t, nsl, gsl]),
-                                  y)
-                acc = acc + y
-        cols.append(acc)
-    return jnp.concatenate(cols, axis=1).astype(out_dtype)
+# Two f32 evaluations of x @ Ŵᵀ that differ only in the order of their
+# sums stay within a few units of roundoff of Σ_k |x_k|·|s|·(q_k + |z|),
+# the size of the partial sums the kernel's epilogue form s·(x·q − z·Σx)
+# accumulates.  64 units (2^-18 relative) leaves room for that; an
+# evaluation with bf16 weights or activations errs by ~2^-9 per product,
+# which at these test widths is still >10× over this bound
+# (tests/test_gemv.py::test_order_tolerance_rejects_bf16_operands).
+ORDER_ULPS = 64
+
+
+def f32_order_tolerance(x, qw, scale, zero, shape, spec: QuantSpec,
+                        task_ids=None):
+    """Per-output bound (M, N) on the summation-order difference between
+    two f32 evaluations of the quantized product (see ORDER_ULPS)."""
+    n, k = shape
+    codes = _codes(qw, k, spec).astype(jnp.float32)
+    g = scale.shape[-1]
+    mag = jnp.abs(scale)[..., None] * (codes.reshape(n, g, k // g)
+                                       + jnp.abs(zero)[..., None])
+    mag = mag.reshape(*scale.shape[:-2], n, k)      # (N, K) or (T, N, K)
+    ax = jnp.abs(x.astype(jnp.float32))
+    hi = jax.lax.Precision.HIGHEST
+    if task_ids is None:
+        bound = jnp.einsum("mk,nk->mn", ax, mag, precision=hi)
+    else:
+        bound = jnp.einsum("mk,mnk->mn", ax, mag[task_ids], precision=hi)
+    return ORDER_ULPS * 2.0 ** -24 * bound
 
 
 def rtn_pack_ref(w, spec: QuantSpec, n_grid: int = 20):

@@ -22,7 +22,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.quant import PACK, PLANE_PACK, QuantSpec
+from repro.core.quant import (PACK, PLANE_PACK, QuantSpec, pack_fields,
+                              tile_words)
 
 DEFAULT_BLOCK_N = 64
 
@@ -41,19 +42,17 @@ def _rtn_quantize_block(w, levels: int, group: int):
 
 
 def _rtn_pack_kernel(w_ref, qw_ref, scale_ref, zero_ref,
-                     *, levels: int, group: int):
+                     *, levels: int, group: int, tw: int):
     w = w_ref[...].astype(jnp.float32)              # (bn, bk)
     bn, bk = w.shape
     q, scale, zero = _rtn_quantize_block(w, levels, group)
-    q = q.reshape(bn, bk // PACK, PACK).astype(jnp.uint32)
-    shifts = jnp.arange(PACK, dtype=jnp.uint32) * 4
-    qw_ref[...] = jnp.sum(q << shifts, axis=-1, dtype=jnp.uint32)
+    qw_ref[...] = pack_fields(q, PACK, 4, tw)
     scale_ref[...] = scale
     zero_ref[...] = zero
 
 
 def _rtn_pack_planes_kernel(w_ref, qw_ref, scale_ref, zero_ref,
-                            *, levels: int, group: int, bits: int):
+                            *, levels: int, group: int, bits: int, tw: int):
     """Quantize + bit-plane pack: qw block is (bits, bn, bk/32) uint32,
     plane p holding bit ``bits-1-p`` (MSB first) of every code — the codes
     never leave VREGs between round and pack."""
@@ -63,9 +62,7 @@ def _rtn_pack_planes_kernel(w_ref, qw_ref, scale_ref, zero_ref,
     q = q.astype(jnp.uint32)
     sel = jnp.arange(bits, dtype=jnp.uint32)[::-1]
     planes = (q[None] >> sel[:, None, None]) & jnp.uint32(1)
-    planes = planes.reshape(bits, bn, bk // PLANE_PACK, PLANE_PACK)
-    shifts = jnp.arange(PLANE_PACK, dtype=jnp.uint32)
-    qw_ref[...] = jnp.sum(planes << shifts, axis=-1, dtype=jnp.uint32)
+    qw_ref[...] = pack_fields(planes, PLANE_PACK, 1, tw)
     scale_ref[...] = scale
     zero_ref[...] = zero
 
@@ -88,7 +85,9 @@ def rtn_pack_pallas(
     group = spec.group_size or k
     bk = block_k or min(max(group, 2048), k)
     bk = (bk // group) * group
-    if k % bk or (spec.plane and bk % PLANE_PACK):
+    fields = PLANE_PACK if spec.plane else PACK
+    tw = tile_words(k, fields)
+    if k % bk or bk % (fields * tw):   # blocks hold whole packing tiles
         bk = k
     bn = min(block_n, n)
     g_blk = bk // group
@@ -106,7 +105,7 @@ def rtn_pack_pallas(
         bits = spec.bits
         qw, scale, zero = pl.pallas_call(
             functools.partial(_rtn_pack_planes_kernel, levels=spec.levels,
-                              group=group, bits=bits),
+                              group=group, bits=bits, tw=tw),
             grid=grid,
             in_specs=[pl.BlockSpec((bn, bk), lambda i, kk: (i, kk))],
             out_specs=[
@@ -122,7 +121,8 @@ def rtn_pack_pallas(
         )(w)
         return qw, scale, zero
     qw, scale, zero = pl.pallas_call(
-        functools.partial(_rtn_pack_kernel, levels=spec.levels, group=group),
+        functools.partial(_rtn_pack_kernel, levels=spec.levels, group=group,
+                          tw=tw),
         grid=grid,
         in_specs=[pl.BlockSpec((bn, bk), lambda i, kk: (i, kk))],
         out_specs=[

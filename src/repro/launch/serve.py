@@ -14,7 +14,7 @@ decode logits vocab-sharded with the shard-local sampler — no vocab
 all-gather in the loop.  On a CPU-only box, fake the devices first:
 
     REPRO_FAKE_DEVICES=8 PYTHONPATH=src python -m repro.launch.serve \
-        --mesh 2,4
+        --tiny --mesh 2,4
 
 ``--continuous`` switches the traffic loop to the continuously-batched
 engine: an arrival-simulating driver builds a mixed-length, mixed-task
@@ -28,7 +28,7 @@ finished sequence occupying a decode step), or the resident scheduler
 idles a single slot-step on task drain, so CI can run it as a smoke gate:
 
     REPRO_FAKE_DEVICES=8 PYTHONPATH=src python -m repro.launch.serve \
-        --mesh 2,4 --continuous
+        --tiny --mesh 2,4 --continuous
 
 ``--scheduler speculative`` (requires ``--layout plane``) self-speculates:
 each round drafts ``--spec-k`` tokens through the ``--draft-bits`` bit-plane
@@ -61,6 +61,7 @@ from repro.core.scale_bank import ScaleBank
 from repro.data import pipeline, synthetic
 from repro.dist import context as dctx
 from repro.dist import sharding as shard_rules
+from repro.launch.mesh import make_mesh
 from repro.models import registry
 from repro.optim.adamw import make_optimizer
 from repro.serve import ServeConfig, driver, traffic
@@ -236,7 +237,8 @@ def run_continuous(engine, cfg, args, tasks):
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-1b")
-    ap.add_argument("--tiny", action="store_true", default=True)
+    ap.add_argument("--tiny", action="store_true",
+                    help="reduced same-family config (CPU-servable)")
     ap.add_argument("--bits", type=int, default=4)
     ap.add_argument("--layout", default="nibble", choices=("nibble", "plane"),
                     help="code packing: 'nibble' is 8 codes/uint32; 'plane' "
@@ -314,6 +316,7 @@ def main():
     ap.add_argument("--install-s", type=float, default=0.0,
                     help="virtual seconds one host→device install costs")
     args = ap.parse_args()
+    backend.enable_compile_cache()
 
     cfg = configs.get_config(args.arch)
     if args.tiny:
@@ -358,7 +361,7 @@ def main():
     ctx = None
     if args.mesh:
         shape = tuple(int(x) for x in args.mesh.split(","))
-        mesh = jax.make_mesh(shape, ("data", "model"))
+        mesh = make_mesh(shape, ("data", "model"))
         ctx = dctx.make_ctx(mesh)
         problems = shard_rules.validate_for_mesh(backbone, mesh)
         if problems:

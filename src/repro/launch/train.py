@@ -19,6 +19,7 @@ from repro import configs
 from repro.configs.base import OptimConfig, QuantConfig, TrainConfig, TuningConfig
 from repro.core import policies
 from repro.data import pipeline, synthetic
+from repro.dist import backend
 from repro.dist import context as dctx
 from repro.launch.mesh import make_debug_mesh, make_production_mesh
 from repro.models import registry
@@ -47,6 +48,7 @@ def main():
     ap.add_argument("--mesh", default="none",
                     choices=["none", "debug", "pod", "multipod"])
     args = ap.parse_args()
+    backend.enable_compile_cache()
 
     cfg = configs.get_config(args.arch)
     if args.tiny:

@@ -85,7 +85,7 @@ def apply_train(p: dict, x: jax.Array, cfg: ModelConfig,
     o = ops.attention(q, k, v, causal=True, window=cfg.swa_window,
                       impl=cfg.attn_impl)
     o = o.reshape(b, s, cfg.n_heads * cfg.d_head)
-    return linear.apply(p["wo"], o, cfg.quant.spec())
+    return linear.apply(p["wo"], o, cfg.quant.spec(), row_parallel=True)
 
 
 def _rope_decode(q, k, pos, cfg: ModelConfig):
@@ -144,7 +144,7 @@ def apply_decode(p: dict, x: jax.Array, cfg: ModelConfig, cache_k: jax.Array,
                       impl=cfg.attn_impl)
     o = o.reshape(b, s, cfg.n_heads * cfg.d_head)
     out = linear.apply(p["wo"], o, cfg.quant.spec(),
-                       slots=linear.slot_entry(slots, "wo"))
+                       slots=linear.slot_entry(slots, "wo"), row_parallel=True)
     return out, cache_k, cache_v
 
 
@@ -186,7 +186,7 @@ def apply_decode_q8(p: dict, x: jax.Array, cfg: ModelConfig, cache: dict,
     o = ops.attention(q, kf, vf, causal=True, offset=pos, impl=cfg.attn_impl)
     o = o.reshape(b, s, cfg.n_heads * cfg.d_head)
     out = linear.apply(p["wo"], o, cfg.quant.spec(),
-                       slots=linear.slot_entry(slots, "wo"))
+                       slots=linear.slot_entry(slots, "wo"), row_parallel=True)
     return out, cache
 
 
@@ -211,7 +211,7 @@ def apply_prefill(p: dict, x: jax.Array, cfg: ModelConfig, cap: int,
     o = ops.attention(q, k, v, causal=True, window=cfg.swa_window)
     o = o.reshape(b, s, cfg.n_heads * cfg.d_head)
     out = linear.apply(p["wo"], o, cfg.quant.spec(),
-                       slots=linear.slot_entry(slots, "wo"))
+                       slots=linear.slot_entry(slots, "wo"), row_parallel=True)
     ck = jnp.roll(k[:, s - cap:], s % cap, axis=1).astype(x.dtype)
     cv = jnp.roll(v[:, s - cap:], s % cap, axis=1).astype(x.dtype)
     return out, ck, cv
@@ -246,4 +246,4 @@ def cross_apply(p: dict, x: jax.Array, enc: jax.Array, cfg: ModelConfig
     v = linear.apply(p["wv"], enc, spec).reshape(b, t, cfg.n_kv_heads, dh)
     o = ops.attention(q, k, v, causal=False)
     o = o.reshape(b, s, cfg.n_heads * dh)
-    return linear.apply(p["wo"], o, spec)
+    return linear.apply(p["wo"], o, spec, row_parallel=True)

@@ -107,7 +107,8 @@ def mlp_apply(p: dict, x: jax.Array, cfg: ModelConfig,
     else:
         h = jax.nn.gelu(up)
     return linear.apply(p["down"], h, spec,
-                        slots=linear.slot_entry(slots, "down"))
+                        slots=linear.slot_entry(slots, "down"),
+                        row_parallel=True)
 
 
 # ---------------------------------------------------------------------------

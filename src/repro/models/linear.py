@@ -82,7 +82,8 @@ def slot_entry(slots, name: str):
 
 def apply(p: dict, x: jax.Array, spec: QuantSpec, *,
           lora_scale: float = 1.0, impl: Optional[str] = None,
-          bf16_reduce: bool = False, slots=None) -> jax.Array:
+          bf16_reduce: bool = False, slots=None,
+          row_parallel: bool = False) -> jax.Array:
     """y = x W^T (+b) (+LoRA), storage-mode dispatched on present keys.
 
     bf16_reduce: emit the dot in the activation dtype (the MXU still
@@ -92,7 +93,11 @@ def apply(p: dict, x: jax.Array, spec: QuantSpec, *,
     slots: optional ``(task_ids (M,), {"scale": (T, out, G), "zero": …})``
     for the mixed-task decode step — each of the M rows of x (flattened
     leading dims) reads the scale row its slot's task owns.  Forward-only;
-    ignored for non-peqa storage modes."""
+    ignored for non-peqa storage modes.
+
+    row_parallel: this layer contracts a mesh-sharded input dim
+    (``dist.sharding.ROW_PARALLEL``: ``wo``, ``down``, ``out_proj``) — the
+    quantized kernel then contracts the codes where they are stored."""
     bf16_reduce = bf16_reduce or getattr(_tls, "bf16", False)
     pet = None if bf16_reduce else jnp.float32
     if "qw" in p:
@@ -101,10 +106,11 @@ def apply(p: dict, x: jax.Array, spec: QuantSpec, *,
             task_ids, stack = slots
             y = ops.quant_matmul_slotted(
                 x, p["qw"], stack["scale"], stack["zero"], task_ids, spec,
-                impl=impl, bf16_reduce=bf16_reduce)
+                impl=impl, bf16_reduce=bf16_reduce, row_parallel=row_parallel)
         else:
             y = ops.quant_matmul(x, p["qw"], p["scale"], p["zero"], spec,
-                                 impl=impl, bf16_reduce=bf16_reduce)
+                                 impl=impl, bf16_reduce=bf16_reduce,
+                                 row_parallel=row_parallel)
     elif "scale" in p:  # qat fake-quant (w present, scale learned)
         w = _fake_quant(p["w"].astype(x.dtype), p["scale"], p["zero"], spec)
         y = jnp.einsum("...k,nk->...n", x, w, preferred_element_type=pet

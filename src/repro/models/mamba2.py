@@ -177,7 +177,7 @@ def apply_train(p: dict, u: jax.Array, cfg: ModelConfig,
     y = y.reshape(bsz, s, d_inner).astype(u.dtype)
     y = y * jax.nn.silu(z)
     y = common.norm_apply(p["gnorm"], y, cfg)
-    out = linear.apply(p["out_proj"], y, cfg.quant.spec())
+    out = linear.apply(p["out_proj"], y, cfg.quant.spec(), row_parallel=True)
     if return_state:
         # decode's rolling conv window holds PRE-conv xproj outputs
         tail = ssm.d_conv - 1
@@ -216,5 +216,5 @@ def apply_decode(p: dict, u: jax.Array, cfg: ModelConfig,
     y = y.reshape(bsz, 1, d_inner).astype(u.dtype)
     y = y * jax.nn.silu(z)
     y = common.norm_apply(p["gnorm"], y, cfg)
-    out = linear.apply(p["out_proj"], y, cfg.quant.spec())
+    out = linear.apply(p["out_proj"], y, cfg.quant.spec(), row_parallel=True)
     return out, S, new_conv

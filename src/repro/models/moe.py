@@ -17,7 +17,9 @@ active) so dispatch stays shard-LOCAL:
   'tensor' sharding (mixtral, E ∤ mp): every shard holds all E experts with
       d_ff sliced over 'model'; expert FFN produces partial sums; combine is
       linear, so we combine FIRST and psum ONE (T_local, d) tensor — the
-      same collective bytes as a dense Megatron MLP.
+      same collective bytes as a dense Megatron MLP.  ``down`` codes whose
+      word shards would cut a packing tile (core.quant.splits_on_tiles)
+      enter whole, and each shard cuts out its own d_ff columns.
   'expert' sharding (deepseek, E % mp == 0): each model shard holds E/mp
       experts; activations are replicated over 'model' (Megatron invariant),
       so each shard dispatches to its own experts locally, computes, and the
@@ -35,10 +37,11 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
+from repro.core import quant
 from repro.dist import context as dctx
 from repro.models import common
 
@@ -197,57 +200,78 @@ def apply(p: dict, x: jax.Array, cfg: ModelConfig):
     mc = cfg.moe
     dp = ctx.data_axes
     m = ctx.model_axis
-    ep = mc.expert_sharding == "expert"
     sizes = dict(zip(ctx.mesh.axis_names, ctx.mesh.devices.shape))
+    msize = sizes[m]
     dp_size = 1
     for a in dp:
         dp_size *= sizes[a]
     b, s, _ = x.shape
     batch_sharded = b % dp_size == 0
     seq_sharded = cfg.seq_shard and s % sizes[m] == 0 and s > 1
+    qspec = cfg.quant.spec()
+    cut_in_shard = set()   # subtrees whose `down` codes arrive whole
 
-    def expert_specs(sub):
-        """Specs for the stacked-expert subtree."""
-        specs = {}
-        for name, mats in sub.items():
-            specs[name] = {}
-            for key in mats:
-                if ep:
-                    specs[name][key] = P(m, *([None] * (mats[key].ndim - 1)))
-                elif name == "down" and key in ("w", "qw"):
-                    specs[name][key] = P(None, None, m)
-                elif name == "down":
-                    specs[name][key] = P(*([None] * mats[key].ndim))
-                else:  # up/gate: shard d_ff (dim 1)
-                    specs[name][key] = P(None, m, *([None] * (mats[key].ndim - 2)))
-        return specs
+    def at(dim: int) -> P:
+        return P(*([None] * dim), m)
+
+    def leaf_spec(top: str, name: str, key: str, leaf) -> P:
+        """Expert-parallel leaves shard the expert dim; otherwise Megatron:
+        up/gate shard their output (d_ff), down its contraction (d_ff)."""
+        nd = leaf.ndim
+        if top == "experts_ep":
+            return at(0)
+        if name != "down":
+            if key in ("w", "qw", "scale", "zero"):
+                return at(nd - 2)
+            return at(nd - 1) if key == "b" else P()
+        if key == "w":
+            return at(nd - 1)
+        if key == "qw":
+            # a word shard is this shard's d_ff columns only when it holds
+            # whole packing tiles; else the codes come whole and each shard
+            # cuts its own columns out (quant.local_columns)
+            if quant.splits_on_tiles(leaf.shape[-1] * qspec.fields, qspec,
+                                     msize):
+                return at(nd - 1)
+            cut_in_shard.add(top)
+            return P()
+        if key in ("scale", "zero") and leaf.shape[-1] > 1:
+            if leaf.shape[-1] % msize:
+                raise ValueError(
+                    f"moe/{top}/down/{key}: {leaf.shape[-1]} quant groups "
+                    f"do not split over the {msize}-way model axis")
+            return at(nd - 1)   # this shard's groups of d_ff
+        return P()                  # per-channel scale/zero: per output row
 
     in_specs_p = {}
     for top, sub in p.items():
         if top == "router":
             in_specs_p[top] = jax.tree.map(lambda l: P(), sub)
-        elif top in ("experts", "experts_ep"):
-            in_specs_p[top] = expert_specs(sub)
-        elif top == "shared":  # dense TP mlp: up/gate column, down row
+        else:
             in_specs_p[top] = {
-                name: {key: (P(m, None) if (name in ("up", "gate") and key in ("w", "qw", "scale", "zero"))
-                             else P(None, m) if (name == "down" and key in ("w", "qw"))
-                             else P(*([None] * sub[name][key].ndim)))
-                       for key in sub[name]}
-                for name in sub
-            }
+                name: {key: leaf_spec(top, name, key, leaf)
+                       for key, leaf in mats.items()}
+                for name, mats in sub.items()}
     x_spec = P(dp if batch_sharded else None,
                m if seq_sharded else None, None)
+
+    def local(pp, xx):
+        for top in cut_in_shard:
+            down = dict(pp[top]["down"])
+            down["qw"] = quant.local_columns(
+                down["qw"], qspec, jax.lax.axis_index(m), msize)
+            pp = {**pp, top: {**pp[top], "down": down}}
+        return fn(pp, xx)
 
     # aux pmean must run only over axes the values actually vary on
     fn = partial(_moe_local, cfg=cfg, model_axis=m,
                  data_axes=dp if batch_sharded else (),
                  seq_sharded=seq_sharded)
     y, aux = shard_map(
-        lambda pp, xx: fn(pp, xx),
+        local,
         mesh=ctx.mesh,
         in_specs=(in_specs_p, x_spec),
         out_specs=(x_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )(p, x)
     return y, aux

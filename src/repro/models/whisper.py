@@ -75,7 +75,8 @@ def encode(params: dict, frames: jax.Array, cfg: ModelConfig) -> jax.Array:
         from repro.models import linear
         o = ops.attention(q, k, v, causal=False)
         o = o.reshape(*h.shape[:2], cfg.n_heads * cfg.d_head)
-        h = h + linear.apply(layer_p["attn"]["wo"], o, cfg.quant.spec())
+        h = h + linear.apply(layer_p["attn"]["wo"], o, cfg.quant.spec(),
+                                 row_parallel=True)
         h = h + common.mlp_apply(layer_p["mlp"],
                                  common.norm_apply(layer_p["ln2"], h, cfg), cfg)
         return h, None
@@ -157,7 +158,8 @@ def prefill(params: dict, frames: jax.Array, tokens: jax.Array,
                          ).reshape(b, s, cfg.n_heads, cfg.d_head)
         o = ops.attention(q, xk, xv, causal=False)
         o = o.reshape(b, s, cfg.n_heads * cfg.d_head)
-        h = h + linear.apply(layer_p["xattn"]["wo"], o, spec)
+        h = h + linear.apply(layer_p["xattn"]["wo"], o, spec,
+                                 row_parallel=True)
         h = h + common.mlp_apply(
             layer_p["mlp"], common.norm_apply(layer_p["ln3"], h, cfg), cfg)
         return h, {"k": ck, "v": cv, "xk": xk.astype(h.dtype),
@@ -210,7 +212,8 @@ def decode_step(params: dict, cache: dict, tokens: jax.Array, pos: jax.Array,
                          ).reshape(b, 1, cfg.n_heads, cfg.d_head)
         o = ops.attention(q, xk, xv, causal=False)
         o = o.reshape(b, 1, cfg.n_heads * cfg.d_head)
-        h = h + linear.apply(layer_p["xattn"]["wo"], o, spec)
+        h = h + linear.apply(layer_p["xattn"]["wo"], o, spec,
+                                 row_parallel=True)
         h = h + common.mlp_apply(
             layer_p["mlp"], common.norm_apply(layer_p["ln3"], h, cfg), cfg)
         return h, {"k": ck, "v": cv}

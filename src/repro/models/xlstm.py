@@ -99,7 +99,7 @@ def mlstm_apply_train(p: dict, u_res: jax.Array, cfg: ModelConfig,
     y, nq = y_aug[..., :hd], y_aug[..., hd]
     y = y / jnp.maximum(jnp.abs(nq), 1.0)[..., None]
     y = (y.reshape(b, s, d_inner) * og).astype(u_res.dtype)
-    out = linear.apply(p["down"], y, cfg.quant.spec())
+    out = linear.apply(p["down"], y, cfg.quant.spec(), row_parallel=True)
     if return_state:
         return out, S_last
     return out
@@ -121,7 +121,8 @@ def mlstm_apply_decode(p: dict, u_res: jax.Array, cfg: ModelConfig,
     y, nq = y_aug[..., :hd], y_aug[..., hd]
     y = y / jnp.maximum(jnp.abs(nq), 1.0)[..., None]
     y = y.reshape(b, 1, d_inner) * og[:, None]
-    out = linear.apply(p["down"], y.astype(u_res.dtype), cfg.quant.spec())
+    out = linear.apply(p["down"], y.astype(u_res.dtype), cfg.quant.spec(),
+                       row_parallel=True)
     return out, S
 
 
@@ -178,7 +179,7 @@ def slstm_apply_train(p: dict, u_res: jax.Array, cfg: ModelConfig,
     wx_t = jnp.swapaxes(wx, 0, 1)                                 # (S,B,4,H,hd)
     carry, ys = jax.lax.scan(step, state, wx_t)
     y = jnp.swapaxes(ys, 0, 1).reshape(b, s, d).astype(u_res.dtype)
-    out = linear.apply(p["down"], y, cfg.quant.spec())
+    out = linear.apply(p["down"], y, cfg.quant.spec(), row_parallel=True)
     if return_state:
         return out, carry
     return out

@@ -57,6 +57,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.scale_bank import ResidentStack, ScaleBank
+from repro.dist import context as dctx
 from repro.dist import sampling
 from repro.models.registry import ModelAPI
 # the serving API types live in repro.serve (the production driver layer);
@@ -240,6 +241,7 @@ class Engine:
         if self.ctx is None:
             return fn
         ctx, ls = self.ctx, self.logitshard
+        fn = self._mesh_scoped(fn)
 
         def wrapped(*args):
             logits, cache = fn(*args)
@@ -251,6 +253,19 @@ class Engine:
                                  cache, self._cache_shardings(cache, b))
             return logits, cache
         return wrapped
+
+    def _mesh_scoped(self, fn):
+        """Trace ``fn`` inside the engine's mesh scope
+        (``dist.context.use_mesh``): quantized linears then run their
+        Pallas kernels per model shard (``ops._kernel_matmul``), and the
+        activation constraints match the dry-run's layout."""
+        if self.ctx is None:
+            return fn
+
+        def scoped(*args, **kw):
+            with dctx.use_mesh(self.ctx):
+                return fn(*args, **kw)
+        return scoped
 
     def _sampler(self, b: int):
         """Greedy sampler for batch ``b`` (cached): shard-local argmax +
@@ -436,6 +451,7 @@ class Engine:
                     self._cache_shardings(cache, tok.shape[0]))
             return g, acc, cache
 
+        rnd = self._mesh_scoped(rnd)
         if slotted:
             fn = jax.jit(lambda p, st, c, tok, pos, act, tid:
                          rnd(p, c, tok, pos, act, stack=st, tid=tid),
@@ -507,8 +523,13 @@ class Engine:
     # ------------------------------------------------------------- generate
     def generate(self, tokens: jnp.ndarray, n_new: int,
                  cache_len: Optional[int] = None,
-                 prefix: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+                 prefix: Optional[jnp.ndarray] = None,
+                 return_logits: bool = False):
         """Greedy decode (LOCKSTEP baseline). tokens (B, S) → (B, S + n_new).
+
+        ``return_logits`` also returns the (B, n_new, V) f32 logits each
+        new token was sampled from: row 0 from the prefill, row i from
+        decode step i — what a reference forward is compared against.
 
         ``prefix``: (B, P, d) per-row prefix state, fed to the prefill
         under the family's ``FamilyCaps.prefix_key`` (vlm image embeddings
@@ -550,6 +571,7 @@ class Engine:
         logits, cache = self._prefill(self.params, batch)
         cache = self._grow_cache(cache, b, cache_len, s_eff)
         out = [tokens]
+        seen = [logits] if return_logits else None
         tok = sample(logits)[:, None]
         for i in range(n_new):
             out.append(tok)
@@ -557,8 +579,11 @@ class Engine:
                 break
             logits, cache = self._decode(self.params, cache, tok,
                                          jnp.int32(s_eff + i))
+            if seen is not None:
+                seen.append(logits)
             tok = sample(logits)[:, None]
-        return jnp.concatenate(out, axis=1)
+        out = jnp.concatenate(out, axis=1)
+        return out if seen is None else (out, jnp.stack(seen, axis=1))
 
     def _init_cache(self, b, cache_len):
         """Decode cache with headroom.  On a mesh it is CREATED sharded per

@@ -2,10 +2,11 @@
 
 Three layers of evidence, strongest first:
 
-  * BIT-exactness against the blocked-replay oracle
-    (``ref.quant_gemv_ref`` walks the same (block_n, block_k) tiles in the
-    same order with the same dequant expression) — any drift in tiling,
-    accumulation order or dequant math fails exactly.
+  * agreement with the blocked-replay oracle (``ref.quant_gemv_ref`` runs
+    the kernel's own tile function over the same N blocks) to within
+    ``ref.f32_order_tolerance`` — the room two f32 evaluations need when
+    XLA orders a dot's sum differently, and far less than bf16 operands
+    would cost (``test_order_tolerance_rejects_bf16_operands``).
   * allclose against the naive oracle (full dequant + one einsum) — guards
     the MATH while the replay guards the MECHANICS.
   * the slotted equality contract: rows of the task-stacked GEMV where
@@ -17,11 +18,14 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 
-from repro.core.quant import QTensor, QuantSpec
+from repro.core.quant import (QTensor, QuantSpec, pack_codes,
+                              pack_codes_planes, tile_words, unpack_codes)
 from repro.kernels import ops, ref
 from repro.kernels import quant_matmul as qm
+from conftest import assert_order_close
 
-BN, BK = 64, 128  # force multi-block grids at test shapes
+BN = 64  # force multi-block grids at test shapes
+
 
 
 def _make(n, k, group, bits, m, seed=0):
@@ -50,13 +54,13 @@ def _stacks(qt, n_tasks, seed=1):
 @pytest.mark.parametrize("bits", [3, 4])
 def test_gemv_bitexact_vs_blocked_replay(group, bits):
     # n=96 does not divide block_n=64 (padded edge tile); k=256 spans
-    # multiple K blocks for every group choice
+    # several groups for every group choice
     x, qt, spec = _make(96, 256, group, bits, m=4, seed=bits)
     got = qm.quant_gemv_pallas(x, qt.qw, qt.scale, qt.zero, spec=spec,
-                               block_n=BN, block_k=BK, interpret=True)
+                               block_n=BN, interpret=True)
     want = ref.quant_gemv_ref(x, qt.qw, qt.scale, qt.zero, qt.shape, spec,
-                              block_n=BN, block_k=BK)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+                              block_n=BN)
+    assert_order_close(got, want, x, qt, spec)
     naive = ref.quant_matmul_ref(x, qt.qw, qt.scale, qt.zero, qt.shape, spec)
     np.testing.assert_allclose(np.asarray(got), np.asarray(naive),
                                rtol=1e-5, atol=1e-4)
@@ -68,11 +72,10 @@ def test_gemv_tasks_bitexact_vs_blocked_replay(group, bits):
     scale_s, zero_s = _stacks(qt, 3)
     tids = jnp.asarray([0, 1, 2, 0, 1], jnp.int32)   # >= 3 distinct tasks
     got = qm.quant_gemv_pallas(x, qt.qw, scale_s, zero_s, task_ids=tids,
-                               spec=spec, block_n=BN, block_k=BK,
-                               interpret=True)
+                               spec=spec, block_n=BN, interpret=True)
     want = ref.quant_gemv_ref(x, qt.qw, scale_s, zero_s, qt.shape, spec,
-                              task_ids=tids, block_n=BN, block_k=BK)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+                              task_ids=tids, block_n=BN)
+    assert_order_close(got, want, x, qt, spec, scale_s, zero_s, tids)
     naive = ref.quant_matmul_tasks_ref(x, qt.qw, scale_s, zero_s, tids,
                                        qt.shape, spec)
     np.testing.assert_allclose(np.asarray(got), np.asarray(naive),
@@ -87,11 +90,11 @@ def test_gemv_tasks_rows_equal_plain_per_task():
     tids = np.asarray([0, 1, 2, 2, 1, 0], np.int32)
     got = np.asarray(qm.quant_gemv_pallas(
         x, qt.qw, scale_s, zero_s, task_ids=jnp.asarray(tids), spec=spec,
-        block_n=BN, block_k=BK, interpret=True))
+        block_n=BN, interpret=True))
     for t in range(3):
         plain = np.asarray(qm.quant_gemv_pallas(
             x, qt.qw, scale_s[t], zero_s[t], spec=spec,
-            block_n=BN, block_k=BK, interpret=True))
+            block_n=BN, interpret=True))
         rows = tids == t
         np.testing.assert_array_equal(got[rows], plain[rows])
 
@@ -144,30 +147,56 @@ def test_unknown_impl_raises(monkeypatch):
         ops.rtn_pack(jnp.zeros((8, 64), jnp.float32), spec)
 
 
-def test_aligned_block_k():
-    """Regression for the bk=k VMEM blow-up: on k % block_k != 0 the block
-    picker must choose the largest pack/group-aligned divisor <= block_k,
-    never fall back to the whole K axis."""
-    assert qm.aligned_block_k(768, 512, 128) == (384, 3, 1)
-    # per-channel large K (group == k > block_k): regime B, the block
-    # subdivides the single group
-    assert qm.aligned_block_k(4096, 512, 4096) == (512, 1, 8)
-    assert qm.aligned_block_k(256, 64, 64) == (64, 1, 1)
-    for k, blk, g in [(768, 512, 128), (4096, 512, 4096), (256, 64, 64),
-                      (384, 512, 96), (224, 96, 56)]:
-        bk, gpb, gdiv = qm.aligned_block_k(k, blk, g)
-        assert bk <= max(blk, g) and k % bk == 0 and bk % qm.PACK == 0
-        assert (gpb == bk // g and gdiv == 1) if g <= bk \
-            else (gpb == 1 and gdiv == g // bk)
+@pytest.mark.parametrize("k,fields,tw", [
+    (2048, 8, 128), (8192, 8, 128), (256, 8, 32), (768, 8, 96),
+    (8192, 32, 128), (2048, 32, 64)])
+def test_tile_words(k, fields, tw):
+    """A packing tile is one 128-word lane width when the row splits into
+    such tiles, else the whole row; pack/unpack round-trip either way."""
+    assert tile_words(k, fields) == tw
+    rng = np.random.default_rng(k + fields)
+    q = jnp.asarray(rng.integers(0, 16, (3, k)).astype(np.uint8))
+    if fields == 8:
+        np.testing.assert_array_equal(
+            np.asarray(unpack_codes(pack_codes(q))), np.asarray(q))
+    # field i of tile t's words is the run of tw columns at (t·F + i)·tw
+    words = np.asarray(pack_codes(q) if fields == 8 else
+                       pack_codes_planes(q & 1, 1)[0])
+    t, i = k // (fields * tw) - 1, fields - 1
+    width = 4 if fields == 8 else 1
+    field = (words[:, t * tw:(t + 1) * tw] >> (width * i)) & ((1 << width) - 1)
+    col = (t * fields + i) * tw
+    want = np.asarray(q)[:, col:col + tw] & ((1 << width) - 1)
+    np.testing.assert_array_equal(field, want)
+
+
+def test_order_tolerance_rejects_bf16_operands():
+    """The stated tolerance is tight enough to catch a kernel that rounds
+    its dequantized weights or its activations to bf16."""
+    x, qt, spec = _make(96, 256, 64, 4, m=4, seed=21)
+    exact = ref.quant_matmul_ref(x, qt.qw, qt.scale, qt.zero, qt.shape, spec)
+    tol = np.asarray(ref.f32_order_tolerance(x, qt.qw, qt.scale, qt.zero,
+                                             qt.shape, spec))
+    w = ref.dequant_ref(qt.qw, qt.scale, qt.zero, qt.shape, spec,
+                        jnp.bfloat16).astype(jnp.float32)
+    for xx, ww in ((x, w), (x.astype(jnp.bfloat16).astype(jnp.float32),
+                            w)):
+        bf16 = np.asarray(jnp.einsum("mk,nk->mn", xx, ww,
+                                     precision="highest"))
+        err = np.abs(bf16 - np.asarray(exact))
+        assert np.max(err / tol) > 10.0
 
 
 def test_gemv_odd_k_blocks_vmem_regression():
-    """k=768 with the default block_k=512: the old fallback set bk=k; the
-    fix tiles at 384 and must stay bit-exact vs the replay at that bk."""
+    """k=768 packs into one 96-word tile whose 96-column fields straddle
+    the 128-column groups: the kernel splits each field at group edges and
+    must agree with the replay."""
     x, qt, spec = _make(64, 768, 128, 4, m=3, seed=13)
     got = qm.quant_gemv_pallas(x, qt.qw, qt.scale, qt.zero, spec=spec,
                                interpret=True)     # default blocks
-    bk, _, _ = qm.aligned_block_k(768, qm.DEFAULT_BLOCK_K, 128)
-    assert bk == 384
+    assert tile_words(768, qm.PACK) == 96
     want = ref.quant_gemv_ref(x, qt.qw, qt.scale, qt.zero, qt.shape, spec)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert_order_close(got, want, x, qt, spec)
+    naive = ref.quant_matmul_ref(x, qt.qw, qt.scale, qt.zero, qt.shape, spec)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(naive),
+                               rtol=1e-5, atol=1e-4)

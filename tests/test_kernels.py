@@ -37,9 +37,9 @@ def test_quant_matmul_kernel_vs_ref(m, n, k, group, bits, xdtype):
                                rtol=1e-5, atol=1e-4)
 
 
-@pytest.mark.parametrize("block_m,block_n,block_k",
-                         [(8, 32, 64), (128, 128, 512), (16, 64, 128)])
-def test_quant_matmul_block_shape_invariance(block_m, block_n, block_k):
+@pytest.mark.parametrize("block_m,block_n",
+                         [(8, 32), (128, 128), (16, 64)])
+def test_quant_matmul_block_shape_invariance(block_m, block_n):
     rng = np.random.default_rng(7)
     n, k = 96, 256
     w = jnp.asarray(rng.normal(size=(n, k)).astype(np.float32) * 0.1)
@@ -49,7 +49,7 @@ def test_quant_matmul_block_shape_invariance(block_m, block_n, block_k):
     y_ref = ref.quant_matmul_ref(x, qt.qw, qt.scale, qt.zero, qt.shape, spec)
     y = quant_matmul_pallas(x, qt.qw, qt.scale, qt.zero, spec=spec,
                             block_m=block_m, block_n=block_n,
-                            block_k=block_k, interpret=True)
+                            interpret=True)
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
                                rtol=1e-5, atol=1e-4)
 

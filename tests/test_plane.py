@@ -10,8 +10,9 @@ The layout contract (core.quant, docs/KERNELS.md "Bit-plane packing"):
   * ``draft_scales`` rescales (s, z) so the truncated codes decode to
     (approximately) the same weights: s·2^(b-p), z/2^(b-p);
   * every kernel path (pallas-interpret, XLA fallback, blocked replay)
-    agrees BIT-exactly on the plane layout, including the spec-view where
-    ``spec.bits < qw.shape[0]`` slices the prefix in-kernel.
+    agrees on the plane layout within the f32 summation-order bound, and
+    the spec-view where ``spec.bits < qw.shape[0]`` slices the prefix
+    in-kernel is bitwise the kernel over the explicitly sliced prefix.
 """
 import numpy as np
 import jax
@@ -23,8 +24,9 @@ from repro.core.quant import (PLANE_PACK, QTensor, QuantSpec, draft_scales,
 from repro.kernels import ops, ref
 from repro.kernels import quant_matmul as qm
 from repro.kernels import rtn_pack as rp
+from conftest import assert_order_close
 
-BN, BK = 64, 128  # force multi-block grids at test shapes
+BN = 64  # force multi-block grids at test shapes
 
 
 def _spec(bits, group):
@@ -87,13 +89,13 @@ def test_draft_scales_decode_identity():
 @pytest.mark.parametrize("bits", [2, 3, 4])
 def test_plane_gemv_bitexact_vs_blocked_replay(group, bits):
     # n=96 does not divide block_n=64 (padded edge tile); k=256 spans
-    # multiple K blocks for every group choice
+    # several groups for every group choice
     x, qt, spec = _make(96, 256, group, bits, m=4, seed=bits)
     got = qm.quant_gemv_pallas(x, qt.qw, qt.scale, qt.zero, spec=spec,
-                               block_n=BN, block_k=BK, interpret=True)
+                               block_n=BN, interpret=True)
     want = ref.quant_gemv_ref(x, qt.qw, qt.scale, qt.zero, qt.shape, spec,
-                              block_n=BN, block_k=BK)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+                              block_n=BN)
+    assert_order_close(got, want, x, qt, spec)
     naive = ref.quant_matmul_ref(x, qt.qw, qt.scale, qt.zero, qt.shape, spec)
     np.testing.assert_allclose(np.asarray(got), np.asarray(naive),
                                rtol=2e-2, atol=2e-2)
@@ -107,14 +109,16 @@ def test_plane_gemv_draft_prefix_view(bits, draft):
     dspec = QuantSpec(bits=draft, group_size=64, layout="plane")
     sd, zd = draft_scales(qt.scale, qt.zero, bits, draft)
     via_view = qm.quant_gemv_pallas(x, qt.qw, sd, zd, spec=dspec,
-                                    block_n=BN, block_k=BK, interpret=True)
+                                    block_n=BN, interpret=True)
     via_slice = qm.quant_gemv_pallas(x, qt.qw[:draft], sd, zd, spec=dspec,
-                                     block_n=BN, block_k=BK, interpret=True)
+                                     block_n=BN, interpret=True)
     np.testing.assert_array_equal(np.asarray(via_view),
                                   np.asarray(via_slice))
     want = ref.quant_gemv_ref(x, qt.qw[:draft], sd, zd, qt.shape, dspec,
-                              block_n=BN, block_k=BK)
-    np.testing.assert_array_equal(np.asarray(via_view), np.asarray(want))
+                              block_n=BN)
+    tol = ref.f32_order_tolerance(x, qt.qw, sd, zd, qt.shape, dspec)
+    assert np.all(np.abs(np.asarray(via_view) - np.asarray(want))
+                  <= np.asarray(tol))
 
 
 @pytest.mark.parametrize("group,bits", [(64, 4), (32, 3), (None, 2)])
@@ -128,13 +132,13 @@ def test_plane_gemv_tasks_bitexact(group, bits):
     zeros = jnp.stack([qt.zero, qt.zero])
     tids = jnp.asarray([1, 0, 1, 0], jnp.int32)
     got = qm.quant_gemv_pallas(x, qt.qw, scales, zeros, task_ids=tids,
-                               spec=spec, block_n=BN, block_k=BK,
+                               spec=spec, block_n=BN,
                                interpret=True)
     # row i == the plain GEMV under task tids[i]'s scales
     for t in (0, 1):
         rows = np.flatnonzero(np.asarray(tids) == t)
         plain = qm.quant_gemv_pallas(x[rows], qt.qw, scales[t], zeros[t],
-                                     spec=spec, block_n=BN, block_k=BK,
+                                     spec=spec, block_n=BN,
                                      interpret=True)
         np.testing.assert_array_equal(np.asarray(got)[rows],
                                       np.asarray(plain))
@@ -146,7 +150,7 @@ def test_plane_rtn_pack_kernel_matches_ref(bits):
     w = jnp.asarray(rng.normal(size=(96, 256)).astype(np.float32) * 0.05)
     spec = _spec(bits, 64)
     qw_k, s_k, z_k = rp.rtn_pack_pallas(w, spec=spec, block_n=BN,
-                                        block_k=BK, interpret=True)
+                                        block_k=128, interpret=True)
     # the kernel is plain min/max RTN — compare against the n_grid=1 oracle
     qw_r, s_r, z_r = ref.rtn_pack_ref(w, spec, n_grid=1)
     np.testing.assert_array_equal(np.asarray(qw_k), np.asarray(qw_r))

@@ -27,6 +27,7 @@ _SERVE_TEST = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from repro import configs
     from repro.configs.base import QuantConfig, TuningConfig
     from repro.core import policies
@@ -38,7 +39,7 @@ _SERVE_TEST = textwrap.dedent("""
     from repro.models import registry
     from repro.train.serve import Engine
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     ctx = dctx.make_ctx(mesh)
     cfg = configs.paper_lm(n_layers=2, d_model=128, n_heads=4, d_ff=256,
                            vocab=512).replace(
@@ -90,12 +91,14 @@ _SERVE_TEST = textwrap.dedent("""
     cspecs = shard_rules.cache_specs(ctx, acache, 2, True,
                                      n_kv_heads=cfg.n_kv_heads,
                                      batch_dims=bdims)
+    # a PartitionSpec stores a one-axis tuple as the bare axis name
+    dax = jax.sharding.PartitionSpec(ctx.data_axes)[0]
     for leaf, bd, cs in zip(jax.tree.leaves(acache), jax.tree.leaves(bdims),
                             jax.tree.leaves(cspecs)):
         assert bd != 0, (leaf.shape, bd)       # dim 0 is the layer stack
         if bd >= 0:
-            assert tuple(cs)[bd] == ctx.data_axes, (leaf.shape, bd, cs)
-            assert all(ax != ctx.data_axes for i, ax in enumerate(tuple(cs))
+            assert tuple(cs)[bd] == dax, (leaf.shape, bd, cs)
+            assert all(ax != dax for i, ax in enumerate(tuple(cs))
                        if i != bd), (leaf.shape, cs)
 
     # ---- shard-local argmax: bit-exact vs gathered argmax --------------
@@ -155,6 +158,7 @@ _CONT_TEST = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from repro import configs
     from repro.configs.base import QuantConfig, TuningConfig
     from repro.core import policies
@@ -167,7 +171,7 @@ _CONT_TEST = textwrap.dedent("""
     from repro.serve import ServeConfig
     from repro.train.serve import Engine, Request
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     ctx = dctx.make_ctx(mesh)
     cfg = configs.paper_lm(n_layers=2, d_model=128, n_heads=4, d_ff=256,
                            vocab=512).replace(
@@ -247,6 +251,7 @@ _SAMPLE_TEST = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from repro.dist import context as dctx, sampling
 
     key = jax.random.PRNGKey(42)
@@ -260,7 +265,7 @@ _SAMPLE_TEST = textwrap.dedent("""
     # the SAME (key, row, vocab-id)-keyed noise field under two mesh
     # shapes: tokens must be bit-identical (reshard invariance)
     for shape in ((2, 4), (1, 8)):
-        mesh = jax.make_mesh(shape, ("data", "model"))
+        mesh = make_mesh(shape, ("data", "model"))
         ctx = dctx.make_ctx(mesh)
         fn = jax.jit(sampling.shard_sample(ctx, B, 0.8))
         got = np.asarray(fn(jax.device_put(lg, ctx.logits_sharding(B)), key))
@@ -301,6 +306,7 @@ _TOPP_TEST = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from repro.dist import context as dctx, sampling
 
     key = jax.random.PRNGKey(42)
@@ -316,7 +322,7 @@ _TOPP_TEST = textwrap.dedent("""
     # kept set — and the sampled stream — is bit-identical across mesh
     # shapes and to the off-mesh path
     for shape in ((2, 4), (1, 8)):
-        mesh = jax.make_mesh(shape, ("data", "model"))
+        mesh = make_mesh(shape, ("data", "model"))
         ctx = dctx.make_ctx(mesh)
         fn = jax.jit(sampling.shard_top_p(ctx, B, 0.9, temperature=0.8))
         got = np.asarray(fn(jax.device_put(lg, ctx.logits_sharding(B)), key))
@@ -375,6 +381,7 @@ _SPEC_SHARD_TEST = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from repro import configs
     from repro.configs.base import QuantConfig, TuningConfig
     from repro.core import policies
@@ -386,7 +393,7 @@ _SPEC_SHARD_TEST = textwrap.dedent("""
 
     # model axis 2: the tiny plane config's quant-group extents
     # (d_model/group = 2) bound the tensor split
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     ctx = dctx.make_ctx(mesh)
     cfg = configs.paper_lm(n_layers=1, d_model=64, n_heads=2, d_ff=128,
                            vocab=128).replace(

@@ -78,6 +78,7 @@ _SUBPROC_TEST = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp
+    from repro.launch.mesh import make_mesh
     from repro import configs
     from repro.configs.base import ShapeConfig, TuningConfig, MoEConfig, TrainConfig
     from repro.core import policies
@@ -86,7 +87,7 @@ _SUBPROC_TEST = textwrap.dedent("""
     from repro.optim.adamw import make_optimizer
     from repro.train import step as step_mod
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     ctx = dctx.make_ctx(mesh)
     rng = jax.random.PRNGKey(0)
     tcfg = TrainConfig()
@@ -146,12 +147,147 @@ def test_sharded_compile_subprocess():
     assert "SUBPROC_OK" in res.stdout, res.stderr[-3000:]
 
 
+_MOE_TEST = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import sys
+    import jax, jax.numpy as jnp, numpy as np
+    from repro import configs
+    from repro.configs.base import MoEConfig, QuantConfig, TuningConfig
+    from repro.core import policies
+    from repro.dist import context as dctx
+    from repro.dist import sharding as shard_rules
+    from repro.launch.mesh import make_mesh
+    from repro.models import moe
+
+    d_ff, sharding, shared, group, layout = sys.argv[1:]
+    cfg = configs.paper_lm(n_layers=1, d_model=64, n_heads=4,
+                           d_ff=int(d_ff), vocab=128).replace(
+        family="moe", tuning=TuningConfig(mode="peqa"),
+        quant=QuantConfig(bits=4, group_size=None if group == "none"
+                          else int(group), layout=layout),
+        moe=MoEConfig(n_experts=4, top_k=2, n_shared_experts=int(shared),
+                      expert_sharding=sharding))
+    rng = jax.random.PRNGKey(0)
+    p = policies.transform({"moe": moe.init(rng, cfg)}, cfg, rng)["moe"]
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 8, 64), jnp.float32)
+
+    def loss(p):
+        y, aux = moe.apply(p, x, cfg)
+        return jnp.sum(y * y) + aux, y
+
+    def scale_grads(p):
+        g = jax.grad(lambda p: loss(p)[0], allow_int=True)(p)
+        return {jax.tree_util.keystr(k): v for k, v in
+                jax.tree_util.tree_leaves_with_path(g)
+                if "scale" in jax.tree_util.keystr(k)}
+
+    run = lambda p: (loss(p)[1], scale_grads(p))
+    y1, g1 = jax.jit(run)(p)
+    ctx = dctx.make_ctx(make_mesh((1, 4), ("data", "model")))
+    pm = jax.device_put(p, shard_rules.named_shardings(ctx, p))
+    with dctx.use_mesh(ctx):   # a new function: jit caches by identity
+        y4, g4 = jax.jit(lambda p: run(p))(pm)
+
+    def close(a, b):
+        a, b = np.asarray(a), np.asarray(b)
+        return np.max(np.abs(a - b)) <= 1e-5 * np.max(np.abs(b))
+    assert close(y4, y1), float(np.max(np.abs(np.asarray(y4 - y1))))
+    assert g1 and all(close(g4[k], g1[k]) for k in g1), "scale grads differ"
+    print("SUBPROC_OK")
+""")
+
+
+@pytest.mark.parametrize("d_ff,sharding,shared,group,layout", [
+    # deepseek-like: experts sharded whole, a shared expert whose down codes
+    # (8 words, one tile) do not split on tiles over 4 shards
+    ("64", "expert", "1", "none", "nibble"),
+    # mixtral-like d_ff-sharded experts: 512-word rows, one tile per shard
+    ("4096", "tensor", "0", "none", "nibble"),
+    # d_ff-sharded experts cut inside each shard, 2 quant groups per shard
+    ("256", "tensor", "1", "32", "nibble"),
+    ("256", "tensor", "1", "none", "plane"),
+])
+def test_moe_mesh_matches_one_device(d_ff, sharding, shared, group, layout):
+    """MoE output and scale gradients on a (1, 4) mesh equal the
+    single-device block, whether or not the row-parallel codes split on
+    packing tiles."""
+    res = subprocess.run(
+        [sys.executable, "-c", _MOE_TEST, d_ff, sharding, shared, group,
+         layout], capture_output=True, text=True, timeout=600,
+        env=subproc_env())
+    assert "SUBPROC_OK" in res.stdout, res.stderr[-3000:]
+
+
+_KSPLIT_TEST = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import sys
+    import jax, jax.numpy as jnp, numpy as np
+    from repro.core.quant import QTensor, QuantSpec
+    from repro.dist import context as dctx
+    from repro.dist import sharding as shard_rules
+    from repro.kernels import ops
+    from repro.launch.mesh import make_mesh
+
+    k, group, n_tasks, layout = (int(sys.argv[1]), sys.argv[2],
+                                 int(sys.argv[3]), sys.argv[4])
+    spec = QuantSpec(bits=4, group_size=None if group == "none"
+                     else int(group), layout=layout)
+    n, m = 256, 4
+    keys = jax.random.split(jax.random.PRNGKey(0), 4)
+    qt = QTensor.quantize(jax.random.normal(keys[0], (n, k)), spec)
+    x = jax.random.normal(keys[1], (m, k), jnp.float32)
+    stack = lambda a: jnp.stack([a * (1 + 0.1 * t) for t in range(n_tasks)])
+    tids = jnp.arange(m, dtype=jnp.int32) % max(n_tasks, 1)
+
+    def fwd(x, qw, s, z):
+        if n_tasks:
+            return ops.quant_matmul_slotted(
+                x, qw, stack(s), stack(z), tids, spec, impl="interpret",
+                row_parallel=True)
+        return ops.quant_matmul(x, qw, s, z, spec, impl="interpret",
+                                row_parallel=True)
+
+    args = (x, qt.qw, qt.scale, qt.zero)
+    want = jax.jit(fwd)(*args)
+    ctx = dctx.make_ctx(make_mesh((1, 4), ("data", "model")))
+    tree = {"down": {"qw": qt.qw, "scale": qt.scale, "zero": qt.zero}}
+    placed = jax.device_put(tree, shard_rules.named_shardings(ctx, tree))
+    with dctx.use_mesh(ctx):   # a new function: jit caches by identity
+        f = jax.jit(lambda *a: fwd(*a))
+        got = f(x, *(placed["down"][key] for key in ("qw", "scale", "zero")))
+        hlo = f.lower(*args).compile().as_text()
+    err = float(np.max(np.abs(np.asarray(got - want))))
+    assert err <= 1e-5 * float(np.max(np.abs(np.asarray(want)))), err
+    print("SUBPROC_OK", "all-reduce" in hlo)
+""")
+
+
+@pytest.mark.parametrize("k,group,n_tasks,layout,split", [
+    (4096, "none", 0, "nibble", True),    # 512 words: one tile per shard
+    (4096, "128", 2, "nibble", True),     # + groups and task-stacked scales
+    (16384, "none", 0, "plane", True),
+    (512, "none", 0, "nibble", False),    # one 64-word tile: split by N
+])
+def test_row_parallel_kernel_on_mesh(k, group, n_tasks, layout, split):
+    """A row-parallel quantized linear on a (1, 4) mesh equals the
+    one-device kernel; where its word shards hold whole tiles the kernel
+    contracts them in place and adds the partial sums (an all-reduce)."""
+    res = subprocess.run(
+        [sys.executable, "-c", _KSPLIT_TEST, str(k), group, str(n_tasks),
+         layout], capture_output=True, text=True, timeout=600,
+        env=subproc_env())
+    assert f"SUBPROC_OK {split}" in res.stdout, res.stderr[-3000:]
+
+
 _PP_TEST = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from repro.dist.pipeline_par import pipeline_apply
-    mesh = jax.make_mesh((4,), ("stage",))
+    mesh = make_mesh((4,), ("stage",))
     L, B, D = 8, 8, 16
     rng = np.random.default_rng(0)
     ws = jnp.asarray(rng.normal(size=(L, D, D)).astype(np.float32) / np.sqrt(D))

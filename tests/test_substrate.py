@@ -131,6 +131,30 @@ def test_ckpt_torn_write_falls_back(tmp_path):
     assert extra["step"] == 1
 
 
+@pytest.mark.parametrize("has_qw", [True, False])
+def test_ckpt_refuses_other_qw_packing(tmp_path, has_qw):
+    """A checkpoint whose manifest predates the packing version (layout 1)
+    restores only when it holds no packed codes."""
+    tree = {"lin": {("qw" if has_qw else "w"): np.arange(4, dtype=np.uint32),
+                    "scale": np.ones(2, np.float32)}}
+    mgr = CheckpointManager(str(tmp_path), keep=2)
+    mgr.save(3, tree)
+    out, _ = mgr.restore(tree)                 # same version: restores
+    np.testing.assert_array_equal(out["lin"]["scale"], tree["lin"]["scale"])
+    path = os.path.join(str(tmp_path), "step_00000003", "manifest.json")
+    with open(path) as f:
+        manifest = json.load(f)
+    del manifest["qw_packing"]
+    with open(path, "w") as f:
+        json.dump(manifest, f)
+    if has_qw:
+        with pytest.raises(ValueError, match="layout 1"):
+            mgr.restore(tree)
+    else:
+        out, extra = mgr.restore(tree)
+        assert extra["step"] == 3
+
+
 def test_ckpt_async_save(tmp_path):
     mgr = CheckpointManager(str(tmp_path), keep=2, async_save=True)
     mgr.save(5, _tree())
@@ -181,3 +205,29 @@ def test_watchdog_quiet_on_fast_steps():
         wd.step_end()
     wd.close()
     assert not events
+
+
+# ------------------------------------------------------------- compile cache
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_placement(monkeypatch, tmp_path, from_env):
+    """JAX_COMPILATION_CACHE_DIR, when set, stands as JAX read it; otherwise
+    the cache goes to the fixed <checkout>/.jax_cache."""
+    from repro.dist import backend
+
+    prev = jax.config.jax_compilation_cache_dir
+    if from_env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        got = backend.enable_compile_cache()
+        if from_env:
+            assert got == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == prev
+        else:
+            assert got == str(backend.CHECKOUT / ".jax_cache")
+            assert (backend.CHECKOUT / "pyproject.toml").exists()
+            assert jax.config.jax_compilation_cache_dir == got
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)

@@ -1,0 +1,100 @@
+"""The main-path Pallas kernels compile for a TPU v5e at llama3.2-1b widths.
+
+Interpret mode runs these kernels on the CPU, but it does not check what
+Mosaic refuses: unaligned blocks, unsupported casts, vector ops it cannot
+lower, VMEM limits.  Here each kernel is lowered and compiled for a
+described (not attached) v5e chip, and the compiled program must contain
+the kernel as a ``tpu_custom_call``.  Widths: d_model 2048, d_ff 8192, so
+the (N, K) pairs are the up/gate (8192, 2048) and down (2048, 8192)
+projections.
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process may load the TPU library at a time, and every pytest
+worker imports every test file.
+"""
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.quant import PACK, PLANE_PACK, QuantSpec
+from repro.kernels import quant_matmul as qm
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    # the TPU compiler otherwise logs under /tmp, shared by every checkout
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this environment
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep the cache out of it
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+BF16, F32 = jnp.bfloat16, jnp.float32
+CASES = {
+    # name: (kernel, m, n, k, bits, group, layout, n_tasks, stored planes,
+    #        activation dtype[, output dtype])
+    "gemm_4bit_channel": ("matmul", 512, 8192, 2048, 4, None, "nibble", 0, 0,
+                          BF16),
+    # the resident scheduler's prefill: a 64-row prompt, two tasks' scales
+    "gemm_tasks_3bit_g128": ("matmul", 64, 2048, 8192, 3, 128, "nibble", 2,
+                             0, BF16),
+    # chip_smoke's float32 logits check (HIGHEST-precision contraction)
+    "gemm_f32_4bit_channel": ("matmul", 80, 2048, 8192, 4, None, "nibble", 0,
+                              0, F32),
+    "gemv_4bit_channel": ("gemv", 8, 2048, 8192, 4, None, "nibble", 0, 0,
+                          BF16),
+    "gemv_3bit_g128": ("gemv", 8, 8192, 2048, 3, 128, "nibble", 0, 0, BF16),
+    "gemv_tasks_4bit_channel": ("gemv", 8, 8192, 2048, 4, None, "nibble", 4,
+                                0, BF16),
+    "gemv_tasks_3bit_g128": ("gemv", 8, 2048, 8192, 3, 128, "nibble", 2, 0,
+                             BF16),
+    "plane_gemv_4bit": ("gemv", 8, 8192, 2048, 4, None, "plane", 0, 4, BF16),
+    "plane_gemv_draft_3of4": ("gemv", 8, 2048, 8192, 3, None, "plane", 0, 4,
+                              BF16),
+    # one shard of a row-parallel `down` on four chips (K 8192 / 4): f32
+    # partial sums, added across shards (ops._kernel_matmul)
+    "gemv_tasks_row_shard": ("gemv", 8, 2048, 2048, 4, None, "nibble", 2, 0,
+                             BF16, F32),
+}
+
+
+@pytest.mark.parametrize("name", list(CASES))
+def test_kernel_compiles_for_v5e(one_chip, name):
+    kern, m, n, k, bits, group, layout, n_tasks, planes, xdt, *out = \
+        CASES[name]
+    spec = QuantSpec(bits=bits, group_size=group, layout=layout)
+    g = spec.n_groups(k)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    qshape = (planes, n, k // PLANE_PACK) if spec.plane else (n, k // PACK)
+    sshape = (n_tasks, n, g) if n_tasks else (n, g)
+    args = [arg((m, k), xdt), arg(qshape, jnp.uint32),
+            arg(sshape, jnp.float32), arg(sshape, jnp.float32)]
+    fn = functools.partial(
+        qm.quant_gemv_pallas if kern == "gemv" else qm.quant_matmul_pallas,
+        out_dtype=out[0] if out else None)
+    if n_tasks:
+        args.append(arg((m,), jnp.int32))
+        call = lambda x, w, s, z, t: fn(x, w, s, z, task_ids=t, spec=spec)
+    else:
+        call = functools.partial(fn, spec=spec)
+    compiled = jax.jit(call).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
