@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.treepath import path_str as _path_str
+from repro.serve import telemetry
 
 SCALE_KEYS = ("scale", "zero")
 
@@ -290,8 +291,12 @@ class ResidentStack:
                 (r for r in self._lru if self.names[r] not in pinned), None)
         if victim is None:
             return None
-        rows = self._put(self._rows_for(name))
-        self.stack = _stack_row_install_jit(self.stack, rows, jnp.int32(victim))
+        with telemetry.span("stack.install", task=name, row=victim) as sp:
+            host = self._rows_for(name)
+            sp.set(bytes=sum(a.nbytes for a in jax.tree.leaves(host)))
+            rows = self._put(host)
+            self.stack = _stack_row_install_jit(self.stack, rows,
+                                                jnp.int32(victim))
         self.names[victim] = name
         self._touch(victim)
         self.installs += 1

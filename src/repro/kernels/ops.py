@@ -186,22 +186,26 @@ def _qmm_bwd(spec, impl, bf16_reduce, row_parallel, res, dy):
     k = x2d.shape[-1]
     n = qw.shape[1] if spec.plane else qw.shape[0]
     g = scale.shape[-1]
-    w = _dequant(qw, scale, zero, k, spec, x2d.dtype)          # (N, K)
-    dx = jax.lax.dot_general(                                   # dy @ W
-        dy, w, dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ).astype(x2d.dtype)
-    # c = dyᵀ x  (N, K) in f32
-    c = jax.lax.dot_general(
-        dy.astype(jnp.float32), x2d.astype(jnp.float32),
-        dimension_numbers=(((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    codes = _codes_f32(qw, k, spec).reshape(n, g, k // g)
-    cg = c.reshape(n, g, k // g)
-    zf = zero.astype(jnp.float32)[..., None]
-    ds = jnp.sum(cg * (codes - zf), axis=-1).astype(scale.dtype)
-    dz = (-scale.astype(jnp.float32) * jnp.sum(cg, axis=-1)).astype(zero.dtype)
+    with jax.named_scope("qmm_bwd.dequant"):
+        w = _dequant(qw, scale, zero, k, spec, x2d.dtype)      # (N, K)
+    with jax.named_scope("qmm_bwd.dx"):
+        dx = jax.lax.dot_general(                               # dy @ W
+            dy, w, dimension_numbers=(((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ).astype(x2d.dtype)
+    with jax.named_scope("qmm_bwd.dscale"):
+        # c = dyᵀ x  (N, K) in f32
+        c = jax.lax.dot_general(
+            dy.astype(jnp.float32), x2d.astype(jnp.float32),
+            dimension_numbers=(((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        codes = _codes_f32(qw, k, spec).reshape(n, g, k // g)
+        cg = c.reshape(n, g, k // g)
+        zf = zero.astype(jnp.float32)[..., None]
+        ds = jnp.sum(cg * (codes - zf), axis=-1).astype(scale.dtype)
+        dz = (-scale.astype(jnp.float32)
+              * jnp.sum(cg, axis=-1)).astype(zero.dtype)
     return dx, None, ds, dz
 
 

@@ -59,44 +59,64 @@ def init_cache(cfg: ModelConfig, batch: int, seq_len: int,
 
 
 def _qkv(p, x, cfg: ModelConfig, slots=None):
-    spec = cfg.quant.spec()
-    b, s, _ = x.shape
-    dh = cfg.d_head
-    ent = lambda name: linear.slot_entry(slots, name)
-    q = linear.apply(p["wq"], x, spec,
-                     slots=ent("wq")).reshape(b, s, cfg.n_heads, dh)
-    k = linear.apply(p["wk"], x, spec,
-                     slots=ent("wk")).reshape(b, s, cfg.n_kv_heads, dh)
-    v = linear.apply(p["wv"], x, spec,
-                     slots=ent("wv")).reshape(b, s, cfg.n_kv_heads, dh)
-    return q, k, v
+    with jax.named_scope("attn.qkv"):
+        spec = cfg.quant.spec()
+        b, s, _ = x.shape
+        dh = cfg.d_head
+        ent = lambda name: linear.slot_entry(slots, name)
+        q = linear.apply(p["wq"], x, spec,
+                         slots=ent("wq")).reshape(b, s, cfg.n_heads, dh)
+        k = linear.apply(p["wk"], x, spec,
+                         slots=ent("wk")).reshape(b, s, cfg.n_kv_heads, dh)
+        v = linear.apply(p["wv"], x, spec,
+                         slots=ent("wv")).reshape(b, s, cfg.n_kv_heads, dh)
+        return q, k, v
+
+
+def _rope_full(q, k, positions, cfg: ModelConfig):
+    with jax.named_scope("attn.rope"):
+        freqs = rope_freqs(cfg)
+        return (apply_rope(q, positions, freqs),
+                apply_rope(k, positions, freqs))
+
+
+def _scores(q, k, v, cfg: ModelConfig, **kw):
+    """Attention over the keys (``ops.attention``), heads merged."""
+    with jax.named_scope("attn.scores"):
+        b, s = q.shape[:2]
+        o = ops.attention(q, k, v, causal=True, **kw)
+        return o.reshape(b, s, cfg.n_heads * cfg.d_head)
+
+
+def _out(p, o, cfg: ModelConfig, slots=None):
+    with jax.named_scope("attn.out"):
+        return linear.apply(p["wo"], o, cfg.quant.spec(),
+                            slots=linear.slot_entry(slots, "wo"),
+                            row_parallel=True)
 
 
 def apply_train(p: dict, x: jax.Array, cfg: ModelConfig,
                 positions: Optional[jax.Array] = None) -> jax.Array:
     """Full-sequence causal attention (training / prefill)."""
-    b, s, _ = x.shape
+    s = x.shape[1]
     q, k, v = _qkv(p, x, cfg)
     if cfg.use_rope:
-        freqs = rope_freqs(cfg)
         pos = positions if positions is not None else jnp.arange(s)
-        q = apply_rope(q, pos, freqs)
-        k = apply_rope(k, pos, freqs)
-    o = ops.attention(q, k, v, causal=True, window=cfg.swa_window,
-                      impl=cfg.attn_impl)
-    o = o.reshape(b, s, cfg.n_heads * cfg.d_head)
-    return linear.apply(p["wo"], o, cfg.quant.spec(), row_parallel=True)
+        q, k = _rope_full(q, k, pos, cfg)
+    o = _scores(q, k, v, cfg, window=cfg.swa_window, impl=cfg.attn_impl)
+    return _out(p, o, cfg)
 
 
 def _rope_decode(q, k, pos, cfg: ModelConfig):
     """RoPE for a decode step of S ≥ 1 tokens starting at ``pos``; pos scalar
     or (B,) per-slot (token s of row b is at absolute position pos[b] + s)."""
-    freqs = rope_freqs(cfg)
-    if jnp.ndim(pos) == 1:
-        return (apply_rope_slots(q, pos, freqs),
-                apply_rope_slots(k, pos, freqs))
-    pos = pos + jnp.arange(q.shape[1])
-    return apply_rope(q, pos, freqs), apply_rope(k, pos, freqs)
+    with jax.named_scope("attn.rope"):
+        freqs = rope_freqs(cfg)
+        if jnp.ndim(pos) == 1:
+            return (apply_rope_slots(q, pos, freqs),
+                    apply_rope_slots(k, pos, freqs))
+        pos = pos + jnp.arange(q.shape[1])
+        return apply_rope(q, pos, freqs), apply_rope(k, pos, freqs)
 
 
 def _cache_write(buf, val, slot):
@@ -108,12 +128,13 @@ def _cache_write(buf, val, slot):
     (per-row indices along the batch dim, so a batch-sharded cache stays
     shard-local).
     """
-    val = val.astype(buf.dtype)
-    if jnp.ndim(slot) == 1:
-        return jax.vmap(
-            lambda c, x, s: jax.lax.dynamic_update_slice_in_dim(
-                c, x, s, axis=0))(buf, val, slot)
-    return jax.lax.dynamic_update_slice_in_dim(buf, val, slot, axis=1)
+    with jax.named_scope("attn.kv_write"):
+        val = val.astype(buf.dtype)
+        if jnp.ndim(slot) == 1:
+            return jax.vmap(
+                lambda c, x, s: jax.lax.dynamic_update_slice_in_dim(
+                    c, x, s, axis=0))(buf, val, slot)
+        return jax.lax.dynamic_update_slice_in_dim(buf, val, slot, axis=1)
 
 
 def apply_decode(p: dict, x: jax.Array, cfg: ModelConfig, cache_k: jax.Array,
@@ -131,7 +152,6 @@ def apply_decode(p: dict, x: jax.Array, cfg: ModelConfig, cache_k: jax.Array,
 
     Returns (out (B, S, d_model), new_cache_k, new_cache_v).
     """
-    b, s, _ = x.shape
     cap = cache_k.shape[1]
     q, k, v = _qkv(p, x, cfg, slots=slots)
     if cfg.use_rope:
@@ -140,12 +160,8 @@ def apply_decode(p: dict, x: jax.Array, cfg: ModelConfig, cache_k: jax.Array,
     cache_k = _cache_write(cache_k, k, slot)
     cache_v = _cache_write(cache_v, v, slot)
     # visible = slots with index <= pos (ring: all written slots; dense: prefix)
-    o = ops.attention(q, cache_k, cache_v, causal=True, offset=pos,
-                      impl=cfg.attn_impl)
-    o = o.reshape(b, s, cfg.n_heads * cfg.d_head)
-    out = linear.apply(p["wo"], o, cfg.quant.spec(),
-                       slots=linear.slot_entry(slots, "wo"), row_parallel=True)
-    return out, cache_k, cache_v
+    o = _scores(q, cache_k, cache_v, cfg, offset=pos, impl=cfg.attn_impl)
+    return _out(p, o, cfg, slots), cache_k, cache_v
 
 
 def quantize_kv(t: jax.Array):
@@ -169,7 +185,6 @@ def apply_decode_q8(p: dict, x: jax.Array, cfg: ModelConfig, cache: dict,
     knob kv_cache_dtype='int8').  cache: {k, v: int8 (B,C,H,D); k_scale,
     v_scale: f16 (B,C,H)}. pos scalar or (B,) per-slot.
     Returns (out, new_cache)."""
-    b, s, _ = x.shape
     cap = cache["k"].shape[1]
     q, k, v = _qkv(p, x, cfg, slots=slots)
     if cfg.use_rope:
@@ -181,13 +196,11 @@ def apply_decode_q8(p: dict, x: jax.Array, cfg: ModelConfig, cache: dict,
     cache = {"k": upd(cache["k"], k8), "v": upd(cache["v"], v8),
              "k_scale": upd(cache["k_scale"], ks),
              "v_scale": upd(cache["v_scale"], vs)}
-    kf = dequantize_kv(cache["k"], cache["k_scale"], x.dtype)
-    vf = dequantize_kv(cache["v"], cache["v_scale"], x.dtype)
-    o = ops.attention(q, kf, vf, causal=True, offset=pos, impl=cfg.attn_impl)
-    o = o.reshape(b, s, cfg.n_heads * cfg.d_head)
-    out = linear.apply(p["wo"], o, cfg.quant.spec(),
-                       slots=linear.slot_entry(slots, "wo"), row_parallel=True)
-    return out, cache
+    with jax.named_scope("attn.scores"):
+        kf = dequantize_kv(cache["k"], cache["k_scale"], x.dtype)
+        vf = dequantize_kv(cache["v"], cache["v_scale"], x.dtype)
+    o = _scores(q, kf, vf, cfg, offset=pos, impl=cfg.attn_impl)
+    return _out(p, o, cfg, slots), cache
 
 
 def apply_prefill(p: dict, x: jax.Array, cfg: ModelConfig, cap: int,
@@ -201,19 +214,15 @@ def apply_prefill(p: dict, x: jax.Array, cfg: ModelConfig, cap: int,
     Returns (out (B,S,d_model), ck (B,cap,Hkv,D), cv) with cache in ring
     layout (slot of token t = t % cap; a no-op roll when cap == S).
     """
-    b, s, _ = x.shape
+    s = x.shape[1]
     q, k, v = _qkv(p, x, cfg, slots=slots)
     if cfg.use_rope:
-        freqs = rope_freqs(cfg)
-        pos = jnp.arange(s)
-        q = apply_rope(q, pos, freqs)
-        k = apply_rope(k, pos, freqs)
-    o = ops.attention(q, k, v, causal=True, window=cfg.swa_window)
-    o = o.reshape(b, s, cfg.n_heads * cfg.d_head)
-    out = linear.apply(p["wo"], o, cfg.quant.spec(),
-                       slots=linear.slot_entry(slots, "wo"), row_parallel=True)
-    ck = jnp.roll(k[:, s - cap:], s % cap, axis=1).astype(x.dtype)
-    cv = jnp.roll(v[:, s - cap:], s % cap, axis=1).astype(x.dtype)
+        q, k = _rope_full(q, k, jnp.arange(s), cfg)
+    o = _scores(q, k, v, cfg, window=cfg.swa_window)
+    out = _out(p, o, cfg, slots)
+    with jax.named_scope("attn.kv_write"):
+        ck = jnp.roll(k[:, s - cap:], s % cap, axis=1).astype(x.dtype)
+        cv = jnp.roll(v[:, s - cap:], s % cap, axis=1).astype(x.dtype)
     return out, ck, cv
 
 

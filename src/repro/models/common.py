@@ -98,17 +98,19 @@ def mlp_apply(p: dict, x: jax.Array, cfg: ModelConfig,
               slots=None) -> jax.Array:
     """slots: optional (task_ids, stacked-scale subtree) for the mixed-task
     decode step — threaded into each quantized linear (see linear.apply)."""
-    spec = cfg.quant.spec()
-    up = linear.apply(p["up"], x, spec, slots=linear.slot_entry(slots, "up"))
-    if "gate" in p:
-        gate = linear.apply(p["gate"], x, spec,
-                            slots=linear.slot_entry(slots, "gate"))
-        h = jax.nn.silu(gate) * up
-    else:
-        h = jax.nn.gelu(up)
-    return linear.apply(p["down"], h, spec,
-                        slots=linear.slot_entry(slots, "down"),
-                        row_parallel=True)
+    with jax.named_scope("mlp"):
+        spec = cfg.quant.spec()
+        up = linear.apply(p["up"], x, spec,
+                          slots=linear.slot_entry(slots, "up"))
+        if "gate" in p:
+            gate = linear.apply(p["gate"], x, spec,
+                                slots=linear.slot_entry(slots, "gate"))
+            h = jax.nn.silu(gate) * up
+        else:
+            h = jax.nn.gelu(up)
+        return linear.apply(p["down"], h, spec,
+                            slots=linear.slot_entry(slots, "down"),
+                            row_parallel=True)
 
 
 # ---------------------------------------------------------------------------
@@ -133,11 +135,13 @@ def head_init(rng, cfg: ModelConfig) -> dict:
 
 def head_apply(p_head: dict, p_embed: dict, x: jax.Array, cfg: ModelConfig,
                slots=None) -> jax.Array:
-    if cfg.tie_embeddings:
-        return jnp.einsum("...d,vd->...v", x, p_embed["emb"].astype(x.dtype),
-                          preferred_element_type=jnp.float32)
-    y = linear.apply(p_head["lm_head"], x, cfg.quant.spec(), slots=slots)
-    return y.astype(jnp.float32)
+    with jax.named_scope("head"):
+        if cfg.tie_embeddings:
+            return jnp.einsum("...d,vd->...v", x,
+                              p_embed["emb"].astype(x.dtype),
+                              preferred_element_type=jnp.float32)
+        y = linear.apply(p_head["lm_head"], x, cfg.quant.spec(), slots=slots)
+        return y.astype(jnp.float32)
 
 
 def cross_entropy(logits: jax.Array, labels: jax.Array,

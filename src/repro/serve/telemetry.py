@@ -27,12 +27,26 @@ Schema (version 1) — what ``benchmarks/trajectory.py`` consumes:
 Wall-clock rows are marked at the CALL SITE (``wall=True``) — the sink
 cannot know which numbers are machine-dependent, and an unmarked noisy
 metric would flake the trajectory gate.
+
+Spans: ``span(name, **attrs)`` times a block of the program's host code.
+Each record is ``(name, id, parent, start_ns, end_ns, attrs)``; the parent
+is the enclosing span on the same thread.  Records go into a bounded ring
+(``spans()`` reads it), always on; the same call enters
+``jax.profiler.TraceAnnotation(name, **attrs)``, so in a profiled run the
+span lands in the trace beside the device's operations.  Timestamps are
+``time.time_ns()``, the realtime clock the profiler stamps host events
+with, so the two copies of a span agree.
 """
 from __future__ import annotations
 
+import collections
+import itertools
 import json
-from typing import Callable, Dict, List, Optional
+import threading
+import time
+from typing import Callable, Dict, List, NamedTuple, Optional
 
+import jax
 import numpy as np
 
 SCHEMA_VERSION = 1
@@ -129,3 +143,75 @@ def stable_metrics(doc: Dict) -> List[Dict]:
     the reproducibility contract ("identical across two seeded runs,
     modulo wall-clock fields") compares exactly this view."""
     return [m for m in doc["metrics"] if not m.get("wall")]
+
+
+class Span(NamedTuple):
+    """One finished span: nanoseconds on ``time.time_ns()``'s clock."""
+    name: str
+    id: int
+    parent: Optional[int]
+    start_ns: int
+    end_ns: int
+    attrs: Dict
+
+    @property
+    def duration_ns(self) -> int:
+        return self.end_ns - self.start_ns
+
+
+class _OpenSpan:
+    """A span being timed: ``set`` adds attributes known only inside it."""
+    __slots__ = ("_rec", "_annotation", "name", "attrs", "id", "parent",
+                 "start_ns")
+
+    def __init__(self, rec: "SpanRecorder", name: str, attrs: Dict):
+        self._rec, self.name, self.attrs = rec, name, attrs
+        self._annotation = jax.profiler.TraceAnnotation(name, **attrs)
+
+    def set(self, **attrs) -> None:
+        self.attrs.update(attrs)
+        self._annotation.set_metadata(**attrs)
+
+    def __enter__(self) -> "_OpenSpan":
+        stack = self._rec._stack()
+        self.id = next(self._rec._ids)
+        self.parent = stack[-1] if stack else None
+        stack.append(self.id)
+        self._annotation.__enter__()
+        self.start_ns = time.time_ns()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        end = time.time_ns()
+        self._annotation.__exit__(*exc)
+        self._rec._stack().pop()
+        self._rec._ring.append(Span(self.name, self.id, self.parent,
+                                    self.start_ns, end, self.attrs))
+
+
+class SpanRecorder:
+    """Bounded in-memory ring of finished spans; the oldest drop first."""
+
+    def __init__(self, maxlen: int = 65536):
+        self._ring: collections.deque = collections.deque(maxlen=maxlen)
+        self._local = threading.local()
+        self._ids = itertools.count(1)
+
+    def _stack(self) -> List[int]:
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        return stack
+
+    def span(self, name: str, **attrs) -> _OpenSpan:
+        """Context manager timing its block as span ``name``."""
+        return _OpenSpan(self, name, attrs)
+
+    def spans(self, name: Optional[str] = None) -> List[Span]:
+        """Recorded spans, oldest first; those named ``name`` if given."""
+        return [s for s in list(self._ring) if name is None or s.name == name]
+
+
+_RECORDER = SpanRecorder()
+span = _RECORDER.span
+spans = _RECORDER.spans

@@ -66,6 +66,7 @@ from repro.serve.config import ServeConfig
 from repro.serve.metrics import (REJECTED, SERVED, SHED, RequestMetrics,
                                  ServeReport)
 from repro.serve.request import Request
+from repro.serve import telemetry
 
 __all__ = ["Engine", "Request", "RequestMetrics", "ServeConfig",
            "ServeReport", "SlotPool"]
@@ -715,69 +716,76 @@ class Engine:
         and sliding-window ring caches (padded writes would wrap onto
         committed rows).  Token streams are unchanged either way.
         """
-        slot = pool.free_slot()
-        if slot is None:
-            raise RuntimeError("admit: no free slot (evict first)")
-        toks = np.asarray(request.tokens, np.int32).reshape(-1)
-        s = int(toks.shape[0])
-        n_new = int(request.n_new)
-        if s < 1 or n_new < 1:
-            raise ValueError(f"need prompt >= 1 and n_new >= 1 tokens, got "
-                             f"({s}, {n_new})")
-        prefix = getattr(request, "prefix", None)
-        self._check_prefix(prefix)
-        p_rows = self._prefix_rows(prefix)   # decoder positions the prefix eats
-        s_eff = s + p_rows
-        has_seq = self._has_seq_leaf()
-        swa = getattr(self.api.cfg, "swa_window", None) is not None
-        if has_seq and not swa and s_eff + n_new - 1 > pool.cache_len:
-            raise ValueError(
-                f"request needs {s_eff + n_new - 1} cache slots, pool has "
-                f"{pool.cache_len}")
-        if (task_row is None and request.task is not None
-                and self.bank is not None
-                and request.task != self.current_task):
-            raise ValueError(
-                f"request targets task {request.task!r} but the engine "
-                f"serves {self.current_task!r}; switch_task first (the "
-                f"scheduler drains the pool before switching)")
-        caps = self.api.caps
-        bucket = bucket and caps.bucketable and has_seq and not swa
-        s_pad = self._bucket_len(s, pool.cache_len - p_rows) if bucket else s
-        if s_pad != s:
-            toks = np.pad(toks, (0, s_pad - s))   # masked filler rows
-        prompt = jnp.asarray(toks)[None]
-        if self.ctx is not None:
-            prompt = jax.device_put(prompt, self.ctx.sharding())
-        batch = {"tokens": prompt}
-        if prefix is not None:
-            pref = jnp.asarray(np.asarray(prefix))[None]
+        with telemetry.span("engine.admit", rid=rid,
+                            task_row=task_row) as sp:
+            slot = pool.free_slot()
+            if slot is None:
+                raise RuntimeError("admit: no free slot (evict first)")
+            toks = np.asarray(request.tokens, np.int32).reshape(-1)
+            s = int(toks.shape[0])
+            n_new = int(request.n_new)
+            if s < 1 or n_new < 1:
+                raise ValueError(f"need prompt >= 1 and n_new >= 1 tokens, "
+                                 f"got ({s}, {n_new})")
+            prefix = getattr(request, "prefix", None)
+            self._check_prefix(prefix)
+            # decoder positions the prefix eats
+            p_rows = self._prefix_rows(prefix)
+            s_eff = s + p_rows
+            has_seq = self._has_seq_leaf()
+            swa = getattr(self.api.cfg, "swa_window", None) is not None
+            if has_seq and not swa and s_eff + n_new - 1 > pool.cache_len:
+                raise ValueError(
+                    f"request needs {s_eff + n_new - 1} cache slots, pool "
+                    f"has {pool.cache_len}")
+            if (task_row is None and request.task is not None
+                    and self.bank is not None
+                    and request.task != self.current_task):
+                raise ValueError(
+                    f"request targets task {request.task!r} but the engine "
+                    f"serves {self.current_task!r}; switch_task first (the "
+                    f"scheduler drains the pool before switching)")
+            caps = self.api.caps
+            bucket = bucket and caps.bucketable and has_seq and not swa
+            s_pad = (self._bucket_len(s, pool.cache_len - p_rows) if bucket
+                     else s)
+            sp.set(prompt=s, bucket=s_pad)
+            if s_pad != s:
+                toks = np.pad(toks, (0, s_pad - s))   # masked filler rows
+            prompt = jnp.asarray(toks)[None]
             if self.ctx is not None:
-                pref = jax.device_put(pref, self.ctx.sharding())
-            batch[caps.prefix_key] = pref
-        if s_pad != s:
-            # traced scalar: every prompt bucketed to s_pad shares one
-            # compile; unpadded prompts keep the original batch treedef
-            batch["last_pos"] = jnp.int32(p_rows + s - 1)
-        pool._prefill_keys.add((s_pad, p_rows, s_pad != s))
-        if task_row is not None:
-            tid = jnp.full((1,), task_row, jnp.int32)
-            if self.ctx is not None:
-                tid = jax.device_put(tid, self.ctx.sharding())
-            logits, pcache = self._slotted_prefill_fn()(
-                self.params, self.resident.stack, batch, tid)
-        else:
-            logits, pcache = self._prefill(self.params, batch)
-        self._check_admit_shapes(pool, pcache)
-        t0 = int(np.asarray(self._sampler(1)(logits))[0])
-        pool.cache = self._admit_write()(pool.cache, pcache, jnp.int32(slot))
-        pool.pos[slot] = s_eff
-        pool.active[slot] = True
-        pool.tok[slot] = t0
-        pool.task[slot] = request.task or self.current_task
-        pool.meta[slot] = {"rid": rid, "request": request, "out": [t0]}
-        pool.decoded += 1
-        pool._dev = None                   # host mirrors changed: re-upload
+                prompt = jax.device_put(prompt, self.ctx.sharding())
+            batch = {"tokens": prompt}
+            if prefix is not None:
+                pref = jnp.asarray(np.asarray(prefix))[None]
+                if self.ctx is not None:
+                    pref = jax.device_put(pref, self.ctx.sharding())
+                batch[caps.prefix_key] = pref
+            if s_pad != s:
+                # traced scalar: every prompt bucketed to s_pad shares one
+                # compile; unpadded prompts keep the original batch treedef
+                batch["last_pos"] = jnp.int32(p_rows + s - 1)
+            pool._prefill_keys.add((s_pad, p_rows, s_pad != s))
+            if task_row is not None:
+                tid = jnp.full((1,), task_row, jnp.int32)
+                if self.ctx is not None:
+                    tid = jax.device_put(tid, self.ctx.sharding())
+                logits, pcache = self._slotted_prefill_fn()(
+                    self.params, self.resident.stack, batch, tid)
+            else:
+                logits, pcache = self._prefill(self.params, batch)
+            self._check_admit_shapes(pool, pcache)
+            with telemetry.span("engine.admit.wait", rid=rid):
+                t0 = int(np.asarray(self._sampler(1)(logits))[0])
+            pool.cache = self._admit_write()(pool.cache, pcache,
+                                             jnp.int32(slot))
+            pool.pos[slot] = s_eff
+            pool.active[slot] = True
+            pool.tok[slot] = t0
+            pool.task[slot] = request.task or self.current_task
+            pool.meta[slot] = {"rid": rid, "request": request, "out": [t0]}
+            pool.decoded += 1
+            pool._dev = None               # host mirrors changed: re-upload
         return slot
 
     def _slot_done(self, pool: SlotPool, slot: int) -> bool:
@@ -826,34 +834,43 @@ class Engine:
         masked garbage (the price of one fixed compiled shape) and emit the
         pad token 0.  Returns the (n_slots,) sampled tokens; host metadata
         (pos/tok/out) is updated for active slots."""
-        if pool.n_active() == 0:
+        n_active = pool.n_active()
+        if n_active == 0:
             raise ValueError("step: no active slot (admit first)")
-        tok, pos, act, tid = self._pool_inputs(pool)
-        if pool.slotted:
-            logits, pool.cache = self._slotted_decode_fn()(
-                self.params, self.resident.stack, pool.cache, tok, pos, tid)
-        else:
-            logits, pool.cache = self._decode(self.params, pool.cache,
-                                              tok, pos)
-        t, tok2d, npos = self._stepper(pool.n_slots)(logits, act, pos)
-        nxt = np.asarray(t)
-        pool._dev = (tok2d, npos, act, tid)
-        pool.steps += 1
-        for slot in np.flatnonzero(pool.active):
-            meta = pool.meta[slot]
-            if self._slot_done(pool, slot):
-                # never happens through serve() — eviction is immediate —
-                # but count it honestly for hand-driven pools (and fall
-                # back to the host mirrors, which now disagree with the
-                # device copies' blind position advance)
-                pool.bubble_slot_steps += 1
-                pool._dev = None
-                continue
-            pool.pos[slot] += 1
-            pool.tok[slot] = int(nxt[slot])
-            meta["out"].append(int(nxt[slot]))
-            pool.decoded += 1
-        pool.idle_slot_steps += pool.n_slots - pool.n_active()
+        # kv_rows_read: the rows the step's attention reads, the whole pool
+        with telemetry.span(
+                "engine.step", active=n_active,
+                kv_rows_live=int(pool.pos[pool.active].sum()) + n_active,
+                kv_rows_read=pool.n_slots * pool.cache_len):
+            tok, pos, act, tid = self._pool_inputs(pool)
+            if pool.slotted:
+                logits, pool.cache = self._slotted_decode_fn()(
+                    self.params, self.resident.stack, pool.cache, tok, pos,
+                    tid)
+            else:
+                logits, pool.cache = self._decode(self.params, pool.cache,
+                                                  tok, pos)
+            t, tok2d, npos = self._stepper(pool.n_slots)(logits, act, pos)
+            with telemetry.span("engine.step.wait"):
+                nxt = np.asarray(t)
+            pool._dev = (tok2d, npos, act, tid)
+            pool.steps += 1
+            for slot in np.flatnonzero(pool.active):
+                meta = pool.meta[slot]
+                if self._slot_done(pool, slot):
+                    # never happens through serve() — eviction is
+                    # immediate — but count it honestly for hand-driven
+                    # pools (and fall back to the host mirrors, which now
+                    # disagree with the device copies' blind position
+                    # advance)
+                    pool.bubble_slot_steps += 1
+                    pool._dev = None
+                    continue
+                pool.pos[slot] += 1
+                pool.tok[slot] = int(nxt[slot])
+                meta["out"].append(int(nxt[slot]))
+                pool.decoded += 1
+            pool.idle_slot_steps += pool.n_slots - pool.n_active()
         return nxt
 
     def _resident_supported(self, requests: Sequence[Request]) -> bool:
