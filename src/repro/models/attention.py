@@ -1,6 +1,6 @@
 """GQA attention with RoPE, sliding-window, KV caches and cross-attention.
 
-Cache layout (per layer): {"k": (B, C, Hkv, D), "v": (B, C, Hkv, D)} where
+Cache layout (stacked over layers): {"k": (L, B, C, Hkv, D), "v": ...} where
 C = cache capacity.  Dense-attention archs use C = seq_len and write slot
 ``pos``; SWA archs use C = window and write slot ``pos % window`` (a ring
 buffer — the visible set is then exactly the last `window` tokens, so the
@@ -119,49 +119,82 @@ def _rope_decode(q, k, pos, cfg: ModelConfig):
         return apply_rope(q, pos, freqs), apply_rope(k, pos, freqs)
 
 
-def _cache_write(buf, val, slot):
-    """Write the new token's K/V (or scale) row(s) into the cache.
+def _cache_write(buf, val, layer, rows):
+    """Write the new tokens' K/V (or scale) rows into layer ``layer`` of the
+    stacked cache ``buf`` (L, B, C, ...); val (B, S, ...), rows (B, S) the
+    cache row of each token.
 
-    slot scalar: one dynamic_update_slice on the seq dim (lockstep decode).
-    slot (B,): each batch row writes its OWN slot (paged slot pool) — a
-    vmapped single-row update, which lowers to a batch-aligned scatter
-    (per-row indices along the batch dim, so a batch-sharded cache stays
-    shard-local).
+    One scatter of the S·B new rows: inside the decode layer scan ``buf`` is
+    the carried (donated) pool, so XLA updates it in place.  The batch dim
+    is a scatter batching dim (each batch row writes only its own rows), so
+    a batch-sharded cache stays shard-local, and the pool keeps its layout.
+    A row past the capacity is dropped, not clamped onto the last rows.
     """
     with jax.named_scope("attn.kv_write"):
-        val = val.astype(buf.dtype)
-        if jnp.ndim(slot) == 1:
-            return jax.vmap(
-                lambda c, x, s: jax.lax.dynamic_update_slice_in_dim(
-                    c, x, s, axis=0))(buf, val, slot)
-        return jax.lax.dynamic_update_slice_in_dim(buf, val, slot, axis=1)
+        idx = jnp.stack([jnp.broadcast_to(layer, rows.shape), rows], axis=-1)
+        dims = jax.lax.ScatterDimensionNumbers(
+            update_window_dims=tuple(range(2, val.ndim)),
+            inserted_window_dims=(0, 2),
+            scatter_dims_to_operand_dims=(0, 2),
+            operand_batching_dims=(1,),
+            scatter_indices_batching_dims=(0,))
+        return jax.lax.scatter(buf, idx, val.astype(buf.dtype), dims)
 
 
-def apply_decode(p: dict, x: jax.Array, cfg: ModelConfig, cache_k: jax.Array,
-                 cache_v: jax.Array, pos: jax.Array, slots=None):
-    """Decode step of S ≥ 1 tokens: x (B, S, d); cache (B, C, Hkv, D); pos
-    scalar i32 or a (B,) per-slot position vector (continuous batching:
-    every batch row decodes at its own depth).  S > 1 is the speculative
-    verify step — row b's tokens land at positions pos[b]..pos[b]+S-1 and
-    the causal mask (key slot j visible iff j ≤ query position) keeps any
-    stale cache rows beyond the written range invisible.
+def _read_layer(cache: dict, layer, dtype):
+    """Layer ``layer`` of the stacked cache as K and V (B, C, Hkv, D)."""
+    at = lambda name: jax.lax.dynamic_index_in_dim(cache[name], layer, 0,
+                                                   keepdims=False)
+    if "k_scale" not in cache:
+        return at("k"), at("v")
+    with jax.named_scope("attn.scores"):
+        return (dequantize_kv(at("k"), at("k_scale"), dtype),
+                dequantize_kv(at("v"), at("v_scale"), dtype))
+
+
+def apply_decode(p: dict, x: jax.Array, cfg: ModelConfig, cache: dict,
+                 layer: jax.Array, pos: jax.Array, slots=None):
+    """Decode step of S ≥ 1 tokens for layer ``layer`` of the stacked cache.
+
+    x (B, S, d); cache: the whole stacked pool, {k, v: (L, B, C, Hkv, D)}
+    and, for the int8 KV cache (kv_cache_dtype='int8', §Perf), k_scale,
+    v_scale: f16 (L, B, C, Hkv).  pos scalar i32 or a (B,) per-slot
+    position vector (continuous batching: every batch row decodes at its
+    own depth).  S > 1 is the speculative verify step — row b's tokens land
+    at positions pos[b]..pos[b]+S-1 and the causal mask (key slot j visible
+    iff j ≤ query position) keeps any stale cache rows beyond the written
+    range invisible.
+
+    The new rows are written into the stacked pool first and layer
+    ``layer`` is read back after: no read of the old pool stays live across
+    the in-place write, which would make XLA copy the pool.
 
     slots: optional (task_ids, stacked-scale subtree) — mixed-task decode
     reads per-slot scale rows in every quantized linear (linear.apply).
     With S > 1 the caller passes task_ids already repeated per token.
 
-    Returns (out (B, S, d_model), new_cache_k, new_cache_v).
+    Returns (out (B, S, d_model), new_cache).
     """
-    cap = cache_k.shape[1]
+    b, s, _ = x.shape
     q, k, v = _qkv(p, x, cfg, slots=slots)
     if cfg.use_rope:
         q, k = _rope_decode(q, k, pos, cfg)
-    slot = jnp.mod(pos, cap) if cfg.swa_window is not None else pos
-    cache_k = _cache_write(cache_k, k, slot)
-    cache_v = _cache_write(cache_v, v, slot)
+    # token s of row b goes to row pos[b] + s (ring: modulo the capacity)
+    rows = jnp.broadcast_to(jnp.reshape(pos, (-1, 1)) + jnp.arange(s), (b, s))
+    if cfg.swa_window is not None:
+        rows = jnp.mod(rows, cache["k"].shape[2])
+    if "k_scale" in cache:
+        k8, ks = quantize_kv(k)
+        v8, vs = quantize_kv(v)
+        new = {"k": k8, "v": v8, "k_scale": ks, "v_scale": vs}
+    else:
+        new = {"k": k, "v": v}
+    cache = {name: _cache_write(buf, new[name], layer, rows)
+             for name, buf in cache.items()}
+    ck, cv = _read_layer(cache, layer, x.dtype)
     # visible = slots with index <= pos (ring: all written slots; dense: prefix)
-    o = _scores(q, cache_k, cache_v, cfg, offset=pos, impl=cfg.attn_impl)
-    return _out(p, o, cfg, slots), cache_k, cache_v
+    o = _scores(q, ck, cv, cfg, offset=pos, impl=cfg.attn_impl)
+    return _out(p, o, cfg, slots), cache
 
 
 def quantize_kv(t: jax.Array):
@@ -177,30 +210,6 @@ def quantize_kv(t: jax.Array):
 def dequantize_kv(q: jax.Array, scale: jax.Array, dtype):
     return (q.astype(jnp.float32) * scale.astype(jnp.float32)[..., None]
             ).astype(dtype)
-
-
-def apply_decode_q8(p: dict, x: jax.Array, cfg: ModelConfig, cache: dict,
-                    pos: jax.Array, slots=None):
-    """Decode step (S ≥ 1 tokens) against an int8-quantized KV cache (§Perf
-    knob kv_cache_dtype='int8').  cache: {k, v: int8 (B,C,H,D); k_scale,
-    v_scale: f16 (B,C,H)}. pos scalar or (B,) per-slot.
-    Returns (out, new_cache)."""
-    cap = cache["k"].shape[1]
-    q, k, v = _qkv(p, x, cfg, slots=slots)
-    if cfg.use_rope:
-        q, k = _rope_decode(q, k, pos, cfg)
-    slot = jnp.mod(pos, cap) if cfg.swa_window is not None else pos
-    k8, ks = quantize_kv(k)
-    v8, vs = quantize_kv(v)
-    upd = lambda buf, val: _cache_write(buf, val, slot)
-    cache = {"k": upd(cache["k"], k8), "v": upd(cache["v"], v8),
-             "k_scale": upd(cache["k_scale"], ks),
-             "v_scale": upd(cache["v_scale"], vs)}
-    with jax.named_scope("attn.scores"):
-        kf = dequantize_kv(cache["k"], cache["k_scale"], x.dtype)
-        vf = dequantize_kv(cache["v"], cache["v_scale"], x.dtype)
-    o = _scores(q, kf, vf, cfg, offset=pos, impl=cfg.attn_impl)
-    return _out(p, o, cfg, slots), cache
 
 
 def apply_prefill(p: dict, x: jax.Array, cfg: ModelConfig, cap: int,

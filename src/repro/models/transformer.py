@@ -199,31 +199,27 @@ def _decode_tokens(params: dict, cache: dict, tokens: jax.Array,
     """
     h = common.embed_apply(params["embed"], tokens, cfg)
 
-    q8 = cfg.kv_cache_dtype == "int8"
     slotted = task_stack is not None
     if slotted and tokens.shape[1] > 1:
         # quantized linears flatten (B, S, d) row-major to M = B·S rows —
         # repeat each slot's task id per token to match
         task_ids = jnp.repeat(task_ids, tokens.shape[1])
 
-    def body(h, xs):
+    # The stacked KV pool rides in the carry, not in xs/ys: each layer
+    # writes only its new rows into it in place and reads its own layer
+    # back, so no layer's whole cache is sliced out or written back.
+    def body(carry, xs):
+        h, cache = carry
         if slotted:
-            layer_p, layer_stack, layer_cache = xs
+            layer_p, layer_stack, layer = xs
             slots = (task_ids, layer_stack)
         else:
-            layer_p, layer_cache = xs
+            layer_p, layer = xs
             slots = None
         hin = common.norm_apply(layer_p["ln1"], h, cfg)
-        if q8:
-            a, layer_cache = attention.apply_decode_q8(
-                layer_p["attn"], hin, cfg, layer_cache, pos,
-                slots=linear.slot_entry(slots, "attn"))
-        else:
-            a, ck, cv = attention.apply_decode(
-                layer_p["attn"], hin, cfg, layer_cache["k"],
-                layer_cache["v"], pos,
-                slots=linear.slot_entry(slots, "attn"))
-            layer_cache = {"k": ck, "v": cv}
+        a, cache = attention.apply_decode(
+            layer_p["attn"], hin, cfg, cache, layer, pos,
+            slots=linear.slot_entry(slots, "attn"))
         h = h + a
         hin = common.norm_apply(layer_p["ln2"], h, cfg)
         if "moe" in layer_p:
@@ -231,11 +227,12 @@ def _decode_tokens(params: dict, cache: dict, tokens: jax.Array,
         else:
             m = common.mlp_apply(layer_p["mlp"], hin, cfg,
                                  slots=linear.slot_entry(slots, "mlp"))
-        return h + m, layer_cache
+        return (h + m, cache), None
 
-    xs = (params["layers"], task_stack["layers"], cache) if slotted \
-        else (params["layers"], cache)
-    h, new_cache = jax.lax.scan(body, h, xs)
+    layers = jnp.arange(cache["k"].shape[0])
+    xs = (params["layers"], task_stack["layers"], layers) if slotted \
+        else (params["layers"], layers)
+    (h, new_cache), _ = jax.lax.scan(body, (h, cache), xs)
     h = common.norm_apply(params["final_norm"], h, cfg)
     head_slots = linear.slot_entry((task_ids, task_stack), "lm_head") \
         if slotted else None

@@ -199,10 +199,12 @@ def decode_step(params: dict, cache: dict, tokens: jax.Array, pos: jax.Array,
         pe = dec["pos"][pos][:, None]          # (B, 1, d) per-slot rows
     h = h + pe.astype(h.dtype)
 
-    def body(h, xs):
-        layer_p, ck, cv, xk, xv = xs
+    def body(carry, xs):
+        h, kv = carry
+        layer_p, xk, xv, layer = xs
         hin = common.norm_apply(layer_p["ln1"], h, cfg)
-        a, ck, cv = attention.apply_decode(layer_p["attn"], hin, cfg, ck, cv, pos)
+        a, kv = attention.apply_decode(layer_p["attn"], hin, cfg, kv, layer,
+                                       pos)
         h = h + a
         hin = common.norm_apply(layer_p["ln2"], h, cfg)
         from repro.models import linear
@@ -216,12 +218,14 @@ def decode_step(params: dict, cache: dict, tokens: jax.Array, pos: jax.Array,
                                  row_parallel=True)
         h = h + common.mlp_apply(
             layer_p["mlp"], common.norm_apply(layer_p["ln3"], h, cfg), cfg)
-        return h, {"k": ck, "v": cv}
+        return (h, kv), None
 
-    h, new_self = jax.lax.scan(
-        body, h, (dec["layers"], cache["k"], cache["v"], cache["xk"],
-                  cache["xv"]))
-    new_cache = dict(cache, k=new_self["k"], v=new_self["v"])
+    # the self-attention pool rides in the carry (transformer._decode_tokens)
+    (h, kv), _ = jax.lax.scan(
+        body, (h, {"k": cache["k"], "v": cache["v"]}),
+        (dec["layers"], cache["xk"], cache["xv"],
+         jnp.arange(cache["k"].shape[0])))
+    new_cache = dict(cache, **kv)
     h = common.norm_apply(dec["final_norm"], h, cfg)
     logits = common.head_apply({}, dec["embed"], h,
                                cfg.replace(tie_embeddings=True))

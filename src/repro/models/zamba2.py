@@ -165,8 +165,9 @@ def decode_step(params: dict, cache: dict, tokens: jax.Array, pos: jax.Array,
     e0 = h
     shared = params["shared"]
 
-    def group_body(h, xs):
-        group_p, ssm_g, conv_g, ck, cv = xs
+    def group_body(carry, xs):
+        h, kv = carry
+        group_p, ssm_g, conv_g, layer = xs
 
         def mamba_body(hh, inner):
             layer_p, s_l, c_l = inner
@@ -176,17 +177,21 @@ def decode_step(params: dict, cache: dict, tokens: jax.Array, pos: jax.Array,
         h, (ssm_g, conv_g) = jax.lax.scan(mamba_body, h, (group_p, ssm_g, conv_g))
         a_in = jnp.concatenate([h, e0], axis=-1)
         a_in = common.norm_apply(shared["ln1"], a_in, cfg)
-        a, ck, cv = attention.apply_decode(shared["attn"], a_in, cfg, ck, cv, pos)
+        a, kv = attention.apply_decode(shared["attn"], a_in, cfg, kv, layer,
+                                       pos)
         h = h + a
         h = h + common.mlp_apply(shared["mlp"],
                                  common.norm_apply(shared["ln2"], h, cfg), cfg)
-        return h, (ssm_g, conv_g, ck, cv)
+        return (h, kv), (ssm_g, conv_g)
 
-    h, (ssm, conv, ks_, vs_) = jax.lax.scan(
-        group_body, h,
+    # the shared attention's pool rides in the carry
+    # (transformer._decode_tokens); the small recurrent states stay xs/ys
+    (h, kv), (ssm, conv) = jax.lax.scan(
+        group_body, (h, {"k": cache["attn_k"], "v": cache["attn_v"]}),
         (params["mamba_groups"], cache["ssm"], cache["conv"],
-         cache["attn_k"], cache["attn_v"]))
-    new_cache = dict(cache, ssm=ssm, conv=conv, attn_k=ks_, attn_v=vs_)
+         jnp.arange(cache["attn_k"].shape[0])))
+    new_cache = dict(cache, ssm=ssm, conv=conv, attn_k=kv["k"],
+                     attn_v=kv["v"])
     if tail:
         def tail_body(hh, inner):
             layer_p, s_l, c_l = inner

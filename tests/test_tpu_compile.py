@@ -6,7 +6,8 @@ lower, VMEM limits.  Here each kernel is lowered and compiled for a
 described (not attached) v5e chip, and the compiled program must contain
 the kernel as a ``tpu_custom_call``.  Widths: d_model 2048, d_ff 8192, so
 the (N, K) pairs are the up/gate (8192, 2048) and down (2048, 8192)
-projections.
+projections.  The decode step is compiled whole too, to see that the
+compiler updates the donated KV pool in place.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process may load the TPU library at a time, and every pytest
@@ -98,3 +99,64 @@ def test_kernel_compiles_for_v5e(one_chip, name):
         call = functools.partial(fn, spec=spec)
     compiled = jax.jit(call).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _decode_program(one_chip, caller):
+    """``caller`` of a two-layer bf16 PEQA dense model, compiled for the
+    chip with the KV pool donated; returns (compiled, pool shapes).  Head
+    size 128 and a pool of 2 × 128 MiB, as the 7B cells' (32 slots of 2048
+    rows, 4 KV heads): a pool small enough for VMEM is staged there."""
+    from repro import configs
+    from repro.configs.base import QuantConfig, TuningConfig
+    from repro.core import policies
+    from repro.core.scale_bank import task_stack_dim
+    from repro.models import registry
+
+    cfg = configs.paper_lm(n_layers=2, d_model=1024, n_heads=8, d_ff=2048,
+                           vocab=1024).replace(
+        n_kv_heads=4, dtype="bfloat16", tuning=TuningConfig(mode="peqa"),
+        quant=QuantConfig(bits=4))
+    api = registry.build(cfg)
+    on_chip = lambda tree: jax.tree.map(
+        lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=one_chip),
+        tree)
+    params = jax.eval_shape(lambda r: policies.transform(api.init(r), cfg, r),
+                            jax.random.PRNGKey(0))
+    b, cap = 32, 2048
+    pool = jax.eval_shape(lambda: api.init_cache(b, cap))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
+    if caller == "decode_step":
+        args = (params, pool, i32(b, 1), i32())
+        donate = 1
+    else:
+        def stacked(kp, leaf):
+            if kp[-1].key not in ("scale", "zero"):
+                return None
+            d = task_stack_dim(leaf.ndim)
+            return jax.ShapeDtypeStruct(
+                leaf.shape[:d] + (3,) + leaf.shape[d:], leaf.dtype)
+        stack = jax.tree_util.tree_map_with_path(stacked, params)
+        args = (params, stack, pool, i32(b, 1), i32(b), i32(b))
+        donate = 2
+    fn = jax.jit(getattr(api, caller), donate_argnums=(donate,))
+    return fn.lower(*on_chip(args)).compile(), pool
+
+
+@pytest.mark.parametrize("caller", ["decode_step_slotted", "decode_step"])
+def test_decode_updates_kv_pool_in_place(one_chip, caller):
+    """The decode layer scan carries the donated pool and writes only the
+    new rows: no temporary as large as the pool, the whole pool aliased
+    to the output, and no copy that outputs a pool-shaped buffer."""
+    import re
+
+    compiled, pool = _decode_program(one_chip, caller)
+    pool_bytes = sum(l.size * l.dtype.itemsize
+                     for l in jax.tree.leaves(pool))
+    ma = compiled.memory_analysis()
+    assert ma.temp_size_in_bytes < pool_bytes, (ma.temp_size_in_bytes,
+                                                pool_bytes)
+    assert ma.alias_size_in_bytes == pool_bytes
+    shape = ",".join(map(str, pool["k"].shape))
+    copies = re.findall(rf"= bf16\[{shape}\]\{{[^}}]*\}} copy\(",
+                        compiled.as_text())
+    assert not copies, copies
