@@ -30,15 +30,46 @@ class QuantConfig:
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
+    """Token-choice top-k experts, dropless (every token's top k run)."""
+
     n_experts: int
     top_k: int
     n_shared_experts: int = 0          # DeepSeek-MoE shared experts
     d_ff_expert: Optional[int] = None  # defaults to ModelConfig.d_ff
-    capacity_factor: float = 1.25
     # 'expert': shard expert dim over 'model' (EP; needs n_experts % axis == 0)
     # 'tensor': shard each expert's d_ff over 'model' (TP-within-expert)
     expert_sharding: str = "tensor"
     router_aux_coef: float = 0.01      # load-balance loss
+    norm_topk_prob: bool = True        # renormalise the top-k gate weights
+    routed_scaling_factor: float = 1.0  # DeepSeek-V2: scales routed output
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2): keys and values are
+    up-projected per head from one ``kv_lora_rank``-wide latent, beside
+    one ``qk_rope_head_dim``-wide rotary key shared by all heads."""
+
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeScaling:
+    """YaRN rotary scaling (``rope_scaling`` of type ``yarn``)."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -83,6 +114,11 @@ class ModelConfig:
     tie_embeddings: bool = False
     swa_window: Optional[int] = None   # Mixtral / Mistral sliding window
     moe: Optional[MoEConfig] = None
+    # leading blocks with a dense d_ff MLP ahead of the MoE blocks
+    # (DeepSeek ``first_k_dense_replace``); stored under layers/dense
+    n_dense_layers: int = 0
+    mla: Optional[MLAConfig] = None
+    rope_scaling: Optional[RopeScaling] = None
     ssm: Optional[SSMConfig] = None
     attn_every: Optional[int] = None   # zamba2: shared attn block period
     slstm_every: Optional[int] = None  # xlstm: sLSTM block period (else mLSTM)

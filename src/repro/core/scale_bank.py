@@ -171,7 +171,9 @@ def stack_scales(base: Dict[str, np.ndarray],
     along frozen).  Each leaf gains a task dim just before the trailing
     (out, G) pair: a stacked-over-layers (L, N, G) leaf becomes (L, T, N, G),
     so ``lax.scan`` slices a (T, N, G) stack per layer — exactly the operand
-    ``quant_gemv_pallas``'s in-kernel task gather wants.  Returned NESTED
+    ``quant_gemv_pallas``'s in-kernel task gather wants; an expert-stacked
+    (L, E, N, G) leaf becomes (L, E, T, N, G), each layer's (E, T, N, G)
+    what ``quant_gmm_pallas`` takes.  Returned NESTED
     (mirroring the params tree pruned to scale leaves), host numpy.
     """
     flat = {}

@@ -62,6 +62,13 @@ HEAD_VECTOR_LEAVES = ("A_log", "ssm_D", "dt_bias")
 
 _LINEAR_LEAVES = ("w", "qw", "scale", "zero", "b")
 
+# the leading dense blocks of a MoE stack (``layers/dense``): their ``down``
+# is stored by OUTPUT rows like a column-parallel linear.  DeepSeek's
+# published d_ff 10944 packs into 1368 code words, which a 16-way axis
+# cannot split, and the quantized kernel splits such a weight by output
+# columns anyway (``ops._kernel_matmul``)
+DENSE_LEAD = "dense"
+
 
 def _mk(ndim: int, axis_at: int) -> P:
     """PartitionSpec with MODEL_AXIS at ``axis_at``, trailing Nones trimmed."""
@@ -107,7 +114,8 @@ def spec_for_path(path: str, ndim: int) -> P:
         return _mk(ndim, ndim - 2)
 
     if leaf in _LINEAR_LEAVES:
-        if parent in ROW_PARALLEL:
+        if parent in ROW_PARALLEL and not (parent == "down"
+                                           and DENSE_LEAD in parts):
             if leaf in ("w", "qw"):         # (…, out, in) — shard input
                 return _mk(ndim, ndim - 1)
             return P()                      # scale/zero/b: per-out-row

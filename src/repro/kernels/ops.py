@@ -291,6 +291,89 @@ def quant_matmul_slotted(
     return y.reshape(*lead, n)
 
 
+def _dequant_experts(qw, scale, zero, k, spec: QuantSpec, dtype):
+    """(E, K, N) dequantized expert weights (plain path)."""
+    w = jax.vmap(lambda q, s, z: _dequant(q, s, z, k, spec, dtype))(
+        qw, scale, zero)
+    return jnp.swapaxes(w, 1, 2)
+
+
+def _gqmm_plain(x2d, qw, scale, zero, group_sizes, spec: QuantSpec):
+    """Grouped matmul by ``ragged_dot`` over the dequantized experts: rows
+    past the groups' total come out zero."""
+    w = _dequant_experts(qw, scale, zero, x2d.shape[-1], spec, x2d.dtype)
+    return jax.lax.ragged_dot(x2d, w, group_sizes,
+                              preferred_element_type=jnp.float32
+                              ).astype(x2d.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
+def _gqmm(x2d, qw, scale, zero, group_sizes, tile_expert, tiles_used,
+          spec: QuantSpec, block_m: int, interpret: bool):
+    from repro.kernels import quant_matmul as _qm
+    return _qm.quant_gmm_pallas(x2d, qw, scale, zero, tile_expert,
+                                tiles_used, spec=spec, block_m=block_m,
+                                interpret=interpret)
+
+
+def _gqmm_fwd(x2d, qw, scale, zero, group_sizes, tile_expert, tiles_used,
+              spec, block_m, interpret):
+    y = _gqmm(x2d, qw, scale, zero, group_sizes, tile_expert, tiles_used,
+              spec, block_m, interpret)
+    return y, (x2d, qw, scale, zero, group_sizes)
+
+
+def _gqmm_bwd(spec, block_m, interpret, res, dy):
+    """The plain path's gradient: codes frozen, as in ``_qmm_bwd``."""
+    x2d, qw, scale, zero, group_sizes = res
+    _, vjp = jax.vjp(lambda x, s, z: _gqmm_plain(x, qw, s, z, group_sizes,
+                                                 spec), x2d, scale, zero)
+    dx, ds, dz = vjp(dy)
+    return dx, None, ds, dz, None, None, None
+
+
+_gqmm.defvjp(_gqmm_fwd, _gqmm_bwd)
+
+
+def grouped_quant_matmul(
+    x: jax.Array,            # (M, K) rows sorted by expert, bm-row tiles
+    qw: jax.Array,           # (E, N, K // 8) packed codes, one per expert
+    scale: jax.Array,        # (E, N, G) — or (E, T, N, G) with task_ids
+    zero: jax.Array,
+    group_sizes: jax.Array,  # (E,) int32 rows of each expert, padded to bm
+    tile_expert: jax.Array,  # (M // bm,) int32 expert of each tile
+    tiles_used: jax.Array,   # () int32 tiles that hold rows
+    spec: QuantSpec,
+    *,
+    block_m: int,
+    task_ids: Optional[jax.Array] = None,   # (M,) rows into the T stack
+    impl: Optional[str] = None,
+) -> jax.Array:
+    """Expert-grouped y[i] = x[i] @ Ŵ(e(i))ᵀ, where row i belongs to the
+    expert whose group holds it (``moe._group``).  Rows past the groups
+    are padding: the kernel leaves them unwritten and the plain path
+    zeroes them.  With ``task_ids`` (forward only) each row reads its
+    task's expert scales, as ``quant_matmul_slotted`` does for one weight.
+    Differentiable in (x, scale, zero) without ``task_ids``."""
+    impl = _check_impl(impl or default_impl())
+    if impl in ("pallas", "interpret"):
+        interpret = impl == "interpret"
+        if task_ids is None:
+            return _gqmm(x, qw, scale, zero, group_sizes, tile_expert,
+                         tiles_used, spec, block_m, interpret)
+        from repro.kernels import quant_matmul as _qm
+        return _qm.quant_gmm_pallas(
+            x, qw, scale, zero, tile_expert, tiles_used, spec=spec,
+            block_m=block_m, task_ids=task_ids, interpret=interpret)
+    if task_ids is None:
+        return _gqmm_plain(x, qw, scale, zero, group_sizes, spec)
+    y = jnp.zeros((x.shape[0], qw.shape[-2]), x.dtype)
+    for t in range(scale.shape[1]):
+        yt = _gqmm_plain(x, qw, scale[:, t], zero[:, t], group_sizes, spec)
+        y = jnp.where((task_ids == t)[:, None], yt, y)
+    return y
+
+
 def dequantize_op(qw, scale, zero, out_features_k: int, spec: QuantSpec,
                   dtype=jnp.bfloat16):
     """Materialize Ŵ (for export / QAT comparisons)."""

@@ -23,12 +23,17 @@ scale row is a lane-dense (1, bn) vector that broadcasts over the rows.
 With bf16 activations this is one MXU pass at full f32-accumulation
 accuracy; f32 activations take the f32 (HIGHEST) contraction.
 
-One kernel body serves both shapes (docs/KERNELS.md):
+One kernel body serves three shapes (docs/KERNELS.md):
 
   * ``quant_matmul_pallas`` — GEMM, grid (M/bm, N/bn).
   * ``quant_gemv_pallas``  — decode-shaped GEMV: a single M block, so the
     whole (M, K) activation block stays VMEM-resident and each packed
     ``qw`` word is streamed from HBM exactly once per call.
+  * ``quant_gmm_pallas``   — grouped over experts: rows sorted by expert in
+    ``bm``-row tiles, grid (tiles,), each tile against the whole (N, K)
+    codes of its expert.  Consecutive tiles of one expert keep the same
+    codes block, so Pallas fetches each touched expert's codes once a call
+    and never an untouched expert's.
 
 Each grid step holds a whole K row of codes (no K grid axis): a (bn, K/8)
 word block is 512 KiB at K = 8192, and the codes are unpacked one
@@ -60,6 +65,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.quant import PACK, PLANE_PACK, QuantSpec, tile_words
 
@@ -177,6 +183,9 @@ def _qmm_call(x, qw, scale, zero, task_ids, *, spec: QuantSpec, block_m: int,
     qw, st, zt, tids = operands(qw, scale, zero, task_ids, spec, m)
     n_tasks, g, _ = st.shape
     bm, bn = min(block_m, m), min(block_n, n)
+    if n % bn and n <= 8 * block_n:
+        bn = n      # one whole-width block (MLA's 576-wide W_kva) beats a
+        #             ragged edge block
     if spec.plane:
         qw_spec = pl.BlockSpec((spec.bits, bn, k // PLANE_PACK),
                                lambda i, j: (0, j, 0))
@@ -244,3 +253,101 @@ def quant_gemv_pallas(
                      block_m=x.shape[0], block_n=block_n,
                      out_dtype=out_dtype, interpret=interpret,
                      name="quant_gemv")
+
+
+class _FlatRows:
+    """A (T·G, N) scale/zero block read as (T, G, N) by ``qmm_tile``: the
+    tasks' group rows stacked on the sublane dim, with no padding of a
+    size-G dim to the sublane tile."""
+
+    def __init__(self, ref, groups: int):
+        self.ref, self.g = ref, groups
+        self.shape = (ref.shape[0] // groups, groups, ref.shape[1])
+
+    def __getitem__(self, idx):
+        t, rows, cols = idx
+        return self.ref[t * self.g + rows.start:t * self.g + rows.stop, cols]
+
+
+def _gmm_kernel(te_ref, used_ref, *refs, has_tids: bool, group: int,
+                groups: int, plane_bits: int):
+    if has_tids:
+        tid_ref, x_ref, qw_ref, s_ref, z_ref, o_ref = refs
+    else:
+        (x_ref, qw_ref, s_ref, z_ref, o_ref), tid_ref = refs, None
+
+    @pl.when(pl.program_id(0) < used_ref[0])
+    def _():
+        y = qmm_tile(x_ref, qw_ref, _FlatRows(s_ref, groups),
+                     _FlatRows(z_ref, groups), tid_ref, group=group,
+                     plane_bits=plane_bits)
+        o_ref[...] = y.astype(o_ref.dtype)
+
+
+# VMEM a grouped call may take: a whole expert's codes and its tasks'
+# scale rows, double-buffered (v5e has 128 MiB)
+GMM_VMEM_BYTES = 64 * 1024 * 1024
+
+
+@functools.partial(
+    jax.jit, static_argnames=("spec", "block_m", "out_dtype", "interpret"))
+def quant_gmm_pallas(
+    x: jax.Array,            # (M, K): rows sorted by expert, bm-row tiles
+    qw: jax.Array,           # (E, N, K // 8) — or (E, bits', N, K // 32)
+    scale: jax.Array,        # (E, N, G) — or (E, T, N, G) with task_ids
+    zero: jax.Array,         # same shape as scale
+    tile_expert: jax.Array,  # (M // bm,) int32: expert of each tile
+    tiles_used: jax.Array,   # () int32: tiles holding rows; the rest skip
+    *,
+    spec: QuantSpec,
+    block_m: int,
+    task_ids: jax.Array | None = None,   # (M,) int32 rows into the T stack
+    out_dtype=None,
+    interpret: bool = False,
+) -> jax.Array:
+    """Grouped y[i] = x[i] @ Ŵ(tile_expert[i // bm])ᵀ;  grid (M / bm,).
+
+    Tiles past ``tiles_used`` point at the last used tile's expert (no new
+    codes are fetched) and compute nothing: their output rows are left
+    unwritten, and nothing may read them."""
+    m, k = x.shape
+    e = qw.shape[0]
+    n = qw.shape[-2]
+    bm = block_m
+    if m % bm:
+        raise ValueError(f"{m} rows are not whole {bm}-row tiles")
+    qw = jax.lax.bitcast_convert_type(qw, jnp.int32)
+    if task_ids is None:
+        scale, zero = scale[:, None], zero[:, None]
+    t_, g = scale.shape[1], scale.shape[-1]
+    # (E, T, N, G) → (E, T·G, N): a group's scale row is lane-dense
+    st = jnp.swapaxes(scale.astype(jnp.float32), -1, -2).reshape(e, t_ * g, n)
+    zt = jnp.swapaxes(zero.astype(jnp.float32), -1, -2).reshape(e, t_ * g, n)
+    if spec.plane:
+        qw_spec = pl.BlockSpec((None, spec.bits, n, k // PLANE_PACK),
+                               lambda i, te, nu: (te[i], 0, 0, 0))
+    else:
+        qw_spec = pl.BlockSpec((None, n, k // PACK),
+                               lambda i, te, nu: (te[i], 0, 0))
+    sz_spec = pl.BlockSpec((None, t_ * g, n), lambda i, te, nu: (te[i], 0, 0))
+    in_specs = [pl.BlockSpec((bm, k), lambda i, te, nu: (i, 0)),
+                qw_spec, sz_spec, sz_spec]
+    args = [x, qw, st, zt]
+    if task_ids is not None:
+        in_specs.insert(0, pl.BlockSpec((bm, 1), lambda i, te, nu: (i, 0)))
+        args.insert(0, task_ids.astype(jnp.int32).reshape(m, 1))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(m // bm,), in_specs=in_specs,
+        out_specs=pl.BlockSpec((bm, n), lambda i, te, nu: (i, 0)))
+    return pl.pallas_call(
+        functools.partial(_gmm_kernel, has_tids=task_ids is not None,
+                          group=k // g, groups=g,
+                          plane_bits=spec.bits if spec.plane else 0),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((m, n), out_dtype or x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=GMM_VMEM_BYTES),
+        interpret=interpret,
+        name="quant_gmm",
+    )(tile_expert.astype(jnp.int32),
+      jnp.reshape(tiles_used, (1,)).astype(jnp.int32), *args)

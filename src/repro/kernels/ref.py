@@ -125,7 +125,8 @@ def flash_attention_ref(q, k, v, *, causal: bool = True, window: int | None = No
                         scale: float | None = None, offset=None):
     """Reference (GQA-aware) attention.
 
-    q: (B, Sq, Hq, D), k/v: (B, Sk, Hkv, D). Hq % Hkv == 0.
+    q: (B, Sq, Hq, D), k: (B, Sk, Hkv, D), v: (B, Sk, Hkv, Dv).
+    Hq % Hkv == 0.
     window: sliding-window size (Mistral/Mixtral SWA) — key j visible to
     query i iff i - window < j <= i (causal).
     offset: absolute position of query 0; key slot j is at absolute position
@@ -163,4 +164,4 @@ def flash_attention_ref(q, k, v, *, causal: bool = True, window: int | None = No
     p = jax.nn.softmax(logits, axis=-1)
     p = jnp.where(jnp.isnan(p), 0.0, p)  # fully-masked rows
     out = jnp.einsum("bhrqk,bkhd->bqhrd", p, vf)
-    return out.reshape(b, sq, hq, d).astype(q.dtype)
+    return out.reshape(b, sq, hq, vf.shape[-1]).astype(q.dtype)

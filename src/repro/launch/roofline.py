@@ -48,8 +48,10 @@ def _matrix_params(cfg):
         shared = mc.n_shared_experts * expert
         routed_total = mc.n_experts * expert
         routed_active = mc.top_k * expert
-        n_tot = L * (attn + shared + routed_total)
-        n_act = L * (attn + shared + routed_active)
+        k = cfg.n_dense_layers                     # leading dense blocks
+        dense = k * (attn + 3 * d * dff)
+        n_tot = dense + (L - k) * (attn + shared + routed_total)
+        n_act = dense + (L - k) * (attn + shared + routed_active)
         return n_tot, n_act
     if cfg.family == "hybrid":
         ssm = cfg.ssm

@@ -1,6 +1,8 @@
 """Shared neural building blocks: norms, RoPE, MLPs, embeddings."""
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -38,8 +40,48 @@ def norm_apply(p: dict, x: jax.Array, cfg: ModelConfig) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 def rope_freqs(cfg: ModelConfig, d: int | None = None) -> jax.Array:
+    """Inverse frequencies of a ``d``-wide rotary embedding; YaRN-blended
+    when ``cfg.rope_scaling`` is set (``yarn_freqs``)."""
     d = d or cfg.d_head
+    if cfg.rope_scaling is not None:
+        return yarn_freqs(cfg.rope_theta, d, cfg.rope_scaling)
     return 1.0 / (cfg.rope_theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature: ``0.1·mscale·ln(factor) + 1``."""
+    if factor <= 1 or not mscale:
+        return 1.0
+    return 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_freqs(theta: float, d: int, rs) -> jax.Array:
+    """YaRN inverse frequencies (Peng et al. 2023, as DeepSeek-V2 has
+    them): dims that turn more than ``beta_fast`` times over the original
+    context keep their frequency, dims that turn fewer than ``beta_slow``
+    times are divided by ``factor``, and a linear ramp blends between."""
+    def dim_at(turns):
+        return (d * math.log(rs.original_max_position_embeddings
+                             / (turns * 2 * math.pi))) / (2 * math.log(theta))
+    lo = max(math.floor(dim_at(rs.beta_fast)), 0)
+    hi = min(math.ceil(dim_at(rs.beta_slow)), d - 1)
+    if lo == hi:
+        hi += 0.001
+    base = theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ramp = jnp.clip((jnp.arange(d // 2, dtype=jnp.float32) - lo) / (hi - lo),
+                    0.0, 1.0)
+    keep = 1.0 - ramp
+    return (1.0 / (rs.factor * base)) * (1.0 - keep) + (1.0 / base) * keep
+
+
+def rope_mscale(cfg: ModelConfig) -> float:
+    """Factor on the rotated cos/sin (YaRN's ``mscale`` over
+    ``mscale_all_dim``; 1 without scaling)."""
+    rs = cfg.rope_scaling
+    if rs is None:
+        return 1.0
+    return (yarn_mscale(rs.factor, rs.mscale)
+            / yarn_mscale(rs.factor, rs.mscale_all_dim))
 
 
 def apply_rope(x: jax.Array, positions: jax.Array, freqs: jax.Array) -> jax.Array:

@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig, ShapeConfig
-from repro.models import attention, linear, transformer, whisper, xlstm, zamba2
+from repro.models import linear, transformer, whisper, xlstm, zamba2
 
 
 def _scoped(cfg: ModelConfig, fn):
@@ -55,6 +55,8 @@ class FamilyCaps:
         input) and is additionally gated on no sliding-window ring.
       * ``slotted_reason`` — why ``decode_step_slotted`` is None (the
         resident scheduler's refusal message); None = supported.
+      * ``mesh_slotted_reason`` — why the slotted steps, present, cannot
+        run under a mesh (the refusal message there); None = they can.
       * ``verify_reason`` — why ``decode_verify`` is unusable (the
         speculative scheduler's refusal message); None = supported.
     """
@@ -64,6 +66,7 @@ class FamilyCaps:
     prefix_positions: bool = False
     bucketable: bool = False
     slotted_reason: Optional[str] = None
+    mesh_slotted_reason: Optional[str] = None
     verify_reason: Optional[str] = None
 
 
@@ -76,8 +79,9 @@ class ModelAPI:
     decode_step: Callable     # (params, cache, tokens, pos) -> (logits, cache)
     init_cache: Callable      # (batch, seq_len) -> cache pytree
     # (params, task_stack, cache, tokens, pos, task_ids) -> (logits, cache)
-    # mixed-task decode against (T, …)-stacked scales; None for families that
-    # cannot thread per-slot scales (MoE's shard_map'd experts, SSM, encdec)
+    # mixed-task decode against (T, …)-stacked scales, with the step's MoE
+    # routing stats (4,) int32 after them for a MoE family; None for
+    # families that cannot thread per-slot scales (SSM, encdec)
     decode_step_slotted: Optional[Callable] = None
     # (params, task_stack, batch, task_ids) -> (last_logits, cache): prefill
     # reading per-row scales from the resident stack (no live-scale swap at
@@ -144,26 +148,33 @@ def build(cfg: ModelConfig) -> ModelAPI:
                                        prefix_embeds=batch.get("image_embeds"),
                                        last_pos=batch.get("last_pos"))
 
-        moe_slotted = ("MoE expert dispatch cannot thread per-slot scales "
-                       "(no slotted decode step)")
+        moe_cfg = cfg.moe is not None
         caps = FamilyCaps(
             positional=True, bucketable=True,
             prefix_key="image_embeds" if fam == "vlm" else None,
             prefix_positions=fam == "vlm",
-            slotted_reason=moe_slotted if cfg.moe is not None else None,
+            mesh_slotted_reason=("MoE experts read per-slot task scales "
+                                 "off the mesh only") if moe_cfg else None,
             verify_reason=("MoE expert dispatch is not supported in the "
-                           "verify step") if cfg.moe is not None else None)
+                           "verify step") if moe_cfg else None)
+
+        def slotted_step(p, st, c, t, pos, tid):
+            # a MoE step also returns its routing stats, which the engine
+            # reads with the step's tokens
+            logits, cache, stats = transformer.decode_tokens(
+                p, c, t, pos, cfg, task_stack=st, task_ids=tid)
+            out = (logits[:, 0], cache)
+            return out + (stats,) if moe_cfg else out
+
         return ModelAPI(
             cfg=cfg,
             init=lambda rng: transformer.init(rng, cfg),
             loss_fn=_scoped(cfg, lambda p, b: transformer.loss_fn(p, b, cfg)),
             prefill=_scoped(cfg, prefill_fn),
             decode_step=_scoped(cfg, lambda p, c, t, pos: transformer.decode_step(p, c, t, pos, cfg)),
-            init_cache=lambda b, s: attention.init_cache(cfg, b, s),
-            decode_step_slotted=None if cfg.moe is not None else _scoped(
-                cfg, lambda p, st, c, t, pos, tid: transformer.decode_step(
-                    p, c, t, pos, cfg, task_stack=st, task_ids=tid)),
-            prefill_slotted=None if cfg.moe is not None else _scoped(
+            init_cache=lambda b, s: transformer.init_cache(cfg, b, s),
+            decode_step_slotted=_scoped(cfg, slotted_step),
+            prefill_slotted=_scoped(
                 cfg, lambda p, st, b, tid: transformer.prefill(
                     p, b["tokens"], cfg,
                     prefix_embeds=b.get("image_embeds"),
@@ -172,7 +183,7 @@ def build(cfg: ModelConfig) -> ModelAPI:
             decode_verify=_scoped(
                 cfg, lambda p, c, t, pos: transformer.decode_verify(
                     p, c, t, pos, cfg)),
-            decode_verify_slotted=None if cfg.moe is not None else _scoped(
+            decode_verify_slotted=_scoped(
                 cfg, lambda p, st, c, t, pos, tid: transformer.decode_verify(
                     p, c, t, pos, cfg, task_stack=st, task_ids=tid)),
             caps=caps,

@@ -16,28 +16,41 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.dist import context as dctx
 from repro.kernels import ops
-from repro.models import attention, common, linear, moe
-from repro.models.common import apply_rope
+from repro.models import attention, common, linear, mla, moe
 
 
-def _block_init(rng, cfg: ModelConfig) -> dict:
+def _attn(cfg: ModelConfig):
+    """The attention module of a block: latent (``mla``) or GQA."""
+    return mla if cfg.mla is not None else attention
+
+
+def _block_init(rng, cfg: ModelConfig, dense: bool = False) -> dict:
     ks = jax.random.split(rng, 3)
     p = {
         "ln1": common.norm_init(cfg),
-        "attn": attention.init(ks[0], cfg),
+        "attn": _attn(cfg).init(ks[0], cfg),
         "ln2": common.norm_init(cfg),
     }
-    if cfg.moe is not None:
+    if cfg.moe is not None and not dense:
         p["moe"] = moe.init(ks[1], cfg)
     else:
         p["mlp"] = common.mlp_init(ks[1], cfg)
     return p
 
 
+def n_dense(cfg: ModelConfig) -> int:
+    """Leading blocks with a dense MLP ahead of the MoE blocks."""
+    return cfg.n_dense_layers if cfg.moe is not None else 0
+
+
 def init(rng, cfg: ModelConfig) -> dict:
     ks = jax.random.split(rng, 4)
-    layer_rngs = jax.random.split(ks[0], cfg.n_layers)
+    k = n_dense(cfg)
+    layer_rngs = jax.random.split(ks[0], cfg.n_layers - k)
     layers = jax.vmap(lambda r: _block_init(r, cfg))(layer_rngs)
+    if k:
+        layers["dense"] = jax.vmap(lambda r: _block_init(r, cfg, True))(
+            jax.random.split(ks[3], k))
     params = {
         "embed": common.embed_init(ks[1], cfg),
         "layers": layers,
@@ -47,10 +60,23 @@ def init(rng, cfg: ModelConfig) -> dict:
     return params
 
 
+def stacks(layers: dict) -> list:
+    """The layer stacks in order: the leading dense blocks
+    (``layers/dense``, where a config has them), then the main blocks."""
+    if "dense" not in layers:
+        return [layers]
+    return [layers["dense"],
+            {k: v for k, v in layers.items() if k != "dense"}]
+
+
+def init_cache(cfg: ModelConfig, batch: int, seq_len: int) -> dict:
+    return _attn(cfg).init_cache(cfg, batch, seq_len)
+
+
 def _block_train(layer_p: dict, h: jax.Array, cfg: ModelConfig,
                  positions: Optional[jax.Array]):
     """Pre-norm block, full-sequence. Returns (h, aux_loss)."""
-    a = attention.apply_train(layer_p["attn"],
+    a = _attn(cfg).apply_train(layer_p["attn"],
                               common.norm_apply(layer_p["ln1"], h, cfg),
                               cfg, positions)
     if cfg.constrain_block_outputs:
@@ -60,7 +86,7 @@ def _block_train(layer_p: dict, h: jax.Array, cfg: ModelConfig,
     h = h + a
     hin = common.norm_apply(layer_p["ln2"], h, cfg)
     if "moe" in layer_p:
-        m, aux = moe.apply(layer_p["moe"], hin, cfg)
+        m, aux, _ = moe.apply(layer_p["moe"], hin, cfg)
     else:
         m, aux = common.mlp_apply(layer_p["mlp"], hin, cfg), 0.0
     if cfg.constrain_block_outputs:
@@ -97,7 +123,9 @@ def forward(params: dict, tokens: jax.Array, cfg: ModelConfig,
             policy=jax.checkpoint_policies.checkpoint_dots)
     elif cfg.remat in ("block", "full"):
         body_fn = jax.checkpoint(body, prevent_cse=False)
-    (h, aux), _ = jax.lax.scan(body_fn, (h, 0.0), params["layers"])
+    aux = 0.0
+    for stack in stacks(params["layers"]):
+        (h, aux), _ = jax.lax.scan(body_fn, (h, aux), stack)
     h = common.norm_apply(params["final_norm"], h, cfg)
     logits = common.head_apply(params, params["embed"], h, cfg)
     return logits, aux
@@ -121,7 +149,7 @@ def prefill(params: dict, tokens: jax.Array, cfg: ModelConfig,
             last_pos=None):
     """Prefill: forward over the prompt, building the KV cache.
 
-    task_stack/task_ids: same contract as ``_decode_tokens`` — the prompt's
+    task_stack/task_ids: same contract as ``decode_tokens`` — the prompt's
     quantized linears read each batch row's scales from the resident stack
     instead of the live tree, so admitting a request for a resident task
     needs NO host→device scale swap (``task_ids: (B,) int32`` stack rows).
@@ -153,24 +181,38 @@ def prefill(params: dict, tokens: jax.Array, cfg: ModelConfig,
             layer_p = xs
             slots = None
         hin = common.norm_apply(layer_p["ln1"], h, cfg)
-        a, ck, cv = attention.apply_prefill(
-            layer_p["attn"], hin, cfg, cap,
-            slots=linear.slot_entry(slots, "attn"))
+        if cfg.mla is not None:
+            a, entry = mla.apply_prefill(
+                layer_p["attn"], hin, cfg,
+                slots=linear.slot_entry(slots, "attn"))
+        else:
+            a, ck, cv = attention.apply_prefill(
+                layer_p["attn"], hin, cfg, cap,
+                slots=linear.slot_entry(slots, "attn"))
+            entry = attention.prefill_cache_entry(ck, cv, cfg)
         h = h + a
         hin = common.norm_apply(layer_p["ln2"], h, cfg)
         if "moe" in layer_p:
-            m, _ = moe.apply(layer_p["moe"], hin, cfg)
+            m, _, _ = moe.apply(
+                layer_p["moe"], hin, cfg,
+                slots=linear.slot_entry((task_ids, layer_stack), "moe")
+                if slotted else None)
         else:
             m = common.mlp_apply(layer_p["mlp"], hin, cfg,
                                  slots=linear.slot_entry(slots, "mlp"))
         h = dctx.constrain_tokens(h + m, cfg.seq_shard)
-        return h, attention.prefill_cache_entry(ck, cv, cfg)
+        return h, entry
 
     if cfg.remat in ("block", "full"):
         body = jax.checkpoint(body, prevent_cse=False)
-    xs = (params["layers"], task_stack["layers"]) if slotted \
-        else params["layers"]
-    h, cache = jax.lax.scan(body, h, xs)
+    caches = []
+    p_stacks = stacks(params["layers"])
+    t_stacks = stacks(task_stack["layers"]) if slotted else p_stacks
+    for ps, ts in zip(p_stacks, t_stacks):
+        h, c = jax.lax.scan(body, h, (ps, ts) if slotted else ps)
+        caches.append(c)
+    cache = caches[0] if len(caches) == 1 else jax.tree.map(
+        lambda *a: jnp.concatenate(a), *caches)
     h = common.norm_apply(params["final_norm"], h, cfg)
     # the head sees only the last (real) token: one row per batch element
     head_slots = linear.slot_entry((task_ids, task_stack), "lm_head") \
@@ -182,28 +224,32 @@ def prefill(params: dict, tokens: jax.Array, cfg: ModelConfig,
     return logits[:, 0], cache
 
 
-def _decode_tokens(params: dict, cache: dict, tokens: jax.Array,
+def decode_tokens(params: dict, cache: dict, tokens: jax.Array,
                    pos: jax.Array, cfg: ModelConfig,
                    task_stack: dict | None = None,
                    task_ids: jax.Array | None = None):
     """Shared decode body: tokens (B, S) at positions pos..pos+S-1 (per-slot
-    when pos is (B,)).  Returns (logits (B, S, V) f32, new_cache).
+    when pos is (B,)).  Returns (logits (B, S, V) f32, new_cache, stats):
+    stats (4,) int32 for a MoE config — MoE layers, and summed over them
+    the experts with a row, the rows routed and the fullest expert's rows —
+    else None.
 
     task_stack/task_ids (mixed-task continuous decode): ``task_stack``
     mirrors the params tree pruned to its scale/zero leaves with a task dim
     stacked in front of the trailing (out, G) dims (scale_bank.stack_scales),
     and ``task_ids: (B,) int32`` names the stack row each slot reads — the
     quantized linears gather per-slot scales in-kernel instead of the pool
-    draining for a scale swap.  MoE blocks are not supported slotted (their
-    shard_map'd expert dispatch runs the autodiff impl); registry gates this.
+    draining for a scale swap; MoE blocks give each routed row its slot's
+    task (off the mesh only).
     """
     h = common.embed_apply(params["embed"], tokens, cfg)
 
     slotted = task_stack is not None
+    row_ids = task_ids
     if slotted and tokens.shape[1] > 1:
         # quantized linears flatten (B, S, d) row-major to M = B·S rows —
         # repeat each slot's task id per token to match
-        task_ids = jnp.repeat(task_ids, tokens.shape[1])
+        row_ids = jnp.repeat(task_ids, tokens.shape[1])
 
     # The stacked KV pool rides in the carry, not in xs/ys: each layer
     # writes only its new rows into it in place and reads its own layer
@@ -212,33 +258,47 @@ def _decode_tokens(params: dict, cache: dict, tokens: jax.Array,
         h, cache = carry
         if slotted:
             layer_p, layer_stack, layer = xs
-            slots = (task_ids, layer_stack)
+            slots = (row_ids, layer_stack)
         else:
             layer_p, layer = xs
             slots = None
         hin = common.norm_apply(layer_p["ln1"], h, cfg)
-        a, cache = attention.apply_decode(
+        a, cache = _attn(cfg).apply_decode(
             layer_p["attn"], hin, cfg, cache, layer, pos,
             slots=linear.slot_entry(slots, "attn"))
         h = h + a
         hin = common.norm_apply(layer_p["ln2"], h, cfg)
+        stats = None
         if "moe" in layer_p:
-            m, _ = moe.apply(layer_p["moe"], hin, cfg)
+            # routing stats off the mesh only (None on it)
+            m, _, stats = moe.apply(
+                layer_p["moe"], hin, cfg,
+                slots=linear.slot_entry((task_ids, layer_stack), "moe")
+                if slotted else None)
         else:
             m = common.mlp_apply(layer_p["mlp"], hin, cfg,
                                  slots=linear.slot_entry(slots, "mlp"))
-        return (h + m, cache), None
+        return (h + m, cache), stats
 
-    layers = jnp.arange(cache["k"].shape[0])
-    xs = (params["layers"], task_stack["layers"], layers) if slotted \
-        else (params["layers"], layers)
-    (h, new_cache), _ = jax.lax.scan(body, (h, cache), xs)
+    p_stacks = stacks(params["layers"])
+    t_stacks = stacks(task_stack["layers"]) if slotted else p_stacks
+    first = 0
+    stats = None
+    for ps, ts in zip(p_stacks, t_stacks):
+        n = jax.tree.leaves(ps)[0].shape[0]
+        layers = jnp.arange(first, first + n)
+        first += n
+        xs = (ps, ts, layers) if slotted else (ps, layers)
+        (h, cache), st = jax.lax.scan(body, (h, cache), xs)
+        if st is not None:
+            stats = jnp.concatenate([jnp.array([n], jnp.int32),
+                                     st.sum(0).astype(jnp.int32)])
     h = common.norm_apply(params["final_norm"], h, cfg)
-    head_slots = linear.slot_entry((task_ids, task_stack), "lm_head") \
+    head_slots = linear.slot_entry((row_ids, task_stack), "lm_head") \
         if slotted else None
     logits = common.head_apply(params, params["embed"], h, cfg,
                                slots=head_slots)
-    return logits, new_cache
+    return logits, cache, stats
 
 
 def decode_step(params: dict, cache: dict, tokens: jax.Array, pos: jax.Array,
@@ -246,9 +306,9 @@ def decode_step(params: dict, cache: dict, tokens: jax.Array, pos: jax.Array,
                 task_ids: jax.Array | None = None):
     """One decode step. tokens (B, 1); pos scalar int32 (next position) or
     (B,) per-slot.  Returns (logits (B, V) f32, new_cache).
-    See ``_decode_tokens`` for the task_stack/task_ids slotted contract."""
-    logits, new_cache = _decode_tokens(params, cache, tokens, pos, cfg,
-                                       task_stack, task_ids)
+    See ``decode_tokens`` for the task_stack/task_ids slotted contract."""
+    logits, new_cache, _ = decode_tokens(params, cache, tokens, pos, cfg,
+                                         task_stack, task_ids)
     return logits[:, 0], new_cache
 
 
@@ -268,5 +328,5 @@ def decode_verify(params: dict, cache: dict, tokens: jax.Array,
 
     Returns (logits (B, S, V) f32, new_cache).
     """
-    return _decode_tokens(params, cache, tokens, pos, cfg, task_stack,
-                          task_ids)
+    return decode_tokens(params, cache, tokens, pos, cfg, task_stack,
+                          task_ids)[:2]

@@ -220,7 +220,7 @@ def decode_step(params: dict, cache: dict, tokens: jax.Array, pos: jax.Array,
             layer_p["mlp"], common.norm_apply(layer_p["ln3"], h, cfg), cfg)
         return (h, kv), None
 
-    # the self-attention pool rides in the carry (transformer._decode_tokens)
+    # the self-attention pool rides in the carry (transformer.decode_tokens)
     (h, kv), _ = jax.lax.scan(
         body, (h, {"k": cache["k"], "v": cache["v"]}),
         (dec["layers"], cache["xk"], cache["xv"],

@@ -185,7 +185,7 @@ def decode_step(params: dict, cache: dict, tokens: jax.Array, pos: jax.Array,
         return (h, kv), (ssm_g, conv_g)
 
     # the shared attention's pool rides in the carry
-    # (transformer._decode_tokens); the small recurrent states stay xs/ys
+    # (transformer.decode_tokens); the small recurrent states stay xs/ys
     (h, kv), (ssm, conv) = jax.lax.scan(
         group_body, (h, {"k": cache["attn_k"], "v": cache["attn_v"]}),
         (params["mamba_groups"], cache["ssm"], cache["conv"],

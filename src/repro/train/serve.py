@@ -71,6 +71,12 @@ from repro.serve import telemetry
 __all__ = ["Engine", "Request", "RequestMetrics", "ServeConfig",
            "ServeReport", "SlotPool"]
 
+# attributes of a slotted MoE step's ``engine.step`` span, in the order of
+# the step's routing stats (``transformer.decode_tokens``)
+MOE_STEP_STATS = ("moe_layers", "experts_hit", "expert_rows",
+                  "expert_rows_max")
+
+
 class SlotPool:
     """Paged slot pool: a fixed pool of ``n_slots`` sequence slots.
 
@@ -245,14 +251,14 @@ class Engine:
         fn = self._mesh_scoped(fn)
 
         def wrapped(*args):
-            logits, cache = fn(*args)
+            logits, cache, *rest = fn(*args)
             b = logits.shape[0]
             spec = (ctx.logits_sharding(b) if ls
                     else ctx.sharding(ctx.batch_axes(b), None))
             logits = jax.lax.with_sharding_constraint(logits, spec)
             cache = jax.tree.map(jax.lax.with_sharding_constraint,
                                  cache, self._cache_shardings(cache, b))
-            return logits, cache
+            return (logits, cache, *rest)
         return wrapped
 
     def _mesh_scoped(self, fn):
@@ -339,8 +345,6 @@ class Engine:
             return caps.verify_reason
         if self.api.decode_verify is None:
             return "family has no multi-token verify step (decode_verify)"
-        if getattr(cfg, "moe", None) is not None:
-            return "MoE expert dispatch is not supported in the verify step"
         if getattr(cfg, "swa_window", None) is not None:
             return ("sliding-window ring cache: rejected draft rows would "
                     "alias committed slots")
@@ -841,10 +845,11 @@ class Engine:
         with telemetry.span(
                 "engine.step", active=n_active,
                 kv_rows_live=int(pool.pos[pool.active].sum()) + n_active,
-                kv_rows_read=pool.n_slots * pool.cache_len):
+                kv_rows_read=pool.n_slots * pool.cache_len) as sp:
             tok, pos, act, tid = self._pool_inputs(pool)
+            extra = []          # a MoE step's routing stats
             if pool.slotted:
-                logits, pool.cache = self._slotted_decode_fn()(
+                logits, pool.cache, *extra = self._slotted_decode_fn()(
                     self.params, self.resident.stack, pool.cache, tok, pos,
                     tid)
             else:
@@ -852,7 +857,10 @@ class Engine:
                                                   tok, pos)
             t, tok2d, npos = self._stepper(pool.n_slots)(logits, act, pos)
             with telemetry.span("engine.step.wait"):
-                nxt = np.asarray(t)
+                # the routing stats come with the tokens: one read
+                nxt, extra = jax.device_get((t, extra))
+            if extra and extra[0] is not None:
+                sp.set(**dict(zip(MOE_STEP_STATS, map(int, extra[0]))))
             pool._dev = (tok2d, npos, act, tid)
             pool.steps += 1
             for slot in np.flatnonzero(pool.active):
@@ -880,9 +888,21 @@ class Engine:
         vacuously tasked — the resolved policy must still be reported
         honestly, see the empty-return in ``serve``)."""
         return (self.bank is not None
-                and self.api.decode_step_slotted is not None
-                and self.api.prefill_slotted is not None
+                and self._slotted_reason() is None
                 and all(r.task is not None for r in requests))
+
+    def _slotted_reason(self) -> Optional[str]:
+        """Why this engine cannot run the slotted (resident-stack) prefill
+        and decode steps, or None when it can."""
+        caps = getattr(self.api, "caps", None)
+        if (self.api.decode_step_slotted is None
+                or self.api.prefill_slotted is None):
+            return (caps.slotted_reason if caps is not None
+                    and caps.slotted_reason
+                    else "family has no slotted decode step")
+        if self.ctx is not None and caps is not None:
+            return caps.mesh_slotted_reason
+        return None
 
     def _ensure_resident(self, resident_tasks: int) -> ResidentStack:
         cap = max(2, min(int(resident_tasks), len(self.bank.tasks)))
@@ -1005,13 +1025,9 @@ class Engine:
                         and not (use_spec
                                  and self.api.decode_verify_slotted is None))
         if cfg.scheduler == "resident" and not use_resident:
-            caps = getattr(self.api, "caps", None)
             missing = ("no ScaleBank attached" if self.bank is None
-                       else (caps.slotted_reason
-                             if caps is not None and caps.slotted_reason
-                             else "family has no slotted decode step")
-                       if self.api.decode_step_slotted is None
-                       else "not every request names a task")
+                       else self._slotted_reason()
+                       or "not every request names a task")
             raise ValueError(f"scheduler='resident' unsupported here: "
                              f"{missing}")
         sched_name = ("speculative" if use_spec

@@ -1,6 +1,6 @@
 """The decode step writes only its new K/V rows into the stacked KV pool.
 
-``transformer._decode_tokens`` carries the whole (L, B, C, Hkv, D) pool
+``transformer.decode_tokens`` carries the whole (L, B, C, Hkv, D) pool
 through its layer scan; each layer writes its new rows in place and reads
 its own layer back.  Every decode caller shares that path, so each case
 below runs one decode step through a different caller and checks:

@@ -62,14 +62,10 @@ def test_arch_smoke_decode_step(arch):
 @pytest.mark.parametrize("arch", ["llama3.2-1b", "qwen2-7b", "mixtral-8x7b"])
 def test_dense_prefill_decode_matches_forward(arch):
     """prefill(t[:s]) + decode steps == teacher-forced forward logits."""
-    import dataclasses
+    # MoE routing is dropless, so the single-token decode dispatch routes
+    # exactly as the full-sequence forward does
     cfg = configs.make_tiny(configs.get_config(arch)).replace(
         tuning=TuningConfig(mode="full"), swa_window=None)
-    if cfg.moe is not None:
-        # exact decode↔forward equality needs drop-free routing (capacity
-        # differs between full-seq and single-token dispatch)
-        cfg = cfg.replace(moe=dataclasses.replace(cfg.moe,
-                                                  capacity_factor=16.0))
     api = registry.build(cfg)
     p = api.init(RNG)
     B, S = 2, 12
@@ -171,14 +167,15 @@ def test_vlm_prefix_loss_alignment():
 
 
 def test_moe_capacity_drops_are_bounded():
-    """With capacity_factor ≥ 1 and uniform-ish routing, most assignments
-    survive; combine weights renormalized."""
+    """Dropless routing (no assignment is dropped, so the bound is zero):
+    the block's output is finite and the Switch aux loss of near-uniform
+    routing is about 1."""
     from repro.models import moe
     cfg = configs.make_tiny(configs.get_config("mixtral-8x7b")).replace(
         tuning=TuningConfig(mode="full"))
     p = moe.init(RNG, cfg)
     x = jax.random.normal(jax.random.PRNGKey(7), (2, 32, cfg.d_model)) * 0.5
-    y, aux = moe.apply(p, x, cfg)
+    y, aux, _ = moe.apply(p, x, cfg)
     assert y.shape == x.shape
     assert np.isfinite(np.asarray(y)).all()
     assert float(aux) > 0.5  # Switch aux ≈ 1 for near-uniform routing

@@ -47,6 +47,20 @@ def test_spec_rules_moe_and_ssm():
     assert sh.spec_for_path("layers/attn/wq/lora_b", 3) == P(None, "model")
 
 
+def test_spec_rules_leading_dense_down():
+    """A MoE stack's leading dense ``down`` is stored by output rows (its
+    packed d_ff need not split the model axis); every other ``down`` keeps
+    the row-parallel layout."""
+    assert sh.spec_for_path("layers/dense/mlp/down/qw", 3) == \
+        P(None, "model")
+    assert sh.spec_for_path("layers/dense/mlp/down/scale", 3) == \
+        P(None, "model")
+    assert sh.spec_for_path("layers/dense/mlp/up/qw", 3) == P(None, "model")
+    assert sh.spec_for_path("layers/mlp/down/qw", 3) == P(None, None, "model")
+    assert sh.spec_for_path("layers/moe/shared/down/qw", 3) == \
+        P(None, None, "model")
+
+
 @pytest.mark.parametrize("arch", configs.ARCHS)
 def test_full_configs_divide_production_mesh(arch):
     """Every param of every FULL config must divide the 16-way model axis.
@@ -173,7 +187,7 @@ _MOE_TEST = textwrap.dedent("""
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 8, 64), jnp.float32)
 
     def loss(p):
-        y, aux = moe.apply(p, x, cfg)
+        y, aux, _ = moe.apply(p, x, cfg)
         return jnp.sum(y * y) + aux, y
 
     def scale_grads(p):

@@ -1,4 +1,6 @@
-"""The main-path Pallas kernels compile for a TPU v5e at llama3.2-1b widths.
+"""The main-path Pallas kernels compile for a TPU v5e at llama3.2-1b widths
+(and the grouped expert kernel and MLA's 576-wide W_kva at
+DeepSeek-V2-Lite's).
 
 Interpret mode runs these kernels on the CPU, but it does not check what
 Mosaic refuses: unaligned blocks, unsupported casts, vector ops it cannot
@@ -97,6 +99,66 @@ def test_kernel_compiles_for_v5e(one_chip, name):
         call = lambda x, w, s, z, t: fn(x, w, s, z, task_ids=t, spec=spec)
     else:
         call = functools.partial(fn, spec=spec)
+    compiled = jax.jit(call).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+# DeepSeek-V2-Lite's MLA down-projection: 576 outputs (a 512-wide latent
+# and a 64-wide rope key), not a multiple of block_n = 128
+WKVA_CASES = {"gemv_wkva_576": ("gemv", 32, 0), "gemm_wkva_576": ("matmul",
+                                                                  512, 0),
+              "gemv_tasks_wkva_576": ("gemv", 32, 32)}
+
+
+@pytest.mark.parametrize("name", list(WKVA_CASES))
+def test_wkva_576_compiles_for_v5e(one_chip, name):
+    kern, m, n_tasks = WKVA_CASES[name]
+    spec = QuantSpec(bits=4)
+    n, k = 576, 2048
+    arg = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+    sshape = (n_tasks, n, 1) if n_tasks else (n, 1)
+    args = [arg((m, k), BF16), arg((n, k // PACK), jnp.uint32),
+            arg(sshape, F32), arg(sshape, F32)]
+    fn = qm.quant_gemv_pallas if kern == "gemv" else qm.quant_matmul_pallas
+    if n_tasks:
+        args.append(arg((m,), jnp.int32))
+        call = lambda x, w, s, z, t: fn(x, w, s, z, task_ids=t, spec=spec)
+    else:
+        call = functools.partial(fn, spec=spec)
+    compiled = jax.jit(call).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+# the grouped expert kernel at DeepSeek-V2-Lite's widths (d 2048, expert
+# 1408, 64 experts): gate/up at K 2048 and down at K 1408; decode's 32
+# slots × top-6 rows in 16-row tiles with 32 tasks' scales, and a 512-token
+# prefill's rows in 64-row tiles under one task's
+GMM_CASES = {
+    "gmm_decode_up_k2048": (1408, 2048, 32 * 6, 16, 32),
+    "gmm_decode_down_k1408": (2048, 1408, 32 * 6, 16, 32),
+    "gmm_prefill_up_k2048": (1408, 2048, 512 * 6, 64, 0),
+    "gmm_prefill_down_k1408": (2048, 1408, 512 * 6, 64, 0),
+}
+
+
+@pytest.mark.parametrize("name", list(GMM_CASES))
+def test_grouped_expert_kernel_compiles_for_v5e(one_chip, name):
+    n, k, rows, bm, n_tasks = GMM_CASES[name]
+    e = 64
+    spec = QuantSpec(bits=4)
+    m = bm * (rows // bm + min(e, rows))
+    arg = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+    sshape = (e, n_tasks, n, 1) if n_tasks else (e, n, 1)
+    args = [arg((m, k), BF16), arg((e, n, k // PACK), jnp.uint32),
+            arg(sshape, F32), arg(sshape, F32), arg((m // bm,), jnp.int32),
+            arg((), jnp.int32)]
+    if n_tasks:
+        args.append(arg((m,), jnp.int32))
+    call = lambda *a: qm.quant_gmm_pallas(
+        *a[:6], spec=spec, block_m=bm,
+        task_ids=a[6] if n_tasks else None)
     compiled = jax.jit(call).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
