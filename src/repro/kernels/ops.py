@@ -18,6 +18,13 @@ gradient): with  y = x·Ŵᵀ,  Ŵ = s·(q − z),
 
 The integer codes get no gradient — they are frozen by construction, which is
 the heart of PEQA (the optimizer additionally masks everything non-scale).
+
+Every quantized op also takes ``layer``: its ``qw``/``scale``/``zero`` then
+carry a leading layer axis and the op computes with layer ``layer`` of
+them.  The Pallas kernels read that layer's blocks in place (the decode
+layer scan's path: no layer is sliced out or copied); every other impl
+takes the layer out with ``at_layer`` and runs its plain path.  With the
+Pallas impls a layer-indexed call is forward-only.
 """
 from __future__ import annotations
 
@@ -86,6 +93,11 @@ def default_impl() -> str:
     return "pallas" if jax.default_backend() == "tpu" else "xla"
 
 
+def at_layer(a: jax.Array, layer) -> jax.Array:
+    """Layer ``layer`` of a layer-stacked array."""
+    return jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
+
+
 def _codes_f32(qw, k, spec: QuantSpec):
     if spec.plane:
         codes = unpack_codes_planes(qw, k, spec.bits)
@@ -103,7 +115,7 @@ def _dequant(qw, scale, zero, k, spec: QuantSpec, dtype):
 
 
 def _kernel_matmul(x2d, qw, scale, zero, task_ids, spec: QuantSpec,
-                   interpret: bool, row_parallel: bool = False):
+                   interpret: bool, row_parallel: bool = False, layer=None):
     """The Pallas path: GEMV for decode-shaped calls, GEMM otherwise.
 
     Inside a mesh scope (``dist.context.use_mesh``) the call runs under
@@ -114,17 +126,22 @@ def _kernel_matmul(x2d, qw, scale, zero, task_ids, spec: QuantSpec,
     columns by its codes and one f32 ``psum`` adds the partial outputs.
     Any other weight is split by output columns from the full activation
     rows: column-parallel weights are stored that way, and a row-parallel
-    one whose K split would cut a tile is resharded to it on entry.
+    one whose K split would cut a tile is resharded to it on entry.  A
+    layer index enters replicated; a stack's layer axis is never split.
     """
     from repro.kernels import quant_matmul as _qm
 
-    def call(x, w, s, z, *tid, out_dtype=None):
+    has_tids = task_ids is not None
+
+    def call(x, w, s, z, *rest, out_dtype=None):
         kern = (_qm.quant_gemv_pallas if x.shape[0] <= GEMV_MAX_M
                 else _qm.quant_matmul_pallas)
-        return kern(x, w, s, z, task_ids=tid[0] if tid else None,
+        return kern(x, w, s, z, task_ids=rest[0] if has_tids else None,
+                    layer=rest[-1] if layer is not None else None,
                     spec=spec, out_dtype=out_dtype, interpret=interpret)
 
-    args = (x2d, qw, scale, zero) + (() if task_ids is None else (task_ids,))
+    args = (x2d, qw, scale, zero) + (task_ids,) * has_tids
+    args += () if layer is None else (layer,)
     ctx = dctx.current()
     if ctx is None:
         return call(*args)
@@ -136,18 +153,20 @@ def _kernel_matmul(x2d, qw, scale, zero, task_ids, spec: QuantSpec,
     if (row_parallel and quant.splits_on_tiles(x2d.shape[-1], spec, parts)
             and (groups == 1 or groups % parts == 0)):
         sz = P(*slead, None, m if groups > 1 else None)
-        specs = (P(rows, m), P(*qlead, None, m), sz, sz, P(rows))
+        specs = (P(rows, m), P(*qlead, None, m), sz, sz)
+        specs += (P(rows),) * has_tids + (P(),) * (layer is not None)
 
         def partial_sums(*a):
             return jax.lax.psum(call(*a, out_dtype=jnp.float32), m)
         y = jax.shard_map(partial_sums, mesh=ctx.mesh,
-                          in_specs=specs[:len(args)], out_specs=P(rows, None),
+                          in_specs=specs, out_specs=P(rows, None),
                           check_vma=False)(*args)
         return y.astype(x2d.dtype)
     cols = m if qw.shape[-2] % parts == 0 else None
     sz = P(*slead, cols, None)
-    specs = (P(rows, None), P(*qlead, cols, None), sz, sz, P(rows))
-    return jax.shard_map(call, mesh=ctx.mesh, in_specs=specs[:len(args)],
+    specs = (P(rows, None), P(*qlead, cols, None), sz, sz)
+    specs += (P(rows),) * has_tids + (P(),) * (layer is not None)
+    return jax.shard_map(call, mesh=ctx.mesh, in_specs=specs,
                          out_specs=P(rows, cols), check_vma=False)(*args)
 
 
@@ -222,15 +241,23 @@ def quant_matmul(
     impl: Optional[str] = None,
     bf16_reduce: bool = False,
     row_parallel: bool = False,
+    layer: Optional[jax.Array] = None,
 ) -> jax.Array:
     """y = x @ Ŵᵀ for arbitrary leading batch dims on x.  Differentiable in
     (x, scale, zero); integer codes are frozen.  ``row_parallel`` marks a
     weight stored K-sharded on a mesh (``dist.sharding.ROW_PARALLEL``);
-    only the Pallas path reads it (``_kernel_matmul``)."""
+    only the Pallas path reads it (``_kernel_matmul``).  ``layer``: the
+    operands are layer-stacked (module docstring)."""
     impl = _check_impl(impl or default_impl())
     lead = x.shape[:-1]
     k = x.shape[-1]
     x2d = x.reshape(-1, k)
+    if layer is not None and impl in ("pallas", "interpret"):
+        y = _kernel_matmul(x2d, qw, scale, zero, None, spec,
+                           impl == "interpret", row_parallel, layer)
+        return y.reshape(*lead, y.shape[-1])
+    if layer is not None:
+        qw, scale, zero = (at_layer(a, layer) for a in (qw, scale, zero))
     if impl == "autodiff":
         # plain expression: autodiff handles scale/zero grads; codes frozen
         w = _dequant(qw, scale, zero, k, spec, x2d.dtype)
@@ -255,8 +282,10 @@ def quant_matmul_slotted(
     impl: Optional[str] = None,
     bf16_reduce: bool = False,
     row_parallel: bool = False,
+    layer: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Mixed-task y[i] = x[i] @ Ŵ(task_ids[i])ᵀ — forward-only (serving).
+    ``layer``: the operands are layer-stacked (module docstring).
 
     Slot i's output is BITWISE what ``quant_matmul`` yields when the live
     scale set is ``scale_stack[task_ids[i]]``: each backend computes every
@@ -268,15 +297,19 @@ def quant_matmul_slotted(
     impl = _check_impl(impl or default_impl())
     lead = x.shape[:-1]
     k = x.shape[-1]
-    n = qw.shape[1] if spec.plane else qw.shape[0]
+    n = qw.shape[-2]
     x2d = x.reshape(-1, k)
     if x2d.shape[0] != task_ids.shape[0]:
         raise ValueError(
             f"task_ids has {task_ids.shape[0]} rows for {x2d.shape[0]} slots")
     if impl in ("pallas", "interpret"):
         y = _kernel_matmul(x2d, qw, scale_stack, zero_stack, task_ids, spec,
-                           impl == "interpret", row_parallel)
-    elif impl == "ref":
+                           impl == "interpret", row_parallel, layer)
+        return y.reshape(*lead, n)
+    if layer is not None:
+        qw, scale_stack, zero_stack = (
+            at_layer(a, layer) for a in (qw, scale_stack, zero_stack))
+    if impl == "ref":
         y = _ref.quant_matmul_tasks_ref(
             x2d, qw, scale_stack, zero_stack, task_ids, (n, k), spec)
     else:  # xla / autodiff: per-task plain-path dot + bitwise-exact select
@@ -348,23 +381,28 @@ def grouped_quant_matmul(
     block_m: int,
     task_ids: Optional[jax.Array] = None,   # (M,) rows into the T stack
     impl: Optional[str] = None,
+    layer: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Expert-grouped y[i] = x[i] @ Ŵ(e(i))ᵀ, where row i belongs to the
     expert whose group holds it (``moe._group``).  Rows past the groups
     are padding: the kernel leaves them unwritten and the plain path
     zeroes them.  With ``task_ids`` (forward only) each row reads its
     task's expert scales, as ``quant_matmul_slotted`` does for one weight.
-    Differentiable in (x, scale, zero) without ``task_ids``."""
+    Differentiable in (x, scale, zero) without ``task_ids``, and on the
+    Pallas impls without ``layer`` (module docstring)."""
     impl = _check_impl(impl or default_impl())
-    if impl in ("pallas", "interpret"):
+    if layer is not None and impl not in ("pallas", "interpret"):
+        qw, scale, zero = (at_layer(a, layer) for a in (qw, scale, zero))
+    elif impl in ("pallas", "interpret"):
         interpret = impl == "interpret"
-        if task_ids is None:
+        if task_ids is None and layer is None:
             return _gqmm(x, qw, scale, zero, group_sizes, tile_expert,
                          tiles_used, spec, block_m, interpret)
         from repro.kernels import quant_matmul as _qm
         return _qm.quant_gmm_pallas(
             x, qw, scale, zero, tile_expert, tiles_used, spec=spec,
-            block_m=block_m, task_ids=task_ids, interpret=interpret)
+            block_m=block_m, task_ids=task_ids, layer=layer,
+            interpret=interpret)
     if task_ids is None:
         return _gqmm_plain(x, qw, scale, zero, group_sizes, spec)
     y = jnp.zeros((x.shape[0], qw.shape[-2]), x.dtype)

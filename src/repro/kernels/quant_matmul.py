@@ -46,6 +46,17 @@ prompt admitted for a resident task prefills without a scale swap.  A row
 of the stacked call is bitwise the plain call under that task's scales:
 the codes' dot is shared and the epilogue's arithmetic is the same.
 
+A decode layer scan passes the quantized operands layer-STACKED, with the
+layer's index: codes ``(L, …)`` uint32 and scales ``(L, [E,] T, N, G)``
+as they are stored, plus ``layer`` as a scalar-prefetched operand.  Every
+BlockSpec index map then selects block ``layer`` of the leading axis, so
+the kernel DMAs that layer's blocks straight out of the stacks: no layer
+is sliced out or copied first.  Each operand is read in the order the chip
+stores it (``minor_swapped``): codes whose K/8 is no multiple of 128 words
+are stored words-major and read as (K/8, bn) blocks, and per-channel
+scales (G = 1) as ``(…, 1, N)``.  Such codes stay uint32 up to the
+kernel, which bitcasts each loaded word block to int32 (``_int32``).
+
 Two storage layouts share the tile loop:
 
   * ``nibble`` — 8 codes per uint32 word; 3-bit rides in nibbles, so the
@@ -85,14 +96,24 @@ def _rows(ref, g: int, tids, n_tasks: int):
     return sel
 
 
-def qmm_tile(x, qw, scale_t, zero_t, tids, *, group: int, plane_bits: int):
+def _int32(words):
+    """A block of code words as int32 (a bitcast of uint32 words)."""
+    if words.dtype == jnp.int32:
+        return words
+    return jax.lax.bitcast_convert_type(words, jnp.int32)
+
+
+def qmm_tile(x, qw, scale_t, zero_t, tids, *, group: int, plane_bits: int,
+             words_major: bool = False):
     """One (bm, bn) output tile of x @ Ŵᵀ in f32.
 
-    ``x``: (bm, K); ``qw``: int32 words (bn, K/8), or (bits, bn, K/32)
-    planes of which the first ``plane_bits`` are read; ``scale_t`` /
-    ``zero_t``: (T, G, bn); ``tids``: (bm, 1) int32 or None.  Every operand
-    may be a Pallas ref or a plain array — the kernel and the blocked
-    replay oracle (ref.quant_gemv_ref) run this same function.
+    ``x``: (bm, K); ``qw``: int32 or uint32 words (bn, K/8), or (bits, bn,
+    K/32) planes of which the first ``plane_bits`` are read — or, with
+    ``words_major``, the same words transposed, (K/8, bn) or
+    (bits, K/32, bn); ``scale_t`` / ``zero_t``: (T, G, bn); ``tids``:
+    (bm, 1) int32 or None.  Every operand may be a Pallas ref or a plain
+    array — the kernel and the blocked replay oracle
+    (ref.quant_gemv_ref) run this same function.
     """
     k = x.shape[1]
     fields, width = (PLANE_PACK, 1) if plane_bits else (PACK, 4)
@@ -106,7 +127,14 @@ def qmm_tile(x, qw, scale_t, zero_t, tids, *, group: int, plane_bits: int):
     if tids is not None:
         tids = tids[...]
     bm = x.shape[0]
-    bn = qw.shape[-2]
+    bn = qw.shape[-1] if words_major else qw.shape[-2]
+
+    def cols(a, lo, hi, *plane):
+        """Columns lo:hi (along K) of words or codes: (bn, ·) row-major,
+        (·, bn) words-major."""
+        return a[plane + ((slice(lo, hi), slice(None)) if words_major
+                          else (slice(None), slice(lo, hi)))]
+    dims = (((1,), (0 if words_major else 1,)), ((), ()))   # contract K
 
     y = jnp.zeros((bm, bn), jnp.float32)
     acc = jnp.zeros((bm, bn), jnp.float32)
@@ -114,9 +142,10 @@ def qmm_tile(x, qw, scale_t, zero_t, tids, *, group: int, plane_bits: int):
     cur = 0
     for t in range(k // (fields * tw)):
         if plane_bits:
-            words = [qw[p, :, t * tw:(t + 1) * tw] for p in range(plane_bits)]
+            words = [_int32(cols(qw, t * tw, (t + 1) * tw, p))
+                     for p in range(plane_bits)]
         else:
-            words = qw[:, t * tw:(t + 1) * tw]
+            words = _int32(cols(qw, t * tw, (t + 1) * tw))
         for i in range(fields):
             if plane_bits:
                 codes = sum(((w >> i) & 1) << (plane_bits - 1 - p)
@@ -135,8 +164,8 @@ def qmm_tile(x, qw, scale_t, zero_t, tids, *, group: int, plane_bits: int):
                     cur = g
                 xc = x[:, col + a:col + a + piece]
                 acc = acc + jax.lax.dot_general(
-                    xc, codes if piece == tw else codes[:, a:a + piece],
-                    dimension_numbers=(((1,), (1,)), ((), ())),  # x @ q.T
+                    xc, codes if piece == tw else cols(codes, a, a + piece),
+                    dimension_numbers=dims,                       # x @ q.T
                     preferred_element_type=jnp.float32, precision=prec)
                 xs = xs + jnp.sum(xc.astype(jnp.float32), axis=1,
                                   keepdims=True)
@@ -144,70 +173,149 @@ def qmm_tile(x, qw, scale_t, zero_t, tids, *, group: int, plane_bits: int):
         acc - _rows(zero_t, cur, tids, n_tasks) * xs)
 
 
-def _kernel(*refs, has_tids: bool, group: int, plane_bits: int):
+def _kernel(*refs, prefetch: int, has_tids: bool, group: int,
+            plane_bits: int, words_major: bool):
+    refs = refs[prefetch:]        # the layer index: read by the index maps
     if has_tids:
         tid_ref, x_ref, qw_ref, s_ref, z_ref, o_ref = refs
     else:
         (x_ref, qw_ref, s_ref, z_ref, o_ref), tid_ref = refs, None
     y = qmm_tile(x_ref, qw_ref, s_ref, z_ref, tid_ref, group=group,
-                 plane_bits=plane_bits)
+                 plane_bits=plane_bits, words_major=words_major)
     o_ref[...] = y.astype(o_ref.dtype)
 
 
-def operands(qw, scale, zero, task_ids, spec: QuantSpec, m: int):
+def minor_swapped(shape) -> bool:
+    """Whether a TPU stores a 32-bit array of ``shape`` with its two minor
+    dims swapped.  XLA lays an (…, R, C) array out in (8, 128) tiles over
+    its two minor dims, in the order that pads less, and row-major on a
+    tie: codes (…, N, K/8) whose K/8 is no multiple of 128 (starcoder2's
+    K = 4608, an expert's K = 1408) are stored words-major, as are
+    per-channel (…, N, 1) scales.  A layer-stacked kernel call reads every
+    operand in its stored order, which keeps the operand a bitcast of the
+    stack (``tests/test_tpu_compile.py`` checks this rule against XLA)."""
+    r, c = shape[-2:]
+    padded = lambda rows, cols: -(-rows // 8) * 8 * (-(-cols // 128) * 128)
+    return padded(c, r) < padded(r, c)
+
+
+def operands(qw, scale, zero, task_ids, spec: QuantSpec, m: int,
+             layer=None):
     """Kernel-side operand layout: int32 words, (T, G, N) scales/zeros,
-    (M, 1) task ids.  Shared with the replay oracle."""
+    (M, 1) task ids.  Shared with the replay oracle.
+
+    With ``layer``, ``qw``/``scale``/``zero`` are layer-stacked and come
+    back in their stored order (``minor_swapped``): the codes uint32 (the
+    kernel bitcasts its word blocks), transposed words-major where they are
+    stored so, and the scales (L, T, G, N).  Scales stored (…, N, G)
+    row-major are transposed, so their layer is taken out first and they
+    come back as a one-layer (1, T, G, N) stack."""
     if not (spec.packs or spec.plane):
         raise NotImplementedError(
             "the quant_matmul/quant_gemv Pallas kernels read nibble- or "
             f"plane-packed codes; {spec} stores unpacked codes")
-    qw = jax.lax.bitcast_convert_type(qw, jnp.int32)
-    if task_ids is None:
-        scale, zero = scale[None], zero[None]
-        tids = None
-    else:
-        tids = task_ids.astype(jnp.int32).reshape(m, 1)
-    st = jnp.swapaxes(scale.astype(jnp.float32), -1, -2)
-    zt = jnp.swapaxes(zero.astype(jnp.float32), -1, -2)
+    qw = _codes_operand(qw, layer)
+    tids = None if task_ids is None else \
+        task_ids.astype(jnp.int32).reshape(m, 1)
+    st, zt = _scale_operands(scale, zero, task_ids is not None, layer)
     return qw, st, zt, tids
+
+
+def _codes_operand(qw, layer):
+    """Per-layer codes as int32 words; a layer stack as stored."""
+    if layer is None:
+        return jax.lax.bitcast_convert_type(qw, jnp.int32)
+    return jnp.swapaxes(qw, -1, -2) if minor_swapped(qw.shape) else qw
+
+
+def _scale_operands(scale, zero, stacked_tasks: bool, layer):
+    """f32 (…, T, G, N) scales and zeros, a task dim of 1 added when the
+    call has no task ids."""
+    if not stacked_tasks:
+        scale, zero = scale[..., None, :, :], zero[..., None, :, :]
+    return tuple(_transposed(a.astype(jnp.float32), layer)
+                 for a in (scale, zero))
+
+
+def _transposed(scale, layer):
+    """(…, N, G) scales as (…, G, N): see ``operands``."""
+    if layer is not None:
+        if not minor_swapped(scale.shape):
+            scale = jax.lax.dynamic_index_in_dim(scale, layer, 0,
+                                                 keepdims=True)
+        elif scale.shape[-1] == 1:    # the stored order: a bitcast
+            return scale.reshape(*scale.shape[:-2], 1, scale.shape[-2])
+    return jnp.swapaxes(scale, -1, -2)
+
+
+def _layer_map(index, stacked: bool):
+    """``index`` (grid indices → block indices) for an operand with a
+    leading layer axis: the layer's block when the operand holds every
+    layer, block 0 of a one-layer operand."""
+    if stacked:
+        return lambda *g: (g[-1][0],) + index(*g)
+    return lambda *g: (0,) + index(*g)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("spec", "block_m", "block_n", "out_dtype", "interpret",
                      "name"))
-def _qmm_call(x, qw, scale, zero, task_ids, *, spec: QuantSpec, block_m: int,
-              block_n: int, out_dtype, interpret: bool, name: str):
+def _qmm_call(x, qw, scale, zero, task_ids, layer, *, spec: QuantSpec,
+              block_m: int, block_n: int, out_dtype, interpret: bool,
+              name: str):
     m, k = x.shape
     n = qw.shape[-2]
-    qw, st, zt, tids = operands(qw, scale, zero, task_ids, spec, m)
-    n_tasks, g, _ = st.shape
+    stacked = layer is not None
+    words_major = stacked and minor_swapped(qw.shape)
+    scales_whole = stacked and minor_swapped(scale.shape)  # _transposed
+    qw, st, zt, tids = operands(qw, scale, zero, task_ids, spec, m, layer)
+    n_tasks, g, _ = st.shape[-3:]
     bm, bn = min(block_m, m), min(block_n, n)
     if n % bn and n <= 8 * block_n:
         bn = n      # one whole-width block (MLA's 576-wide W_kva) beats a
         #             ragged edge block
-    if spec.plane:
-        qw_spec = pl.BlockSpec((spec.bits, bn, k // PLANE_PACK),
-                               lambda i, j: (0, j, 0))
+    words = k // (PLANE_PACK if spec.plane else PACK)
+    planes = (spec.bits,) if spec.plane else ()
+    top = (0,) * len(planes)            # the top spec.bits stored planes
+    if words_major:
+        qw_block = planes + (words, bn)
+        qw_index = lambda i, j, *_: top + (0, j)
     else:
-        qw_spec = pl.BlockSpec((bn, k // PACK), lambda i, j: (j, 0))
-    sz_spec = pl.BlockSpec((n_tasks, g, bn), lambda i, j: (0, 0, j))
-    in_specs = [pl.BlockSpec((bm, k), lambda i, j: (i, 0)),
-                qw_spec, sz_spec, sz_spec]
+        qw_block = planes + (bn, words)
+        qw_index = lambda i, j, *_: top + (j, 0)
+    sz_block, sz_index = (n_tasks, g, bn), lambda i, j, *_: (0, 0, j)
+    if stacked:
+        qw_block, qw_index = (None,) + qw_block, _layer_map(qw_index, True)
+        sz_block = (None,) + sz_block
+        sz_index = _layer_map(sz_index, scales_whole)
+    sz_spec = pl.BlockSpec(sz_block, sz_index)
+    in_specs = [pl.BlockSpec((bm, k), lambda i, j, *_: (i, 0)),
+                pl.BlockSpec(qw_block, qw_index), sz_spec, sz_spec]
     args = [x, qw, st, zt]
     if tids is not None:
-        in_specs.insert(0, pl.BlockSpec((bm, 1), lambda i, j: (i, 0)))
+        in_specs.insert(0, pl.BlockSpec((bm, 1), lambda i, j, *_: (i, 0)))
         args.insert(0, tids)
+    grid = (pl.cdiv(m, bm), pl.cdiv(n, bn))
+    out_spec = pl.BlockSpec((bm, bn), lambda i, j, *_: (i, j))
+    prefetch = []
+    if layer is None:
+        grid_kw = dict(grid=grid, in_specs=in_specs, out_specs=out_spec)
+    else:
+        prefetch = [jnp.reshape(layer, (1,)).astype(jnp.int32)]
+        grid_kw = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
+            out_specs=out_spec))
     return pl.pallas_call(
-        functools.partial(_kernel, has_tids=tids is not None, group=k // g,
-                          plane_bits=spec.bits if spec.plane else 0),
-        grid=(pl.cdiv(m, bm), pl.cdiv(n, bn)),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
+        functools.partial(_kernel, prefetch=len(prefetch),
+                          has_tids=tids is not None, group=k // g,
+                          plane_bits=spec.bits if spec.plane else 0,
+                          words_major=words_major),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype or x.dtype),
         interpret=interpret,
         name=name,
-    )(*args)
+        **grid_kw,
+    )(*prefetch, *args)
 
 
 def quant_matmul_pallas(
@@ -218,6 +326,7 @@ def quant_matmul_pallas(
     *,
     spec: QuantSpec,
     task_ids: jax.Array | None = None,   # (M,) int32 rows into the T stack
+    layer: jax.Array | None = None,      # () int32: operands layer-stacked
     block_m: int = DEFAULT_BLOCK_M,
     block_n: int = DEFAULT_BLOCK_N,
     out_dtype=None,
@@ -225,8 +334,10 @@ def quant_matmul_pallas(
 ) -> jax.Array:
     """y = x @ Ŵᵀ with Ŵ = scale · (codes − zero);  returns (M, N).
     Grid (M/bm, N/bn); ``block_m`` must be a multiple of the activation
-    dtype's sublane tile (8 for f32, 16 for bf16) when it is below M."""
-    return _qmm_call(x, qw, scale, zero, task_ids, spec=spec,
+    dtype's sublane tile (8 for f32, 16 for bf16) when it is below M.
+    With ``layer``, ``qw``/``scale``/``zero`` carry a leading layer axis
+    and the call reads layer ``layer`` of them in place."""
+    return _qmm_call(x, qw, scale, zero, task_ids, layer, spec=spec,
                      block_m=block_m, block_n=block_n, out_dtype=out_dtype,
                      interpret=interpret, name="quant_matmul")
 
@@ -238,6 +349,7 @@ def quant_gemv_pallas(
     zero: jax.Array,        # same shape as scale
     *,
     task_ids: jax.Array | None = None,   # (M,) int32 rows into the T stack
+    layer: jax.Array | None = None,      # () int32: operands layer-stacked
     spec: QuantSpec,
     block_n: int = DEFAULT_BLOCK_N,
     out_dtype=None,
@@ -248,8 +360,12 @@ def quant_gemv_pallas(
     Plane layout (``spec.plane``): ``qw`` is (bits', N, K/32) and only the
     top ``spec.bits`` planes are streamed — with ``bits' > spec.bits`` this
     is the draft decode reading a prefix of the target's buffer.
+
+    Layer-stacked (``layer`` given): ``qw`` is (L, N, K/8) or
+    (L, bits', N, K/32), ``scale``/``zero`` (L, N, G) or (L, T, N, G), and
+    the call is the plain call on layer ``layer`` of each, read in place.
     """
-    return _qmm_call(x, qw, scale, zero, task_ids, spec=spec,
+    return _qmm_call(x, qw, scale, zero, task_ids, layer, spec=spec,
                      block_m=x.shape[0], block_n=block_n,
                      out_dtype=out_dtype, interpret=interpret,
                      name="quant_gemv")
@@ -269,18 +385,20 @@ class _FlatRows:
         return self.ref[t * self.g + rows.start:t * self.g + rows.stop, cols]
 
 
-def _gmm_kernel(te_ref, used_ref, *refs, has_tids: bool, group: int,
-                groups: int, plane_bits: int):
+def _gmm_kernel(te_ref, used_ref, *refs, prefetch: int, has_tids: bool,
+                group: int, groups: int, plane_bits: int, words_major: bool):
+    refs = refs[prefetch:]        # the layer index: read by the index maps
     if has_tids:
         tid_ref, x_ref, qw_ref, s_ref, z_ref, o_ref = refs
     else:
         (x_ref, qw_ref, s_ref, z_ref, o_ref), tid_ref = refs, None
+    if len(s_ref.shape) == 2:     # (T·G, N): see quant_gmm_pallas
+        s_ref, z_ref = _FlatRows(s_ref, groups), _FlatRows(z_ref, groups)
 
     @pl.when(pl.program_id(0) < used_ref[0])
     def _():
-        y = qmm_tile(x_ref, qw_ref, _FlatRows(s_ref, groups),
-                     _FlatRows(z_ref, groups), tid_ref, group=group,
-                     plane_bits=plane_bits)
+        y = qmm_tile(x_ref, qw_ref, s_ref, z_ref, tid_ref, group=group,
+                     plane_bits=plane_bits, words_major=words_major)
         o_ref[...] = y.astype(o_ref.dtype)
 
 
@@ -302,6 +420,7 @@ def quant_gmm_pallas(
     spec: QuantSpec,
     block_m: int,
     task_ids: jax.Array | None = None,   # (M,) int32 rows into the T stack
+    layer: jax.Array | None = None,      # () int32: operands layer-stacked
     out_dtype=None,
     interpret: bool = False,
 ) -> jax.Array:
@@ -309,45 +428,69 @@ def quant_gmm_pallas(
 
     Tiles past ``tiles_used`` point at the last used tile's expert (no new
     codes are fetched) and compute nothing: their output rows are left
-    unwritten, and nothing may read them."""
+    unwritten, and nothing may read them.
+
+    Layer-stacked (``layer`` given): ``qw``/``scale``/``zero`` carry a
+    leading layer axis, (L, E, …), and each tile reads its expert's blocks
+    of layer ``layer`` in place; the scales enter (L, E, T, G, N)."""
     m, k = x.shape
-    e = qw.shape[0]
     n = qw.shape[-2]
     bm = block_m
     if m % bm:
         raise ValueError(f"{m} rows are not whole {bm}-row tiles")
-    qw = jax.lax.bitcast_convert_type(qw, jnp.int32)
-    if task_ids is None:
-        scale, zero = scale[:, None], zero[:, None]
-    t_, g = scale.shape[1], scale.shape[-1]
-    # (E, T, N, G) → (E, T·G, N): a group's scale row is lane-dense
-    st = jnp.swapaxes(scale.astype(jnp.float32), -1, -2).reshape(e, t_ * g, n)
-    zt = jnp.swapaxes(zero.astype(jnp.float32), -1, -2).reshape(e, t_ * g, n)
-    if spec.plane:
-        qw_spec = pl.BlockSpec((None, spec.bits, n, k // PLANE_PACK),
-                               lambda i, te, nu: (te[i], 0, 0, 0))
+    at = lambda i, te, nu, *_: (te[i],)
+    words_major = layer is not None and minor_swapped(qw.shape)
+    qw = _codes_operand(qw, layer)
+    if layer is None:
+        e = qw.shape[0]
+        if task_ids is None:
+            scale, zero = scale[:, None], zero[:, None]
+        t_, g = scale.shape[1], scale.shape[-1]
+        # (E, T, N, G) → (E, T·G, N): a group's scale row is lane-dense
+        st = jnp.swapaxes(scale.astype(jnp.float32), -1, -2).reshape(
+            e, t_ * g, n)
+        zt = jnp.swapaxes(zero.astype(jnp.float32), -1, -2).reshape(
+            e, t_ * g, n)
+        sz_spec = pl.BlockSpec((None, t_ * g, n),
+                               lambda *a: at(*a) + (0, 0))
+        qw_at = at
     else:
-        qw_spec = pl.BlockSpec((None, n, k // PACK),
-                               lambda i, te, nu: (te[i], 0, 0))
-    sz_spec = pl.BlockSpec((None, t_ * g, n), lambda i, te, nu: (te[i], 0, 0))
-    in_specs = [pl.BlockSpec((bm, k), lambda i, te, nu: (i, 0)),
+        scales_whole = minor_swapped(scale.shape)       # _transposed
+        st, zt = _scale_operands(scale, zero, task_ids is not None, layer)
+        t_, g = st.shape[-3], st.shape[-2]
+        sz_spec = pl.BlockSpec(
+            (None, None, t_, g, n),
+            _layer_map(lambda *a: at(*a) + (0, 0, 0), scales_whole))
+        qw_at = _layer_map(at, True)
+    lead = (None,) * (1 if layer is None else 2)      # [layer,] expert
+    words = k // (PLANE_PACK if spec.plane else PACK)
+    planes = (spec.bits,) if spec.plane else ()
+    qw_block = lead + planes + ((words, n) if words_major else (n, words))
+    qw_spec = pl.BlockSpec(
+        qw_block, lambda *a: qw_at(*a) + (0,) * (len(planes) + 2))
+    in_specs = [pl.BlockSpec((bm, k), lambda i, *_: (i, 0)),
                 qw_spec, sz_spec, sz_spec]
     args = [x, qw, st, zt]
     if task_ids is not None:
-        in_specs.insert(0, pl.BlockSpec((bm, 1), lambda i, te, nu: (i, 0)))
+        in_specs.insert(0, pl.BlockSpec((bm, 1), lambda i, *_: (i, 0)))
         args.insert(0, task_ids.astype(jnp.int32).reshape(m, 1))
+    prefetch = [tile_expert.astype(jnp.int32),
+                jnp.reshape(tiles_used, (1,)).astype(jnp.int32)]
+    if layer is not None:
+        prefetch.append(jnp.reshape(layer, (1,)).astype(jnp.int32))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2, grid=(m // bm,), in_specs=in_specs,
-        out_specs=pl.BlockSpec((bm, n), lambda i, te, nu: (i, 0)))
+        num_scalar_prefetch=len(prefetch), grid=(m // bm,),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((bm, n), lambda i, *_: (i, 0)))
     return pl.pallas_call(
-        functools.partial(_gmm_kernel, has_tids=task_ids is not None,
-                          group=k // g, groups=g,
-                          plane_bits=spec.bits if spec.plane else 0),
+        functools.partial(_gmm_kernel, prefetch=len(prefetch) - 2,
+                          has_tids=task_ids is not None, group=k // g,
+                          groups=g, plane_bits=spec.bits if spec.plane else 0,
+                          words_major=words_major),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype or x.dtype),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=GMM_VMEM_BYTES),
         interpret=interpret,
         name="quant_gmm",
-    )(tile_expert.astype(jnp.int32),
-      jnp.reshape(tiles_used, (1,)).astype(jnp.int32), *args)
+    )(*prefetch, *args)

@@ -65,6 +65,53 @@ def _fake_quant(w, scale, zero, spec: QuantSpec):
     return (s * (q - z)).reshape(n, m)
 
 
+class LayerOf:
+    """Layer ``index`` of the layer-stacked array ``stack``, left in place.
+
+    The decode layer scan hands each quantized linear its codes and scales
+    this way (``transformer.decode_tokens``): the ops take the whole stack
+    with ``layer=index`` and the kernels read that layer's blocks where
+    they lie, so no layer is sliced out or copied.  ``shape``, ``ndim`` and
+    ``dtype`` are the layer's; ``plain`` slices the layer out, for the few
+    readers that need an array of their own."""
+    __slots__ = ("stack", "index")
+
+    def __init__(self, stack: jax.Array, index: jax.Array):
+        self.stack, self.index = stack, index
+
+    @property
+    def shape(self) -> tuple:
+        return self.stack.shape[1:]
+
+    @property
+    def ndim(self) -> int:
+        return self.stack.ndim - 1
+
+    @property
+    def dtype(self):
+        return self.stack.dtype
+
+
+def plain(a):
+    """``a`` as an array: a ``LayerOf`` sliced out, anything else as is."""
+    if isinstance(a, LayerOf):
+        return ops.at_layer(a.stack, a.index)
+    return a
+
+
+def plain_tree(tree):
+    return jax.tree.map(plain, tree)
+
+
+def in_place(*leaves) -> tuple:
+    """(arrays, layer) for an op's ``layer=``: the stacks and their layer
+    when every leaf is a ``LayerOf``, else the leaves as plain arrays and
+    None."""
+    if all(isinstance(a, LayerOf) for a in leaves):
+        return tuple(a.stack for a in leaves), leaves[0].index
+    return tuple(plain(a) for a in leaves), None
+
+
 def slot_entry(slots, name: str):
     """Narrow a ``(task_ids, stack_subtree)`` pair to one child module.
 
@@ -93,7 +140,8 @@ def apply(p: dict, x: jax.Array, spec: QuantSpec, *,
     slots: optional ``(task_ids (M,), {"scale": (T, out, G), "zero": …})``
     for the mixed-task decode step — each of the M rows of x (flattened
     leading dims) reads the scale row its slot's task owns.  Forward-only;
-    ignored for non-peqa storage modes.
+    ignored for non-peqa storage modes.  The codes and scales may be
+    ``LayerOf`` views (the decode layer scan's), read in place.
 
     row_parallel: this layer contracts a mesh-sharded input dim
     (``dist.sharding.ROW_PARALLEL``: ``wo``, ``down``, ``out_proj``) — the
@@ -104,13 +152,17 @@ def apply(p: dict, x: jax.Array, spec: QuantSpec, *,
         if slots is not None and isinstance(slots[1], dict) \
                 and "scale" in slots[1]:
             task_ids, stack = slots
+            (qw, s, z), layer = in_place(p["qw"], stack["scale"],
+                                         stack["zero"])
             y = ops.quant_matmul_slotted(
-                x, p["qw"], stack["scale"], stack["zero"], task_ids, spec,
-                impl=impl, bf16_reduce=bf16_reduce, row_parallel=row_parallel)
+                x, qw, s, z, task_ids, spec, impl=impl,
+                bf16_reduce=bf16_reduce, row_parallel=row_parallel,
+                layer=layer)
         else:
-            y = ops.quant_matmul(x, p["qw"], p["scale"], p["zero"], spec,
-                                 impl=impl, bf16_reduce=bf16_reduce,
-                                 row_parallel=row_parallel)
+            (qw, s, z), layer = in_place(p["qw"], p["scale"], p["zero"])
+            y = ops.quant_matmul(x, qw, s, z, spec, impl=impl,
+                                 bf16_reduce=bf16_reduce,
+                                 row_parallel=row_parallel, layer=layer)
     elif "scale" in p:  # qat fake-quant (w present, scale learned)
         w = _fake_quant(p["w"].astype(x.dtype), p["scale"], p["zero"], spec)
         y = jnp.einsum("...k,nk->...n", x, w, preferred_element_type=pet

@@ -144,6 +144,14 @@ def apply_prefill(p: dict, x: jax.Array, cfg: ModelConfig, slots=None):
     return o, {"ckv": c.astype(dtype), "kpe": k_pe.astype(dtype)}
 
 
+def _task_rows(stack, tids):
+    """Rows ``tids`` of a (T, …) task stack, gathered straight from the
+    layer stack when ``stack`` is a ``linear.LayerOf`` view."""
+    if isinstance(stack, linear.LayerOf):
+        return stack.stack[stack.index, tids]
+    return stack[tids]
+
+
 def _kvb_factors(p_kvb: dict, slots, cfg: ModelConfig, dtype):
     """``W_kvb`` as (Q (H, dn + dv, G, R/G), s, z (M, H, dn + dv, G)):
     ``W[h, i, g, j] = s[., h, i, g] · (Q[h, i, g, j] − z[., h, i, g])`` per
@@ -155,12 +163,13 @@ def _kvb_factors(p_kvb: dict, slots, cfg: ModelConfig, dtype):
         w = p_kvb["w"].astype(dtype).reshape(h, width, 1, r)
         one = jnp.ones((1, h, width, 1), jnp.float32)
         return w, one, jnp.zeros_like(one)
+    p_kvb = linear.plain_tree(p_kvb)    # dequantized here, not a kernel's
     ent = linear.slot_entry(slots, "wkv_b")
     if ent is None:
         s, z = p_kvb["scale"][None], p_kvb["zero"][None]
     else:
         tids, st = ent
-        s, z = st["scale"][tids], st["zero"][tids]
+        s, z = (_task_rows(st[key], tids) for key in ("scale", "zero"))
     g = s.shape[-1]
     unit = jnp.ones(s.shape[1:], jnp.float32)
     q = ops.dequantize_op(p_kvb["qw"], unit, jnp.zeros_like(unit), r,

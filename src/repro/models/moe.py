@@ -166,9 +166,11 @@ def _experts_apply(experts: dict, xs: jax.Array, grp: Groups,
         else:
             tids, st = ent
             scale, zero = st["scale"], st["zero"]
+        (qw, scale, zero), layer = linear.in_place(p["qw"], scale, zero)
         return ops.grouped_quant_matmul(
-            x, p["qw"], scale, zero, grp.sizes, grp.tile_expert,
-            grp.tiles_used, spec, block_m=grp.block_m, task_ids=tids)
+            x, qw, scale, zero, grp.sizes, grp.tile_expert,
+            grp.tiles_used, spec, block_m=grp.block_m, task_ids=tids,
+            layer=layer)
 
     with jax.named_scope("moe.experts"):
         up = lin("up", xs)
@@ -211,7 +213,8 @@ def _moe_math(p: dict, xt: jax.Array, cfg: ModelConfig,
         tids, stack = slots
         if one_task:
             experts = {name: {**lin, **jax.tree.map(
-                lambda a: jnp.take(a, tids[0], axis=1), stack[name])}
+                lambda a: jnp.take(linear.plain(a), tids[0], axis=1),
+                stack[name])}
                 for name, lin in experts.items()}
         else:
             tpad = jnp.concatenate([tids, jnp.zeros((1,), tids.dtype)])
@@ -299,6 +302,7 @@ def apply(p: dict, x: jax.Array, cfg: ModelConfig, slots=None):
     if slots is not None:
         raise NotImplementedError(
             "per-slot task scales run off the mesh only")
+    p = linear.plain_tree(p)      # shard_map takes arrays, not LayerOf
 
     mc = cfg.moe
     dp = ctx.data_axes

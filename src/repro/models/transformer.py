@@ -224,6 +224,34 @@ def prefill(params: dict, tokens: jax.Array, cfg: ModelConfig,
     return logits[:, 0], cache
 
 
+# the leaves of a quantized linear that its kernel reads in place
+_IN_PLACE = ("qw", "scale", "zero")
+
+
+def _split_in_place(tree: dict) -> tuple:
+    """A layer stack split into (the quantized linears' codes and scales,
+    the rest): two trees of the same dicts, each leaf in one of them."""
+    held, rest = {}, {}
+    for key, v in tree.items():
+        if isinstance(v, dict):
+            held[key], rest[key] = _split_in_place(v)
+        elif "qw" in tree and key in _IN_PLACE:
+            held[key] = v
+        else:
+            rest[key] = v
+    return held, rest
+
+
+def _join_in_place(rest: dict, held: dict, index) -> dict:
+    """``rest`` (one layer's slice) with ``held``'s stacks added back as
+    ``linear.LayerOf`` views of layer ``index``."""
+    out = dict(rest)
+    for key, v in held.items():
+        out[key] = (_join_in_place(rest.get(key, {}), v, index)
+                    if isinstance(v, dict) else linear.LayerOf(v, index))
+    return out
+
+
 def decode_tokens(params: dict, cache: dict, tokens: jax.Array,
                    pos: jax.Array, cfg: ModelConfig,
                    task_stack: dict | None = None,
@@ -241,6 +269,14 @@ def decode_tokens(params: dict, cache: dict, tokens: jax.Array,
     quantized linears gather per-slot scales in-kernel instead of the pool
     draining for a scale swap; MoE blocks give each routed row its slot's
     task (off the mesh only).
+
+    The layer scan slices only the small leaves (norms, biases, the
+    router, full-precision weights) out of its ``xs``.  The quantized
+    linears' codes and scales, and the whole task stack, are closed over
+    and reach each linear as ``linear.LayerOf`` views: the kernels read
+    the layer's blocks in place instead of a per-layer copy.  Their index
+    counts within a layer group of ``stacks`` (DeepSeek's leading dense
+    block, then its MoE blocks); the KV pool's counts over all layers.
     """
     h = common.embed_apply(params["embed"], tokens, cfg)
 
@@ -254,17 +290,15 @@ def decode_tokens(params: dict, cache: dict, tokens: jax.Array,
     # The stacked KV pool rides in the carry, not in xs/ys: each layer
     # writes only its new rows into it in place and reads its own layer
     # back, so no layer's whole cache is sliced out or written back.
-    def body(carry, xs):
+    def body(held, task_held, first: int, carry, xs):
         h, cache = carry
-        if slotted:
-            layer_p, layer_stack, layer = xs
-            slots = (row_ids, layer_stack)
-        else:
-            layer_p, layer = xs
-            slots = None
+        rest, i = xs
+        layer_p = _join_in_place(rest, held, i)
+        layer_stack = _join_in_place({}, task_held, i) if slotted else None
+        slots = (row_ids, layer_stack) if slotted else None
         hin = common.norm_apply(layer_p["ln1"], h, cfg)
         a, cache = _attn(cfg).apply_decode(
-            layer_p["attn"], hin, cfg, cache, layer, pos,
+            layer_p["attn"], hin, cfg, cache, first + i, pos,
             slots=linear.slot_entry(slots, "attn"))
         h = h + a
         hin = common.norm_apply(layer_p["ln2"], h, cfg)
@@ -281,15 +315,16 @@ def decode_tokens(params: dict, cache: dict, tokens: jax.Array,
         return (h + m, cache), stats
 
     p_stacks = stacks(params["layers"])
-    t_stacks = stacks(task_stack["layers"]) if slotted else p_stacks
+    t_stacks = stacks(task_stack["layers"]) if slotted else [{}] * len(
+        p_stacks)
     first = 0
     stats = None
     for ps, ts in zip(p_stacks, t_stacks):
         n = jax.tree.leaves(ps)[0].shape[0]
-        layers = jnp.arange(first, first + n)
+        held, rest = _split_in_place(ps)
+        (h, cache), st = jax.lax.scan(partial(body, held, ts, first),
+                                      (h, cache), (rest, jnp.arange(n)))
         first += n
-        xs = (ps, ts, layers) if slotted else (ps, layers)
-        (h, cache), st = jax.lax.scan(body, (h, cache), xs)
         if st is not None:
             stats = jnp.concatenate([jnp.array([n], jnp.int32),
                                      st.sum(0).astype(jnp.int32)])

@@ -18,7 +18,7 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 
-from repro.core.quant import (QTensor, QuantSpec, pack_codes,
+from repro.core.quant import (QTensor, QuantSpec, draft_scales, pack_codes,
                               pack_codes_planes, tile_words, unpack_codes)
 from repro.kernels import ops, ref
 from repro.kernels import quant_matmul as qm
@@ -200,3 +200,103 @@ def test_gemv_odd_k_blocks_vmem_regression():
     naive = ref.quant_matmul_ref(x, qt.qw, qt.scale, qt.zero, qt.shape, spec)
     np.testing.assert_allclose(np.asarray(got), np.asarray(naive),
                                rtol=1e-5, atol=1e-4)
+
+
+def _layer_stack(n, k, spec, layers=3, n_tasks=0, planes=None, seed=31):
+    """Codes, scale and zero stacks of ``layers`` quantized layers (L, …):
+    scales (L, T, N, G) with ``n_tasks``; plane codes stored with
+    ``planes`` planes (the target's) read under ``spec`` (a draft)."""
+    rng = np.random.default_rng(seed)
+    store = QuantSpec(bits=planes or spec.bits, group_size=spec.group_size,
+                      layout=spec.layout)
+    qts = [QTensor.quantize(jnp.asarray(rng.normal(size=(n, k)).astype(
+        np.float32) * 0.05), store, n_grid=2) for _ in range(layers)]
+    qw = jnp.stack([q.qw for q in qts])
+    scale = jnp.stack([q.scale for q in qts])
+    zero = jnp.stack([q.zero for q in qts])
+    if planes:
+        scale, zero = draft_scales(scale, zero, planes, spec.bits)
+    if n_tasks:
+        mult = rng.uniform(0.8, 1.2, (layers, n_tasks) + scale.shape[1:])
+        scale = scale[:, None] * jnp.asarray(mult, jnp.float32)
+        zero = jnp.broadcast_to(zero[:, None], scale.shape)
+    return qw, scale, zero
+
+
+# (layout, bits, stored planes): nibbles, bit planes, and the draft's
+# view of the top 3 of 4 stored planes
+LAYOUTS = {"nibble": ("nibble", 4, None), "plane": ("plane", 3, None),
+           "draft": ("plane", 3, 4)}
+
+
+@pytest.mark.parametrize("n_tasks", [0, 3])
+@pytest.mark.parametrize("layout", list(LAYOUTS))
+def test_layer_indexed_gemv_equals_per_layer_call(layout, n_tasks):
+    """The decode scan's call — the whole layer stacks and a layer index —
+    is bitwise the plain call on that layer, for the first, a middle and
+    the last layer.  N = 128 and 128 words a row: stored row-major, the
+    order the plain call reads (quant_matmul.minor_swapped)."""
+    kind, bits, planes = LAYOUTS[layout]
+    spec = QuantSpec(bits=bits, layout=kind)
+    k = 1024 if kind == "nibble" else 4096
+    qw, scale, zero = _layer_stack(128, k, spec, n_tasks=n_tasks,
+                                   planes=planes)
+    assert not qm.minor_swapped(qw.shape)
+    rng = np.random.default_rng(5)
+    x = jnp.asarray(rng.normal(size=(6, k)).astype(np.float32))
+    tids = jnp.asarray([0, 1, 2, 2, 1, 0], jnp.int32) if n_tasks else None
+    for layer in (0, 1, 2):
+        got = qm.quant_gemv_pallas(x, qw, scale, zero, task_ids=tids,
+                                   layer=jnp.int32(layer), spec=spec,
+                                   block_n=BN, interpret=True)
+        want = qm.quant_gemv_pallas(x, qw[layer], scale[layer],
+                                    zero[layer], task_ids=tids, spec=spec,
+                                    block_n=BN, interpret=True)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("n_tasks", [0, 3])
+@pytest.mark.parametrize("group", [None, 64])
+def test_layer_indexed_gemv_reads_words_major_codes(group, n_tasks):
+    """Codes stored words-major (96 outputs of 32 words: each layer
+    (K/8, N) on the chip) are read in that order: the same products,
+    summed in another order, within the f32 summation-order bound of the
+    plain call on the layer."""
+    spec = QuantSpec(bits=4, group_size=group)
+    qw, scale, zero = _layer_stack(96, 256, spec, n_tasks=n_tasks)
+    assert qm.minor_swapped(qw.shape)
+    rng = np.random.default_rng(7)
+    x = jnp.asarray(rng.normal(size=(5, 256)).astype(np.float32))
+    tids = jnp.asarray([0, 1, 2, 0, 1], jnp.int32) if n_tasks else None
+    for layer in (0, 1, 2):
+        got = qm.quant_gemv_pallas(x, qw, scale, zero, task_ids=tids,
+                                   layer=jnp.int32(layer), spec=spec,
+                                   block_n=BN, interpret=True)
+        want = qm.quant_gemv_pallas(x, qw[layer], scale[layer],
+                                    zero[layer], task_ids=tids, spec=spec,
+                                    block_n=BN, interpret=True)
+        qt = QTensor(qw[layer], scale[layer], zero[layer], (96, 256), spec)
+        assert_order_close(got, want, x, qt, spec, task_ids=tids)
+
+
+@pytest.mark.parametrize("slotted", [False, True])
+@pytest.mark.parametrize("impl", ["interpret", "xla", "ref", "autodiff"])
+def test_layer_indexed_ops_equal_per_layer_call(impl, slotted):
+    """``ops.quant_matmul`` and ``ops.quant_matmul_slotted`` with
+    ``layer=``: one convention in every impl, bitwise the impl's call on
+    the layer's own arrays."""
+    spec = QuantSpec(bits=4)
+    qw, scale, zero = _layer_stack(128, 1024, spec,
+                                   n_tasks=3 if slotted else 0)
+    rng = np.random.default_rng(9)
+    x = jnp.asarray(rng.normal(size=(2, 3, 1024)).astype(np.float32))
+    tids = jnp.asarray([0, 1, 2, 2, 1, 0], jnp.int32)
+    if slotted:
+        op = lambda *a, **kw: ops.quant_matmul_slotted(
+            x, *a, tids, spec, impl=impl, **kw)
+    else:
+        op = lambda *a, **kw: ops.quant_matmul(x, *a, spec, impl=impl, **kw)
+    for layer in (0, 1, 2):
+        got = op(qw, scale, zero, layer=jnp.int32(layer))
+        want = op(qw[layer], scale[layer], zero[layer])
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

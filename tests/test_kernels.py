@@ -160,3 +160,86 @@ def test_flash_pallas_matches_ref(b, sq, sk, h, d, causal, window, offset,
                                    interpret=True)
     np.testing.assert_allclose(np.asarray(o_pal), np.asarray(o_ref),
                                rtol=1e-5, atol=1e-5)
+
+
+def _expert_stacks(k, spec, n_tasks, layers=3, e=4, n=128, seed=0):
+    """(L, E, …) codes and scale stacks of ``layers`` MoE layers, the
+    scales (L, E, T, N, G) with ``n_tasks``."""
+    from repro.core import quant
+    rng = np.random.default_rng(seed)
+    codes = rng.integers(0, 1 << spec.bits, (layers * e, n, k))
+    pack = (jax.vmap(lambda q: quant.pack_codes_planes(q, spec.bits))
+            if spec.plane else jax.vmap(quant.pack_codes))
+    qw = pack(jnp.asarray(codes, jnp.uint32))
+    qw = qw.reshape((layers, e) + qw.shape[1:])
+    g = spec.n_groups(k)
+    shape = (layers, e) + ((n_tasks,) if n_tasks else ()) + (n, g)
+    scale = jnp.asarray(rng.uniform(0.01, 0.02, shape), jnp.float32)
+    zero = jnp.asarray(rng.uniform(3, 5, shape), jnp.float32)
+    return qw, scale, zero
+
+
+def _tiles(sizes, bm, m):
+    ends = np.cumsum(sizes)
+    return jnp.asarray(np.minimum(np.searchsorted(
+        ends, np.minimum(np.arange(m // bm) * bm, ends[-1] - 1),
+        side="right"), len(sizes) - 1).astype(np.int32))
+
+
+@pytest.mark.parametrize("k", [1024, 256], ids=["row_major", "words_major"])
+@pytest.mark.parametrize("layout,group,n_tasks", [
+    ("nibble", None, 0), ("nibble", 32, 3), ("plane", None, 2)])
+def test_layer_indexed_gmm_equals_per_layer_call(layout, group, n_tasks, k):
+    """The grouped expert kernel on the whole (L, E, …) stacks and a layer
+    index against the same call on that layer's (E, …) arrays, for the
+    first, a middle and the last layer: bitwise where the codes are stored
+    row-major (128 outputs of 128 words), and to f32 summation order where
+    they are stored words-major (32 words a row) and read so."""
+    from repro.kernels import quant_matmul as qm
+    spec = QuantSpec(bits=3 if layout == "plane" else 4, group_size=group,
+                     layout=layout)
+    k = k * 4 if layout == "plane" else k       # the same words a row
+    qw, scale, zero = _expert_stacks(k, spec, n_tasks)
+    bm, sizes = 16, np.array([32, 0, 16, 48], np.int32)   # expert 1 idle
+    rows = int(sizes.sum())
+    m = rows + 2 * bm                                      # 2 unused tiles
+    rng = np.random.default_rng(1)
+    x = jnp.asarray(rng.standard_normal((m, k)), jnp.float32)
+    tids = jnp.asarray(rng.integers(0, max(n_tasks, 1), m), jnp.int32) \
+        if n_tasks else None
+    call = lambda *a, **kw: qm.quant_gmm_pallas(
+        x, *a, _tiles(sizes, bm, m), jnp.int32(rows // bm), spec=spec,
+        block_m=bm, task_ids=tids, interpret=True, **kw)
+    for layer in (0, 1, 2):
+        got = np.asarray(call(qw, scale, zero, layer=jnp.int32(layer)))
+        want = np.asarray(call(qw[layer], scale[layer], zero[layer]))
+        if qm.minor_swapped(qw.shape):
+            np.testing.assert_allclose(got[:rows], want[:rows], rtol=1e-5,
+                                       atol=1e-5)
+        else:
+            np.testing.assert_array_equal(got[:rows], want[:rows])
+
+
+@pytest.mark.parametrize("impl", ["interpret", "xla"])
+def test_layer_indexed_grouped_op_equals_per_layer_call(impl):
+    """``ops.grouped_quant_matmul`` with ``layer=``: bitwise the impl's call
+    on the layer's own arrays, with and without task ids."""
+    spec = QuantSpec(bits=4)
+    bm, sizes = 16, np.array([16, 32, 0, 16], np.int32)
+    rows = int(sizes.sum())
+    m = rows + bm
+    rng = np.random.default_rng(2)
+    x = jnp.asarray(rng.standard_normal((m, 1024)), jnp.float32)
+    tile_expert, used = _tiles(sizes, bm, m), jnp.int32(rows // bm)
+    for n_tasks in (0, 3):
+        qw, scale, zero = _expert_stacks(1024, spec, n_tasks, seed=n_tasks)
+        tids = jnp.asarray(rng.integers(0, 3, m), jnp.int32) \
+            if n_tasks else None
+        call = lambda *a, **kw: ops.grouped_quant_matmul(
+            x, *a, jnp.asarray(sizes), tile_expert, used, spec, block_m=bm,
+            task_ids=tids, impl=impl, **kw)
+        for layer in (0, 1, 2):
+            got = call(qw, scale, zero, layer=jnp.int32(layer))
+            want = call(qw[layer], scale[layer], zero[layer])
+            np.testing.assert_array_equal(np.asarray(got)[:rows],
+                                          np.asarray(want)[:rows])
