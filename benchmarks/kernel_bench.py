@@ -23,20 +23,12 @@
     GEMV; ``--check-sharded`` gates the 4-bit nibble ratio ≥ 3.2×, the
     3-bit BIT-PLANE ratio ≥ 4.2×, and plane-vs-nibble decode
     weight-bytes/token ≥ 1.25× (sub-4-bit finally pays in bytes).
-  * Speculative serving: self-speculative decode drafting through the
-    top-3 bit-planes of the 4-bit backbone (zero extra weight memory),
-    verified ``spec_k`` tokens per target step — gates token-for-token
-    equality with greedy and ≥ 1.3× fewer target steps; acceptance rate
-    and tokens/target-step are trajectory-guarded.
   * Mixed-task serving: 3 tasks round-robin through ``Engine.serve``
     under both schedulers; gates token-for-token equality, ZERO
     task-drain idle slot-steps under ``resident`` (>0 under ``drain``),
     and ≥ 1.2× fewer decode steps — all deterministic counters, so a
     noisy runner cannot flake the build.  Wall-clock tokens/s is
     reported unguarded.
-  * Sharded speculative: the same draft/verify loop through the mesh
-    (logitshard sampling, per-shard scale layout) — token equality with
-    greedy and the ≥ 1.3× target-step ratio must survive sharding.
   * Family serving: one tiny arch per served family (dense, encdec, vlm,
     ssm, hybrid) through the SAME continuous-batching slot pool — gates
     token-for-token equality with lockstep and zero bubble slot-steps
@@ -99,7 +91,7 @@ def emit_json(outdir: str):
     import os
     os.makedirs(outdir, exist_ok=True)
     serving_keys = ("sharded", "logitshard", "continuous", "mixed_task",
-                    "speculative", "serving")
+                    "serving")
     rows = SINK.metrics
     kern = [m for m in rows if not any(k in m["name"] for k in serving_keys)]
     serv = [m for m in rows if any(k in m["name"] for k in serving_keys)]
@@ -676,151 +668,6 @@ def mixed_task_serving(report, check: bool = False) -> bool:
     return ok
 
 
-def speculative_serving(report, check: bool = False) -> bool:
-    """Self-speculative decode from the bit-plane prefix vs plain greedy.
-
-    A 4-bit plane backbone drafts through its own top-3 planes (zero extra
-    weight memory — the draft IS a prefix read of the target buffer) and
-    verifies ``spec_k`` tokens per target step.  Deterministic gates
-    (check mode): token-for-token equality with greedy, and ≥ 1.3× fewer
-    TARGET steps at ``spec_k`` ≥ 2.  Acceptance rate and tokens emitted
-    per target step are trajectory-guarded (deterministic for the seeded
-    workload); wall tokens/s rides along unguarded.
-    """
-    from repro.serve import ServeConfig
-    from repro.train.serve import Engine, Request
-
-    cfg = configs.paper_lm(n_layers=1, d_model=64, n_heads=2, d_ff=96,
-                           vocab=128).replace(
-        tuning=TuningConfig(mode="peqa"),
-        quant=QuantConfig(bits=4, n_grid=2, layout="plane"))
-    api = registry.build(cfg)
-    rng = jax.random.PRNGKey(0)
-    p, _ = policies.prepare(api.init(rng), cfg, rng)
-    p = jax.tree.map(np.asarray, p)
-    vocab = cfg.vocab_size
-    mk = lambda: Engine(api, jax.tree.map(jnp.asarray, p))
-
-    reqs = [Request(tokens=(np.arange(6, dtype=np.int32) * (i + 1)) % vocab,
-                    n_new=(16, 24, 32)[i % 3]) for i in range(8)]
-    tokens_total = sum(r.n_new for r in reqs)
-
-    greedy = mk().serve(reqs, ServeConfig(n_slots=4, scheduler="auto"))
-    spec_cfg = ServeConfig(n_slots=4, scheduler="speculative", spec_k=2,
-                           draft_bits=3)
-    mk().serve(reqs, spec_cfg)                             # compile warmup
-    spec = mk().serve(reqs, spec_cfg)
-
-    ok = True
-    for i, (a, b) in enumerate(zip(greedy.tokens, spec.tokens)):
-        if a is None or a != b:
-            report("kernel/speculative", 0.0,
-                   f"FAIL req{i}: speculative tokens diverge from greedy")
-            ok = False
-            break
-    step_ratio = greedy.steps / max(spec.steps, 1)
-    if check and step_ratio < 1.3:
-        report("kernel/speculative", 0.0,
-               f"FAIL target-step ratio {step_ratio:.2f}x < 1.3x "
-               f"(greedy {greedy.steps} vs speculative {spec.steps})")
-        ok = False
-    acc = spec.acceptance_rate or 0.0
-    tok_per_step = spec.decoded / max(spec.steps, 1)
-
-    report("kernel/speculative", spec.wall_s * 1e6,
-           f"tok/s spec={tokens_total / spec.wall_s:.0f} "
-           f"greedy={tokens_total / greedy.wall_s:.0f} "
-           f"target_steps={spec.steps} vs {greedy.steps} "
-           f"({step_ratio:.2f}x) draft_steps={spec.draft_steps} "
-           f"acceptance={acc:.2f} tok/target_step={tok_per_step:.2f}")
-    metric("kernel/speculative", tokens_total / spec.wall_s, "tok/s",
-           wall=True, greedy_tok_s=tokens_total / greedy.wall_s,
-           spec_steps=spec.steps, greedy_steps=greedy.steps,
-           draft_steps=spec.draft_steps, spec_k=2, draft_bits=3)
-    metric("kernel/speculative_step_ratio", step_ratio, "x_vs_greedy",
-           guard=("higher", 0.15))
-    metric("kernel/speculative_acceptance", round(acc, 6), "frac",
-           guard=("higher", 0.2))
-    metric("kernel/speculative_tok_per_target_step", round(tok_per_step, 6),
-           "tok/step", guard=("higher", 0.15))
-    return ok
-
-
-def sharded_speculative(report, check: bool = False) -> bool:
-    """Speculative decode ON THE MESH: the bit-plane draft + multi-token
-    verify run under logitshard sampling on fake devices.
-
-    Same deterministic gates as the off-mesh speculative bench —
-    token-for-token equality with greedy and ≥ 1.3× fewer target steps —
-    but through the sharded decode path, so a draft/verify step that only
-    works replicated (e.g. one that regathers the vocab or breaks the
-    per-shard scale layout) fails here.  Model axis is 2: the tiny plane
-    config's scale-group extents (d_model/group = 2) bound the tensor
-    split.
-    """
-    from repro.dist import context as dctx
-    from repro.dist import sharding as shard_rules
-    from repro.serve import ServeConfig
-    from repro.train.serve import Engine, Request
-
-    n = jax.device_count()
-    if n < 2:
-        report("kernel/sharded_speculative", 0.0,
-               "skipped: 1 device (set XLA_FLAGS="
-               "--xla_force_host_platform_device_count=8)")
-        return not check
-    mesh = make_mesh((n // 2, 2), ("data", "model"))
-    ctx = dctx.make_ctx(mesh)
-
-    # d_ff=128 (not the off-mesh bench's 96): every quant-group extent must
-    # divide the model axis, and 96/32 = 3 groups does not split in 2
-    cfg = configs.paper_lm(n_layers=1, d_model=64, n_heads=2, d_ff=128,
-                           vocab=128).replace(
-        tuning=TuningConfig(mode="peqa"),
-        quant=QuantConfig(bits=4, n_grid=2, layout="plane"))
-    api = registry.build(cfg)
-    rng = jax.random.PRNGKey(0)
-    p, _ = policies.prepare(api.init(rng), cfg, rng)
-    p = jax.tree.map(np.asarray, p)
-    vocab = cfg.vocab_size
-    mk = lambda: Engine(
-        api, jax.device_put(p, shard_rules.named_shardings(ctx, p)),
-        ctx=ctx, logitshard=True)
-
-    reqs = [Request(tokens=(np.arange(6, dtype=np.int32) * (i + 1)) % vocab,
-                    n_new=(16, 24, 32)[i % 3]) for i in range(8)]
-    greedy = mk().serve(reqs, ServeConfig(n_slots=4, scheduler="auto"))
-    spec = mk().serve(reqs, ServeConfig(n_slots=4, scheduler="speculative",
-                                        spec_k=2, draft_bits=3))
-
-    ok = True
-    equal = all(a is not None and a == b
-                for a, b in zip(greedy.tokens, spec.tokens))
-    if not equal:
-        report("kernel/sharded_speculative", 0.0,
-               "FAIL sharded speculative tokens diverge from greedy")
-        ok = False
-    step_ratio = greedy.steps / max(spec.steps, 1)
-    if check and step_ratio < 1.3:
-        report("kernel/sharded_speculative", 0.0,
-               f"FAIL target-step ratio {step_ratio:.2f}x < 1.3x "
-               f"(greedy {greedy.steps} vs speculative {spec.steps})")
-        ok = False
-    acc = spec.acceptance_rate or 0.0
-    report("kernel/sharded_speculative", spec.wall_s * 1e6,
-           f"({n // 2}x2 mesh, logitshard) target_steps={spec.steps} vs "
-           f"{greedy.steps} ({step_ratio:.2f}x) "
-           f"draft_steps={spec.draft_steps} acceptance={acc:.2f} "
-           f"tokens==greedy: {equal}")
-    metric("serving/sharded_speculative_step_ratio", step_ratio,
-           "x_vs_greedy", guard=("higher", 0.15),
-           spec_steps=spec.steps, greedy_steps=greedy.steps,
-           draft_steps=spec.draft_steps, acceptance=round(acc, 6))
-    metric("serving/sharded_speculative_token_equality", int(equal), "bool",
-           guard=("higher", 0.0))
-    return ok
-
-
 # the continuous-batching smoke matrix: one arch per served family, each
 # with its slot-state protocol (dense KV pages, encdec cross-KV admitted
 # as position-free rows, vlm image prefix occupying decoder positions,
@@ -1180,8 +1027,6 @@ def run(report, traffic_kind: str = "poisson", seed: int = 0):
     sharded_serving(report)
     continuous_serving(report)
     mixed_task_serving(report)
-    speculative_serving(report)
-    sharded_speculative(report)
     family_serving(report)
     production_serving(report, traffic_kind=traffic_kind, seed=seed)
     scalebank_tiering(report, seed=seed)
@@ -1194,12 +1039,11 @@ if __name__ == "__main__":
     ap = argparse.ArgumentParser()
     ap.add_argument("--check-sharded", action="store_true",
                     help="run only the roofline + sharded + continuous + "
-                         "mixed-task + speculative serving benches; exit 1 "
+                         "mixed-task serving benches; exit 1 "
                          "on sharding problems / swap collectives / vocab "
                          "all-gathers / bubble steps / bytes-per-token "
                          "regression / task-drain idle under the resident "
-                         "scheduler / speculative-vs-greedy token mismatch "
-                         "or target-step ratio < 1.3x / tiered-bank init "
+                         "scheduler / tiered-bank init "
                          "payload bytes != 0 or tiered-vs-eager token "
                          "mismatch (the serve-smoke CI gate)")
     ap.add_argument("--emit-json", metavar="DIR", default=None,
@@ -1220,8 +1064,6 @@ if __name__ == "__main__":
         passed = sharded_serving(_report, check=True) and passed
         passed = continuous_serving(_report, check=True) and passed
         passed = mixed_task_serving(_report, check=True) and passed
-        passed = speculative_serving(_report, check=True) and passed
-        passed = sharded_speculative(_report, check=True) and passed
         passed = family_serving(_report, check=True) and passed
         passed = production_serving(_report, check=True,
                                     traffic_kind=args.traffic,

@@ -65,8 +65,8 @@ Two storage layouts share the tile loop:
     first, 32 codes/word/plane; core.quant.pack_codes_planes).  A b-bit
     tensor streams exactly b bits/weight, and a ``spec.bits = p`` view of
     a wider buffer loads only the top-p planes (the BlockSpec's plane axis
-    is a prefix slice) — the zero-copy low-bit DRAFT behind
-    self-speculative decoding.
+    is a prefix slice) — a zero-copy view of the top ``spec.bits`` planes
+    through the same BlockSpec that reads a full buffer.
 """
 from __future__ import annotations
 
@@ -359,7 +359,7 @@ def quant_gemv_pallas(
 
     Plane layout (``spec.plane``): ``qw`` is (bits', N, K/32) and only the
     top ``spec.bits`` planes are streamed — with ``bits' > spec.bits`` this
-    is the draft decode reading a prefix of the target's buffer.
+    reads a plane prefix of a wider buffer.
 
     Layer-stacked (``layer`` given): ``qw`` is (L, N, K/8) or
     (L, bits', N, K/32), ``scale``/``zero`` (L, N, G) or (L, T, N, G), and

@@ -20,22 +20,17 @@ all-gather in the loop.  On a CPU-only box, fake the devices first:
 engine: an arrival-simulating driver builds a mixed-length, mixed-task
 request stream (staggered arrivals on the decode-step clock) and pushes it
 through ``Engine.serve`` — paged KV slots, mid-loop admit/evict, per-slot
-positions.  ``--scheduler`` picks the mixed-task policy (default ``auto``
-→ ``resident``: stacked per-task scales stay device-resident and decode
-gathers each slot's row in-kernel, no drain-before-switch).  It exits
+positions.  ``--scheduler`` picks the mixed-task policy: ``resident``
+(stacked per-task scales stay device-resident and decode gathers each
+slot's row in-kernel, no drain-before-switch), ``drain`` (the pool drains
+before each scale swap) or ``auto`` (the default: ``resident`` where the
+workload allows it, else ``drain``).  It exits
 non-zero if any request is dropped, any bubble step is observed (a
 finished sequence occupying a decode step), or the resident scheduler
 idles a single slot-step on task drain, so CI can run it as a smoke gate:
 
     REPRO_FAKE_DEVICES=8 PYTHONPATH=src python -m repro.launch.serve \
         --tiny --mesh 2,4 --continuous
-
-``--scheduler speculative`` (requires ``--layout plane``) self-speculates:
-each round drafts ``--spec-k`` tokens through the ``--draft-bits`` bit-plane
-prefix of the SAME weight buffer and verifies them in one target step.  The
-launcher then replays the identical stream through the greedy scheduler and
-exits non-zero on any token mismatch — the speculative path must be
-token-for-token exact, just faster.
 """
 from __future__ import annotations
 
@@ -47,7 +42,6 @@ if os.environ.get("REPRO_FAKE_DEVICES"):
     backend.fake_host_devices(os.environ["REPRO_FAKE_DEVICES"])
 
 import argparse
-import dataclasses
 import time
 
 import jax
@@ -171,7 +165,6 @@ def run_continuous(engine, cfg, args, tasks):
             n_new=(max(2, args.n_new // 2), args.n_new, 2 * args.n_new))
         print(f"[serve] traffic: {meta}")
     config = ServeConfig(n_slots=args.batch, scheduler=args.scheduler,
-                         spec_k=args.spec_k, draft_bits=args.draft_bits,
                          prefetch_depth=args.prefetch_depth,
                          host_cache_tasks=args.host_cache or None,
                          disk_load_s=args.disk_load_s,
@@ -211,25 +204,6 @@ def run_continuous(engine, cfg, args, tasks):
         print(f"[serve] FAIL: resident scheduler idled "
               f"{rep.task_drain_idle_slot_steps} slot-steps on task drain")
         ok = False
-    if rep.scheduler == "speculative":
-        # replay the exact stream through the greedy scheduler: speculative
-        # decoding must be token-for-token identical (the draft only picks
-        # WHICH tokens get verified) and spend fewer target steps
-        greedy = engine.serve(
-            reqs, dataclasses.replace(config, scheduler="auto"))
-        if rep.tokens != greedy.tokens:
-            print("[serve] FAIL: speculative tokens diverge from greedy")
-            ok = False
-        elif rep.steps >= greedy.steps:
-            print(f"[serve] FAIL: speculative spent {rep.steps} target "
-                  f"steps vs greedy {greedy.steps}")
-            ok = False
-        else:
-            print(f"[serve] speculative == greedy over {greedy.decoded} "
-                  f"tokens: target steps {rep.steps} vs {greedy.steps} "
-                  f"({greedy.steps / rep.steps:.2f}x), "
-                  f"acceptance={rep.acceptance_rate:.2f} "
-                  f"draft_steps={rep.draft_steps}")
     print(f"[serve] continuous {'OK' if ok else 'FAILED'}")
     return ok
 
@@ -242,9 +216,7 @@ def main():
     ap.add_argument("--bits", type=int, default=4)
     ap.add_argument("--layout", default="nibble", choices=("nibble", "plane"),
                     help="code packing: 'nibble' is 8 codes/uint32; 'plane' "
-                         "stores b bit-planes so a lower-bit draft is a "
-                         "buffer-prefix read (required for --scheduler "
-                         "speculative)")
+                         "stores b bit-planes (any bit width packs densely)")
     ap.add_argument("--tasks", default="taskA,taskB")
     ap.add_argument("--tune-steps", type=int, default=100)
     ap.add_argument("--n-new", type=int, default=16)
@@ -275,17 +247,13 @@ def main():
     ap.add_argument("--trace", default="",
                     help="trace traffic: JSON trace file to replay")
     ap.add_argument("--scheduler", default="auto",
-                    choices=("auto", "resident", "drain", "speculative"),
+                    choices=("auto", "resident", "drain"),
                     help="mixed-task policy for --continuous: 'resident' "
                          "keeps stacked per-task scales device-resident "
                          "and decodes mixed-task slots drain-free via the "
                          "in-kernel row gather; 'drain' waits the pool "
                          "out before each scale swap; 'auto' picks "
-                         "resident when supported; 'speculative' drafts "
-                         "--spec-k tokens from the --draft-bits bit-plane "
-                         "prefix and verifies them in one target step "
-                         "(token-identical to greedy; the launcher replays "
-                         "the stream greedily and fails on any mismatch)")
+                         "resident when supported")
     ap.add_argument("--family-smoke", action="store_true",
                     help="skip tuning and serve an untasked mixed-length "
                          "stream through the continuous engine for THIS "
@@ -293,11 +261,6 @@ def main():
                          "synthesized, SSM prompts chunk-aligned); exits 1 "
                          "if any request's tokens diverge from lockstep "
                          "generate or any bubble slot-step is observed")
-    ap.add_argument("--spec-k", type=int, default=2,
-                    help="speculative: draft tokens proposed per round")
-    ap.add_argument("--draft-bits", type=int, default=None,
-                    help="speculative: draft plane-prefix width "
-                         "(default bits-1)")
     ap.add_argument("--bank-root", default="",
                     help="persist tuned task scales as npz files here and "
                          "serve through the TIERED bank: the serving bank "

@@ -160,7 +160,7 @@ def apply_decode(p: dict, x: jax.Array, cfg: ModelConfig, cache: dict,
     and, for the int8 KV cache (kv_cache_dtype='int8', §Perf), k_scale,
     v_scale: f16 (L, B, C, Hkv).  pos scalar i32 or a (B,) per-slot
     position vector (continuous batching: every batch row decodes at its
-    own depth).  S > 1 is the speculative verify step — row b's tokens land
+    own depth).  S > 1 writes S rows per slot — row b's tokens land
     at positions pos[b]..pos[b]+S-1 and the causal mask (key slot j visible
     iff j ≤ query position) keeps any stale cache rows beyond the written
     range invisible.

@@ -105,8 +105,8 @@ def apply_rope_slots(x: jax.Array, positions: jax.Array,
     step of ``train/serve.py`` — sequences admitted at different times are
     at different depths).  ``apply_rope`` cannot express this: its
     ``positions`` index the sequence dim, shared across the batch.
-    Token s of row b is at absolute position ``positions[b] + s`` (S > 1 is
-    the speculative verify step: k+1 consecutive tokens per slot).
+    Token s of row b is at absolute position ``positions[b] + s`` (S > 1:
+    S consecutive tokens per slot, each at its own position).
     """
     pos = jnp.asarray(positions, jnp.float32)
     pos = pos[:, None] + jnp.arange(x.shape[1], dtype=jnp.float32)[None, :]

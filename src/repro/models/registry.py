@@ -57,8 +57,6 @@ class FamilyCaps:
         resident scheduler's refusal message); None = supported.
       * ``mesh_slotted_reason`` — why the slotted steps, present, cannot
         run under a mesh (the refusal message there); None = they can.
-      * ``verify_reason`` — why ``decode_verify`` is unusable (the
-        speculative scheduler's refusal message); None = supported.
     """
     positional: bool = True
     prefix_key: Optional[str] = None
@@ -67,7 +65,6 @@ class FamilyCaps:
     bucketable: bool = False
     slotted_reason: Optional[str] = None
     mesh_slotted_reason: Optional[str] = None
-    verify_reason: Optional[str] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -87,12 +84,6 @@ class ModelAPI:
     # reading per-row scales from the resident stack (no live-scale swap at
     # admit); gated exactly like decode_step_slotted
     prefill_slotted: Optional[Callable] = None
-    # (params, cache, tokens (B, S), pos (B,)) -> (logits (B, S, V), cache):
-    # score S tokens in one pass for speculative verify; None for families
-    # without a multi-token KV-cache decode path (SSM, hybrid, encdec)
-    decode_verify: Optional[Callable] = None
-    # slotted variant (+ task_stack, task_ids); gated like decode_step_slotted
-    decode_verify_slotted: Optional[Callable] = None
     # what the serving engine may assume about this family's decode state;
     # ``build`` always sets it — None only on hand-rolled stand-ins, which
     # the slot pool refuses
@@ -135,7 +126,6 @@ def input_specs(cfg: ModelConfig, shape: ShapeConfig) -> dict:
     return batch
 
 
-_NO_VERIFY = "family has no multi-token verify step (decode_verify)"
 _NO_SLOTTED = ("recurrent state layers cannot thread per-slot scales "
                "(no slotted decode step)")
 
@@ -154,9 +144,7 @@ def build(cfg: ModelConfig) -> ModelAPI:
             prefix_key="image_embeds" if fam == "vlm" else None,
             prefix_positions=fam == "vlm",
             mesh_slotted_reason=("MoE experts read per-slot task scales "
-                                 "off the mesh only") if moe_cfg else None,
-            verify_reason=("MoE expert dispatch is not supported in the "
-                           "verify step") if moe_cfg else None)
+                                 "off the mesh only") if moe_cfg else None)
 
         def slotted_step(p, st, c, t, pos, tid):
             # a MoE step also returns its routing stats, which the engine
@@ -180,12 +168,6 @@ def build(cfg: ModelConfig) -> ModelAPI:
                     prefix_embeds=b.get("image_embeds"),
                     last_pos=b.get("last_pos"),
                     task_stack=st, task_ids=tid)),
-            decode_verify=_scoped(
-                cfg, lambda p, c, t, pos: transformer.decode_verify(
-                    p, c, t, pos, cfg)),
-            decode_verify_slotted=_scoped(
-                cfg, lambda p, st, c, t, pos, tid: transformer.decode_verify(
-                    p, c, t, pos, cfg, task_stack=st, task_ids=tid)),
             caps=caps,
         )
     if fam == "hybrid":
@@ -197,8 +179,7 @@ def build(cfg: ModelConfig) -> ModelAPI:
             decode_step=lambda p, c, t, pos: zamba2.decode_step(p, c, t, pos, cfg),
             init_cache=lambda b, s: zamba2.init_cache(cfg, b, s),
             caps=FamilyCaps(positional=True, bucketable=False,
-                            slotted_reason=_NO_SLOTTED,
-                            verify_reason=_NO_VERIFY),
+                            slotted_reason=_NO_SLOTTED),
         )
     if fam == "ssm":
         return ModelAPI(
@@ -209,8 +190,7 @@ def build(cfg: ModelConfig) -> ModelAPI:
             decode_step=lambda p, c, t, pos: xlstm.decode_step(p, c, t, pos, cfg),
             init_cache=lambda b, s: xlstm.init_cache(cfg, b, s),
             caps=FamilyCaps(positional=False, bucketable=False,
-                            slotted_reason=_NO_SLOTTED,
-                            verify_reason=_NO_VERIFY),
+                            slotted_reason=_NO_SLOTTED),
         )
     if fam == "encdec":
         return ModelAPI(
@@ -226,7 +206,6 @@ def build(cfg: ModelConfig) -> ModelAPI:
                             prefix_key="frames", prefix_required=True,
                             prefix_positions=False,
                             slotted_reason=("encoder-decoder backbone has "
-                                            "no slotted decode step"),
-                            verify_reason=_NO_VERIFY),
+                                            "no slotted decode step")),
         )
     raise ValueError(f"unknown family {fam}")

@@ -346,22 +346,3 @@ def decode_step(params: dict, cache: dict, tokens: jax.Array, pos: jax.Array,
                                          task_stack, task_ids)
     return logits[:, 0], new_cache
 
-
-def decode_verify(params: dict, cache: dict, tokens: jax.Array,
-                  pos: jax.Array, cfg: ModelConfig,
-                  task_stack: dict | None = None,
-                  task_ids: jax.Array | None = None):
-    """Speculative verify: score S = k+1 tokens in ONE target pass.
-
-    tokens (B, S) = [next-input, draft_1..draft_k]; row b's token s sits at
-    absolute position pos[b] + s, writing cache rows pos[b]..pos[b]+S-1
-    (the draft's provisional rows are overwritten with target K/V).  Row s
-    of the returned logits is the target's next-token distribution AFTER
-    consuming tokens[:, :s+1] — greedy-argmax it against draft_{s+1} to find
-    the longest accepted prefix.  Stale cache rows beyond the accepted
-    prefix are never visible: the causal mask keys on absolute position.
-
-    Returns (logits (B, S, V) f32, new_cache).
-    """
-    return decode_tokens(params, cache, tokens, pos, cfg, task_stack,
-                          task_ids)[:2]

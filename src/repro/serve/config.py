@@ -12,7 +12,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional
 
-SCHEDULERS = ("auto", "resident", "drain", "speculative")
+SCHEDULERS = ("auto", "resident", "drain")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -25,18 +25,7 @@ class ServeConfig:
         longest request (prompt + budget).
 
     Mixed-task policy (``scheduler``): ``"drain"`` | ``"resident"`` |
-    ``"auto"`` | ``"speculative"`` — semantics in ``Engine.serve``'s
-    docstring.  ``"speculative"`` decodes each pool step as a
-    self-speculative round: ``spec_k`` draft tokens from the
-    ``draft_bits``-bit prefix of the bit-plane-packed backbone (same
-    weights, fewer planes), then ONE multi-token target verify; tasked
-    traffic runs resident (drain otherwise), exactly like ``"auto"``.
-
-    Speculative knobs (used only by ``scheduler="speculative"``):
-      * ``spec_k`` — draft tokens proposed per verify step (≥ 1).
-      * ``draft_bits`` — how many bit-planes the draft reads; ``None`` =
-        target bits − 1.  Must be < the backbone's quant bits, and the
-        backbone must use ``QuantConfig(layout="plane")``.
+    ``"auto"`` — semantics in ``Engine.serve``'s docstring.
 
     Admission control (overload degrades gracefully instead of queueing
     unboundedly — every outcome is accounted in ``ServeReport``):
@@ -80,8 +69,6 @@ class ServeConfig:
     shed_after_s: Optional[float] = None
     step_s: float = 1.0
     prefill_s: Optional[float] = None
-    spec_k: int = 2
-    draft_bits: Optional[int] = None
     prefetch_depth: int = 2
     host_cache_tasks: Optional[int] = None
     disk_load_s: float = 0.0
@@ -114,11 +101,6 @@ class ServeConfig:
             raise ValueError(f"step_s={self.step_s} must be > 0")
         if self.prefill_s is not None and self.prefill_s < 0:
             raise ValueError(f"prefill_s={self.prefill_s} must be >= 0")
-        if self.spec_k < 1:
-            raise ValueError(f"spec_k={self.spec_k} must be >= 1")
-        if self.draft_bits is not None and self.draft_bits < 1:
-            raise ValueError(
-                f"draft_bits={self.draft_bits} must be >= 1")
         if self.prefetch_depth < 0:
             raise ValueError(
                 f"prefetch_depth={self.prefetch_depth} must be >= 0")

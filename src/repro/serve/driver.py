@@ -62,12 +62,6 @@ def summarize(report: ServeReport, wall_s: Optional[float] = None) -> Dict:
         "task_drain_idle_slot_steps": report.task_drain_idle_slot_steps,
         "switches": report.switches,
         "peak_queue_depth": report.peak_queue_depth,
-        "draft_steps": report.draft_steps,
-        "draft_proposed": report.draft_proposed,
-        "draft_accepted": report.draft_accepted,
-        "acceptance_rate": report.acceptance_rate,
-        "tok_per_target_step": (report.decoded / report.steps
-                                if report.steps else 0.0),
         "tier_device_hits": report.tier_device_hits,
         "tier_host_hits": report.tier_host_hits,
         "tier_disk_loads": report.tier_disk_loads,
@@ -101,17 +95,6 @@ def log_summary(sink: MetricSink, summary: Dict, *,
                 continue
             sink.log(f"{base}_{short}_{pname}", round(val, 9), "s",
                      guard=("lower", SLO_GUARD_BAND))
-    if summary["draft_steps"]:
-        # speculative decode: acceptance is a model/traffic property
-        # (deterministic for a seeded workload) — guarded; tokens emitted
-        # per TARGET step is the speedup the draft buys
-        sink.log(f"{base}_acceptance_rate",
-                 round(summary["acceptance_rate"], 6), "frac",
-                 guard=("higher", SLO_GUARD_BAND))
-        sink.log(f"{base}_tok_per_target_step",
-                 round(summary["tok_per_target_step"], 6), "tok/step",
-                 guard=("higher", SLO_GUARD_BAND))
-        sink.log(f"{base}_draft_steps", summary["draft_steps"], "steps")
     tier_total = (summary["tier_device_hits"] + summary["tier_host_hits"]
                   + summary["tier_disk_loads"])
     if tier_total:

@@ -42,10 +42,6 @@ class RequestMetrics:
     n_prompt: int = 0
     n_budget: int = 0                  # requested n_new
     tokens: Optional[List[int]] = None  # generated tokens (served only)
-    # speculative decoding (scheduler="speculative"; 0 otherwise): draft
-    # tokens this request's slot proposed / the target verify accepted
-    draft_proposed: int = 0
-    draft_accepted: int = 0
     # tiered ScaleBank (tasked requests through a bank; None otherwise):
     # which tier held the task's scales when the request reached the head
     # of the queue — "device" (resident row, zero swap bytes), "host"
@@ -89,13 +85,6 @@ class RequestMetrics:
         if self.finish_s is None:
             return None
         return self.finish_s - self.arrival_s
-
-    @property
-    def acceptance_rate(self) -> Optional[float]:
-        """Fraction of proposed draft tokens the target verify accepted."""
-        if self.draft_proposed == 0:
-            return None
-        return self.draft_accepted / self.draft_proposed
 
 
 # the SLO dimensions ``slo_summary`` aggregates, in glossary order
@@ -142,10 +131,6 @@ class ServeReport:
     # idle slot-steps attributable to task incompatibility alone (the cost
     # the resident scheduler exists to delete; 0 under ``resident``)
     task_drain_idle_slot_steps: int = 0
-    # speculative: draft decode steps the pool ran (spec_k per round);
-    # ``steps`` counts TARGET steps (one verify per round), so
-    # decoded / steps is the accepted-tokens-per-target-step headline
-    draft_steps: int = 0
     resident_installs: int = 0         # stack rows (re)installed this serve
     # tiered ScaleBank: per-admitted-request tier of the task's scales at
     # the head of the queue (see RequestMetrics.scale_tier), prefetcher
@@ -182,20 +167,6 @@ class ServeReport:
     @property
     def n_shed(self) -> int:
         return sum(m.status == SHED for m in self.requests)
-
-    @property
-    def draft_proposed(self) -> int:
-        return sum(m.draft_proposed for m in self.requests)
-
-    @property
-    def draft_accepted(self) -> int:
-        return sum(m.draft_accepted for m in self.requests)
-
-    @property
-    def acceptance_rate(self) -> Optional[float]:
-        """Aggregate accepted/proposed draft tokens (None off speculative)."""
-        prop = self.draft_proposed
-        return None if prop == 0 else self.draft_accepted / prop
 
     @property
     def swap_wait_total_s(self) -> float:

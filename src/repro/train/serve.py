@@ -18,12 +18,13 @@ Two serving modes:
     ONE compiled shape (n_slots) with a per-slot position VECTOR, and
     finished sequences are evicted mid-loop so their slot is refilled on
     the next step.  ``serve`` is the scheduler: arrival-ordered admission,
-    EOS/length eviction, and one of two mixed-task policies — ``drain``
-    (drain-before-switch, one live scale set) or ``resident`` (scales for
+    EOS/length eviction, and one of three schedulers — ``drain``
+    (drain-before-switch, one live scale set), ``resident`` (scales for
     the k hottest tasks stay device-resident stacked ``(T, out, G)``;
     decode gathers each slot's row in-kernel through
-    ``decode_step_slotted``, so admission never waits on a task mismatch).
-    Zero bubble steps, zero recompiles per traffic shape.
+    ``decode_step_slotted``, so admission never waits on a task mismatch)
+    or ``auto`` (``resident`` where the workload allows it, else
+    ``drain``).  Zero bubble steps, zero recompiles per traffic shape.
 
 Mesh mode: construct with a ``dist.context.MeshContext`` (params already
 homed on the mesh per ``dist.sharding.named_shardings``) and the engine
@@ -123,7 +124,6 @@ class SlotPool:
         self._dev = None
         # accounting (the benchmark's bubble/utilisation story)
         self.steps = 0                 # decode steps executed
-        self.draft_steps = 0           # speculative draft steps executed
         self.decoded = 0               # useful tokens decoded
         self.bubble_slot_steps = 0     # slot-steps spent on FINISHED seqs
         self.idle_slot_steps = 0       # inactive slot-steps while work waited
@@ -162,7 +162,6 @@ class Engine:
                                donate_argnums=(1,))
         self._decode_slotted = None
         self._prefill_slotted = None
-        self._spec_rounds = {}
         self._samplers = {}
         self._steppers = {}
         self._cache_inits = {}
@@ -327,187 +326,6 @@ class Engine:
             self._prefill_slotted = jax.jit(
                 self._shard_logits(self.api.prefill_slotted))
         return self._prefill_slotted
-
-    # ----------------------------------------------------- speculative decode
-    def _spec_supported(self) -> Optional[str]:
-        """None when the self-speculative scheduler can run, else the reason
-        it cannot.  The gates are exactly the assumptions the round's KV
-        bookkeeping rests on: a dense (non-ring) cache whose row index IS
-        the absolute position (stale rows past the accepted prefix stay
-        causally invisible and are rewritten before any query reaches
-        them), a full-precision KV store (re-quantizing accepted rows in
-        the batched verify would drift from the greedy trajectory), and a
-        bit-plane backbone (the draft is a prefix READ of the same codes —
-        zero extra weight memory)."""
-        cfg = self.api.cfg
-        caps = getattr(self.api, "caps", None)
-        if caps is not None and caps.verify_reason is not None:
-            return caps.verify_reason
-        if self.api.decode_verify is None:
-            return "family has no multi-token verify step (decode_verify)"
-        if getattr(cfg, "swa_window", None) is not None:
-            return ("sliding-window ring cache: rejected draft rows would "
-                    "alias committed slots")
-        if getattr(cfg, "kv_cache_dtype", "model") != "model":
-            return ("quantized KV cache: verify re-quantization drifts "
-                    "from the greedy trajectory")
-        if cfg.quant.layout != "plane":
-            return ("draft needs bit-plane packed codes "
-                    "(QuantConfig(layout='plane'))")
-        return None
-
-    def _resolve_draft_bits(self, cfg: ServeConfig) -> int:
-        bits = self.api.cfg.quant.bits
-        db = bits - 1 if cfg.draft_bits is None else int(cfg.draft_bits)
-        if not 1 <= db < bits:
-            raise ValueError(
-                f"draft_bits={db} must be in [1, {bits - 1}] for a "
-                f"{bits}-bit backbone (the draft reads a strict prefix of "
-                f"the bit-planes)")
-        return db
-
-    @staticmethod
-    def _draft_params(tree, f: float):
-        """Draft view of a quantized param tree: every PEQA linear's scale
-        is multiplied by ``f = 2**(b-p)`` and its zero divided (the p-bit
-        plane-prefix truncation satisfies q ≈ q_p · f, see
-        ``core.quant.draft_scales``).  The packed codes are SHARED by
-        reference — the draft costs no extra weight memory, and tracing
-        this inside the round's jit keeps even the rescaled scales fused
-        into the decode, never materialized as a second tree."""
-        if isinstance(tree, dict):
-            if "qw" in tree and "scale" in tree:
-                out = dict(tree)
-                out["scale"] = tree["scale"] * f
-                if "zero" in tree:
-                    out["zero"] = tree["zero"] / f
-                return out
-            return {k: Engine._draft_params(v, f) for k, v in tree.items()}
-        return tree
-
-    @staticmethod
-    def _draft_stack(tree, f: float):
-        """Same rescale for a ResidentStack tree (scale/zero leaves only)."""
-        if isinstance(tree, dict):
-            return {k: (v * f if k == "scale" else
-                        v / f if k == "zero" else Engine._draft_stack(v, f))
-                    for k, v in tree.items()}
-        return tree
-
-    def _spec_round_fn(self, spec_k: int, draft_bits: int, slotted: bool):
-        """Jitted speculative round: ``spec_k`` greedy draft steps through
-        the ``draft_bits``-bit plane prefix, then ONE target verify over
-        the k+1 tokens [next-input, d_1..d_k].
-
-        Cache discipline: draft step j writes PROVISIONAL draft K/V at row
-        pos+j and attends rows ≤ pos+j (committed target rows + its own
-        draft rows); the verify overwrites rows pos..pos+k with target
-        K/V.  After acceptance the host advances pos by a+1 ≤ k+1, so the
-        stale suffix rows sit ABOVE every live position and the causal
-        mask (keyed on absolute position) hides them until the next round
-        rewrites them.  Sampling is in-jit argmax — logits never leave the
-        step, so the round works identically under ``logitshard``.
-
-        Returns ``(g (B, k+1) i32, acc (B,) i32, cache)``: ``g`` row b =
-        the target's greedy tokens, ``acc`` = accepted draft count (the
-        host emits ``g[:acc+1]``).
-        """
-        key = (spec_k, draft_bits, slotted)
-        if key in self._spec_rounds:
-            return self._spec_rounds[key]
-        import dataclasses
-
-        from repro.models import registry as _registry
-        cfg = self.api.cfg
-        cfg_d = cfg.replace(
-            quant=dataclasses.replace(cfg.quant, bits=draft_bits))
-        api_d = _registry.build(cfg_d)
-        f = float(1 << (cfg.quant.bits - draft_bits))
-        draft = api_d.decode_step_slotted if slotted else api_d.decode_step
-        verify = (self.api.decode_verify_slotted if slotted
-                  else self.api.decode_verify)
-        ctx = self.ctx
-
-        def rnd(params, cache, tok, pos, act, stack=None, tid=None):
-            dparams = Engine._draft_params(params, f)
-            dstack = Engine._draft_stack(stack, f) if slotted else None
-            seq = [tok]
-            t = tok
-            for j in range(spec_k):
-                if slotted:
-                    lg, cache = draft(dparams, dstack, cache, t, pos + j, tid)
-                else:
-                    lg, cache = draft(dparams, cache, t, pos + j)
-                t = jnp.argmax(lg, axis=-1).astype(jnp.int32)[:, None]
-                seq.append(t)
-            seq = jnp.concatenate(seq, axis=1)            # (B, k+1)
-            if slotted:
-                logits, cache = verify(params, stack, cache, seq, pos, tid)
-            else:
-                logits, cache = verify(params, cache, seq, pos)
-            g = jnp.argmax(logits, axis=-1).astype(jnp.int32)   # (B, k+1)
-            g = jnp.where(act[:, None], g, 0)
-            match = (seq[:, 1:] == g[:, :-1]).astype(jnp.int32)
-            acc = jnp.cumprod(match, axis=1).sum(axis=1)
-            acc = jnp.where(act, acc, 0)
-            if ctx is not None:
-                cache = jax.tree.map(
-                    jax.lax.with_sharding_constraint, cache,
-                    self._cache_shardings(cache, tok.shape[0]))
-            return g, acc, cache
-
-        rnd = self._mesh_scoped(rnd)
-        if slotted:
-            fn = jax.jit(lambda p, st, c, tok, pos, act, tid:
-                         rnd(p, c, tok, pos, act, stack=st, tid=tid),
-                         donate_argnums=(2,))
-        else:
-            fn = jax.jit(rnd, donate_argnums=(1,))
-        self._spec_rounds[key] = fn
-        return fn
-
-    def spec_step(self, pool: SlotPool, spec_k: int,
-                  draft_bits: int) -> np.ndarray:
-        """One speculative round over the pool.  Every active slot proposes
-        ``spec_k`` draft tokens and commits 1..spec_k+1 target tokens
-        (capped by its remaining budget and EOS).  ``pool.steps`` counts
-        ONE target step per round; ``pool.draft_steps`` accrues the draft
-        work.  Returns the (n_slots, spec_k+1) greedy target tokens."""
-        if pool.n_active() == 0:
-            raise ValueError("spec_step: no active slot (admit first)")
-        tok, pos, act, tid = self._pool_inputs(pool)
-        fn = self._spec_round_fn(spec_k, draft_bits, pool.slotted)
-        if pool.slotted:
-            g, acc, pool.cache = fn(self.params, self.resident.stack,
-                                    pool.cache, tok, pos, act, tid)
-        else:
-            g, acc, pool.cache = fn(self.params, pool.cache, tok, pos, act)
-        g = np.asarray(g)
-        acc = np.asarray(acc)
-        pool.steps += 1
-        pool.draft_steps += spec_k
-        pool._dev = None          # per-slot advance is data-dependent
-        for slot in np.flatnonzero(pool.active):
-            meta = pool.meta[slot]
-            req = meta["request"]
-            out = meta["out"]
-            if self._slot_done(pool, slot):
-                pool.bubble_slot_steps += 1
-                continue
-            take = min(int(acc[slot]) + 1, int(req.n_new) - len(out))
-            toks = [int(x) for x in g[slot, :take]]
-            if req.eos_id is not None and req.eos_id in toks:
-                toks = toks[:toks.index(req.eos_id) + 1]
-                take = len(toks)
-            meta["draft_proposed"] = meta.get("draft_proposed", 0) + spec_k
-            meta["draft_accepted"] = (meta.get("draft_accepted", 0)
-                                      + int(acc[slot]))
-            out.extend(toks)
-            pool.pos[slot] += take
-            pool.tok[slot] = toks[-1]
-            pool.decoded += take
-        pool.idle_slot_steps += pool.n_slots - pool.n_active()
-        return g
 
     # ------------------------------------------------------------- task swap
     def switch_task(self, name: str) -> float:
@@ -995,15 +813,6 @@ class Engine:
             - ``"auto"`` — ``resident`` when supported (ScaleBank attached,
               family has a slotted decode step, every request tasked),
               ``drain`` otherwise.
-            - ``"speculative"`` — each pool step is a self-speculative
-              ROUND: ``config.spec_k`` draft tokens from the
-              ``config.draft_bits``-bit plane prefix of the shared packed
-              backbone, then one multi-token target verify
-              (``spec_step``).  Emitted tokens are token-for-token
-              identical to plain greedy; only the step count changes.
-              Task policy composes like ``"auto"`` (resident when
-              supported, drain otherwise).  Requires a bit-plane backbone
-              and a family with ``decode_verify`` (``_spec_supported``).
 
         Requesting ``"resident"`` on an unsupported workload raises;
         ``report.scheduler`` records which policy actually ran — including
@@ -1013,33 +822,16 @@ class Engine:
         cfg = self._serve_config(config, n_slots, cache_len, scheduler,
                                  resident_tasks)
         requests = list(requests)
-        use_spec = cfg.scheduler == "speculative"
-        if use_spec:
-            reason = self._spec_supported()
-            if reason is not None:
-                raise ValueError(
-                    f"scheduler='speculative' unsupported here: {reason}")
-            spec_bits = self._resolve_draft_bits(cfg)
         use_resident = (cfg.scheduler != "drain"
-                        and self._resident_supported(requests)
-                        and not (use_spec
-                                 and self.api.decode_verify_slotted is None))
+                        and self._resident_supported(requests))
         if cfg.scheduler == "resident" and not use_resident:
             missing = ("no ScaleBank attached" if self.bank is None
                        else self._slotted_reason()
                        or "not every request names a task")
             raise ValueError(f"scheduler='resident' unsupported here: "
                              f"{missing}")
-        sched_name = ("speculative" if use_spec
-                      else "resident" if use_resident else "drain")
+        sched_name = "resident" if use_resident else "drain"
         step_s, admit_cost = cfg.step_s, cfg.admit_cost_s
-        if use_spec:
-            # one round = spec_k draft steps + one verify.  A draft step's
-            # weight traffic is draft_bits/bits of a target step's (prefix
-            # read of the same planes), and the verify streams the weights
-            # once regardless of k — so on the virtual clock a round costs
-            round_s = step_s * (1.0 + cfg.spec_k * spec_bits
-                                / self.api.cfg.quant.bits)
         metrics = [RequestMetrics(rid=i, task=r.task,
                                   arrival_s=r.arrival_time(step_s),
                                   n_prompt=r.n_prompt,
@@ -1054,12 +846,6 @@ class Engine:
             eff_cache_len = max(
                 self._prefix_rows(getattr(r, "prefix", None))
                 + r.n_prompt + int(r.n_new) for r in requests)
-        if use_spec:
-            # rollback headroom: a round starting at the final needed
-            # position still writes spec_k provisional rows past it —
-            # without the margin the cache's DUS clamp would silently
-            # shift those writes onto committed rows
-            eff_cache_len += cfg.spec_k
         if use_resident:
             self._slotted_decode_fn()           # raise early if unsupported
             resident = self._ensure_resident(cfg.resident_tasks)
@@ -1114,8 +900,6 @@ class Engine:
         def finish_slot(slot: int) -> None:
             meta = pool.meta[slot]
             m = metrics[meta["rid"]]
-            m.draft_proposed = meta.get("draft_proposed", 0)
-            m.draft_accepted = meta.get("draft_accepted", 0)
             m.tokens = [int(t) for t in self.evict(pool, slot)]
             m.status = SERVED
             m.finish_s = now
@@ -1298,12 +1082,8 @@ class Engine:
                 now += k * step_s
                 continue
             n_act = pool.n_active()
-            if use_spec:
-                self.spec_step(pool, cfg.spec_k, spec_bits)
-                now += round_s
-            else:
-                self.step(pool)
-                now += step_s
+            self.step(pool)
+            now += step_s
             if blocked_by_task:
                 # the free slots this step could have hosted the blocked
                 # request — the drain tax the resident scheduler deletes
@@ -1317,7 +1097,6 @@ class Engine:
             idle_slot_steps=pool.idle_slot_steps,
             switches=switches, wall_s=time.perf_counter() - t0,
             task_drain_idle_slot_steps=pool.task_drain_idle_slot_steps,
-            draft_steps=pool.draft_steps,
             resident_installs=(resident.installs - installs0
                                if use_resident else 0),
             prefill_compiles=len(pool._prefill_keys),

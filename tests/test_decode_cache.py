@@ -30,7 +30,7 @@ from repro.configs.base import QuantConfig, TuningConfig
 from repro.core import policies
 from repro.core import scale_bank as sb
 from repro.kernels import ops
-from repro.models import attention, common, linear, registry
+from repro.models import attention, common, linear, registry, transformer
 
 B, C = 4, 16
 TOL = dict(rtol=2e-4, atol=2e-4)
@@ -40,7 +40,7 @@ CASES = {
     "scalar_pos": ({}, 6, 1, "step"),
     "slotted_pos": ({}, [0, 5, 9, 15], 1, "step"),
     "task_stack": ({}, [3, 0, 12, 7], 1, "slotted"),
-    "verify_s3": ({}, [0, 5, 9, 13], 3, "verify"),
+    "verify_s3": ({}, [0, 5, 9, 13], 3, "tokens"),
     "int8_kv": ({"kv_cache_dtype": "int8"}, [2, 7, 11, 4], 1, "step"),
     # capacity 6: every row has passed the ring's end at least once
     "swa_ring_wraps": ({"swa_window": 6}, [6, 13, 23, 8], 1, "step"),
@@ -152,8 +152,10 @@ def test_decode_writes_only_new_rows(case):
     if caller == "step":
         logits, out = jax.jit(api.decode_step)(params, pool, tokens, pos)
         logits = logits[:, None]
-    elif caller == "verify":
-        logits, out = jax.jit(api.decode_verify)(params, pool, tokens, pos)
+    elif caller == "tokens":
+        # the shared decode body at S = 3 tokens per slot
+        logits, out = jax.jit(lambda p, c, t, q: transformer.decode_tokens(
+            p, c, t, q, cfg)[:2])(params, pool, tokens, pos)
     else:
         stack = _task_stack(params)
         task_ids = jnp.asarray([2, 0, 1, 2], jnp.int32)

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import stale_rows_tokens
 
 from repro.core import scale_bank as sb
 from repro.kernels import ops
@@ -266,20 +267,36 @@ def test_dropless_routing_matches_per_token_loop(tiny, impl, skew):
 
 def test_registry_serves_mla_moe_on_the_resident_path(tiny):
     """The config reaches the resident scheduler: slotted prefill and decode
-    exist, only verify is refused, with its reason in one place."""
+    exist, and the slot pool holds the latent cache."""
     cfg, api, params = tiny[:3]
     assert api.caps.slotted_reason is None
     assert api.decode_step_slotted is not None
     assert api.prefill_slotted is not None
-    assert api.caps.verify_reason is not None
     eng = Engine(api, params)
-    assert eng._spec_supported() == api.caps.verify_reason
     pool = eng.open_pool(2, 16)
     assert set(pool.cache) == {"ckv", "kpe"}
     bdims, sdims = eng._cache_dims()
     assert bdims == {"ckv": 1, "kpe": 1} and sdims == {"ckv": 2, "kpe": 2}
     assert MOE_STEP_STATS[0] == "moe_layers"
     assert transformer.n_dense(cfg) == 1
+
+
+def test_latent_rows_past_pos_never_read(tiny):
+    """The latent pool (``ckv``, ``kpe``) under the resident pool: rows an
+    evicted request left past the new occupant's position, and every other
+    row at or past a slot's ``pos``, change no token when poisoned
+    (``conftest.stale_rows_tokens``)."""
+    cfg, api, params, ref, arch, root = tiny
+    bank = sb.ScaleBank()
+    for t in range(2):
+        bank.tasks[f"t{t}"] = _task_scales(params, ref, arch, root, t)[0]
+    eng = Engine(api, jax.tree.map(jnp.array, params), bank=bank)
+    eng.resident = sb.ResidentStack(bank, eng.params, 2)
+    pool = eng.open_pool(2, 24)
+    pool.slotted = True
+    assert set(pool.cache) == {"ckv", "kpe"}
+    clean, poisoned = stale_rows_tokens(eng, pool, ("t0", "t1"))
+    assert clean == poisoned
 
 
 def test_mesh_refusal_reads_the_caps(tiny):

@@ -13,7 +13,9 @@ The regression half pins the decode-path bugfix sweep:
     pick updated the wrong axis);
   * the masked sampler pins inactive slots to the pad token;
   * a (B,) per-slot position vector decodes bit-identically to the scalar
-    position it replaces.
+    position it replaces;
+  * cache rows past a slot's position are never read, on nibble and
+    bit-plane backbones, through the plain and the resident pool.
 """
 import types
 
@@ -21,26 +23,35 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import stale_rows_tokens
 
 from repro import configs
 from repro.configs.base import QuantConfig, TuningConfig
 from repro.core import policies
+from repro.core import scale_bank as sb
 from repro.dist import sampling
 from repro.models import registry
 from repro.serve import ServeConfig
 from repro.train.serve import Engine, Request
 
 
-def _make_engine(kv_cache_dtype="model"):
+def _make_engine(kv_cache_dtype="model", layout="nibble", bank=None):
     cfg = configs.paper_lm(n_layers=2, d_model=64, n_heads=2, d_ff=96,
                            vocab=128).replace(
         tuning=TuningConfig(mode="peqa"),
-        quant=QuantConfig(bits=4, n_grid=2),
+        quant=QuantConfig(bits=4, n_grid=2, layout=layout),
         kv_cache_dtype=kv_cache_dtype)
     api = registry.build(cfg)
     rng = jax.random.PRNGKey(0)
     p, _ = policies.prepare(api.init(rng), cfg, rng)
-    return Engine(api, jax.tree.map(jnp.array, p))
+    if bank is not None:
+        # task t0 is the backbone's scales, t1 a perturbation of them
+        bank.add("t0", p)
+        rs = np.random.default_rng(7)
+        bank.tasks["t1"] = {k: (v * rs.uniform(0.8, 1.2, v.shape)
+                                ).astype(v.dtype)
+                            for k, v in bank.tasks["t0"].items()}
+    return Engine(api, jax.tree.map(jnp.array, p), bank=bank)
 
 
 @pytest.fixture(scope="module")
@@ -201,3 +212,21 @@ def test_pool_rejects_capless_apis():
     eng = Engine(fake, {})
     with pytest.raises(NotImplementedError, match="capability record"):
         eng.open_pool(2, 8)
+
+
+@pytest.mark.parametrize("pool", ["plain", "resident"])
+@pytest.mark.parametrize("layout", ["nibble", "plane"])
+def test_stale_rows_past_pos_never_read(layout, pool):
+    """Rows an evicted request left past the new occupant's position, and
+    every other row at or past a slot's ``pos``, are dead state: poisoning
+    them changes no token (``conftest.stale_rows_tokens``)."""
+    if pool == "plain":
+        eng, tasks = _make_engine(layout=layout), (None, None)
+    else:
+        eng = _make_engine(layout=layout, bank=sb.ScaleBank())
+        eng.resident = sb.ResidentStack(eng.bank, eng.params, 2)
+        tasks = ("t0", "t1")
+    slots = eng.open_pool(2, 24)
+    slots.slotted = pool == "resident"
+    clean, poisoned = stale_rows_tokens(eng, slots, tasks)
+    assert clean == poisoned

@@ -90,8 +90,6 @@ def test_family_caps_registry_complete():
         assert caps is not None, arch
         if caps.slotted_reason is None:
             assert api.prefill_slotted is not None, arch
-        if caps.verify_reason is None:
-            assert api.decode_verify is not None, arch
         if caps.prefix_required:
             assert caps.prefix_key is not None, arch
 

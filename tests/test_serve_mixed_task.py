@@ -29,12 +29,10 @@ from repro.train.serve import Engine, Request
 TASKS = ("t0", "t1", "t2")
 
 
-@pytest.fixture(scope="module")
-def setup():
+def _setup(quant):
     cfg = configs.paper_lm(n_layers=2, d_model=64, n_heads=2, d_ff=96,
                            vocab=128).replace(
-        tuning=TuningConfig(mode="peqa"),
-        quant=QuantConfig(bits=4, n_grid=2))
+        tuning=TuningConfig(mode="peqa"), quant=quant)
     api = registry.build(cfg)
     rng = jax.random.PRNGKey(0)
     p, _ = policies.prepare(api.init(rng), cfg, rng)
@@ -49,9 +47,21 @@ def setup():
     return cfg, api, p, bank
 
 
-def _engine(setup):
+@pytest.fixture(scope="module")
+def setup():
+    return _setup(QuantConfig(bits=4, n_grid=2))
+
+
+@pytest.fixture(scope="module")
+def plane_setup():
+    """A 3-bit bit-plane backbone, the layout the tune cell runs."""
+    return _setup(QuantConfig(bits=3, n_grid=2, layout="plane"))
+
+
+def _engine(setup, with_bank=True):
     cfg, api, p, bank = setup
-    return Engine(api, jax.tree.map(jnp.asarray, p), bank=bank)
+    return Engine(api, jax.tree.map(jnp.asarray, p),
+                  bank=bank if with_bank else None)
 
 
 def _requests(cfg, n=9):
@@ -134,6 +144,33 @@ def test_explicit_resident_raises_when_unsupported(setup):
                      ServeConfig(n_slots=2, scheduler="resident"))
     with pytest.raises(ValueError, match="unknown scheduler"):
         ServeConfig(n_slots=2, scheduler="residnet")
+
+
+@pytest.mark.parametrize("case", ["untasked", "resident"])
+def test_plane_backbone_serves_exactly(plane_setup, case):
+    """A bit-plane backbone through ``Engine.serve``: untasked continuous
+    serving (``auto``, no bank) emits each request's lockstep ``generate``
+    tokens; tasked, the resident scheduler emits the drain scheduler's
+    tokens and never idles a slot on a task switch."""
+    cfg = plane_setup[0]
+    reqs = _requests(cfg)
+    if case == "untasked":
+        reqs = [Request(tokens=r.tokens, n_new=r.n_new) for r in reqs]
+        eng = _engine(plane_setup, with_bank=False)
+        rep = eng.serve(reqs, ServeConfig(n_slots=3, scheduler="auto"))
+        want = [list(np.asarray(eng.generate(
+            jnp.asarray(r.tokens)[None], n_new=r.n_new))[0, len(r.tokens):])
+            for r in reqs]
+    else:
+        rep = _engine(plane_setup).serve(
+            reqs, ServeConfig(n_slots=3, scheduler="resident"))
+        drain = _engine(plane_setup).serve(
+            reqs, ServeConfig(n_slots=3, scheduler="drain"))
+        assert rep.scheduler == "resident" and drain.scheduler == "drain"
+        assert rep.task_drain_idle_slot_steps == 0
+        want = drain.tokens
+    assert all(t is not None for t in rep.tokens)
+    assert rep.tokens == want
 
 
 def test_resident_stack_row_content(setup):

@@ -14,8 +14,8 @@ serving acceptance surface on a (2, 4) mesh:
   * end-to-end: mesh-engine greedy generation equals the host engine's.
 
 Further children cover Gumbel-max and nucleus (top-p) sampling — both
-bit-identical across mesh shapes and off-mesh — continuous batching on the
-mesh, and speculative decode through the sharded logitshard path.
+bit-identical across mesh shapes and off-mesh — and continuous batching on
+the mesh.
 """
 import subprocess
 import sys
@@ -375,61 +375,6 @@ def test_shard_top_p_reshard_invariant_subprocess():
                          capture_output=True, text=True, timeout=900,
                          env=subproc_env())
     assert "SUBPROC_TOPP_OK" in res.stdout, res.stderr[-3000:]
-
-
-_SPEC_SHARD_TEST = textwrap.dedent("""
-    import os
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    import jax, jax.numpy as jnp, numpy as np
-    from repro.launch.mesh import make_mesh
-    from repro import configs
-    from repro.configs.base import QuantConfig, TuningConfig
-    from repro.core import policies
-    from repro.dist import context as dctx
-    from repro.dist import sharding as shard_rules
-    from repro.models import registry
-    from repro.serve import ServeConfig
-    from repro.train.serve import Engine, Request
-
-    # model axis 2: the tiny plane config's quant-group extents
-    # (d_model/group = 2) bound the tensor split
-    mesh = make_mesh((4, 2), ("data", "model"))
-    ctx = dctx.make_ctx(mesh)
-    cfg = configs.paper_lm(n_layers=1, d_model=64, n_heads=2, d_ff=128,
-                           vocab=128).replace(
-        tuning=TuningConfig(mode="peqa"),
-        quant=QuantConfig(bits=4, n_grid=2, layout="plane"))
-    api = registry.build(cfg)
-    rng = jax.random.PRNGKey(0)
-    p, _ = policies.prepare(api.init(rng), cfg, rng)
-    p = jax.tree.map(np.asarray, p)
-    assert shard_rules.validate_for_mesh(p, mesh) == []
-    mk = lambda: Engine(
-        api, jax.device_put(p, shard_rules.named_shardings(ctx, p)),
-        ctx=ctx, logitshard=True)
-
-    reqs = [Request(tokens=(np.arange(6, dtype=np.int32) * (i + 1)) % 128,
-                    n_new=(16, 24, 32)[i % 3]) for i in range(8)]
-    greedy = mk().serve(reqs, ServeConfig(n_slots=4, scheduler="auto"))
-    spec = mk().serve(reqs, ServeConfig(n_slots=4, scheduler="speculative",
-                                        spec_k=2, draft_bits=3))
-    assert spec.scheduler == "speculative"
-    for i, (a, b) in enumerate(zip(greedy.tokens, spec.tokens)):
-        assert a is not None and a == b, f"req {i} diverges on the mesh"
-    assert spec.steps < greedy.steps, (spec.steps, greedy.steps)
-    assert (spec.acceptance_rate or 0.0) > 0.0
-    print("SUBPROC_SPEC_OK")
-""")
-
-
-def test_sharded_speculative_subprocess():
-    """Speculative decode through the sharded logitshard path: drafts and
-    multi-token verifies on a (4,2) mesh stay token-for-token equal to
-    greedy while spending fewer target steps."""
-    res = subprocess.run([sys.executable, "-c", _SPEC_SHARD_TEST],
-                         capture_output=True, text=True, timeout=900,
-                         env=subproc_env())
-    assert "SUBPROC_SPEC_OK" in res.stdout, res.stderr[-3000:]
 
 
 def test_continuous_serving_subprocess():

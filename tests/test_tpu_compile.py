@@ -227,8 +227,8 @@ def _mla_moe_cfg():
 # layer-stacked calls (the decode scan's): (kernel, m, n, k, bits,
 # layout, n_tasks, stored planes, layers).  K = 4608 (starcoder2's
 # q/k/v/o/up) and the plane-stored K = 14336 are stored words-major, K =
-# 18432 row-major (quant_matmul.minor_swapped); the verify step's B·S rows
-# take the GEMM
+# 18432 row-major (quant_matmul.minor_swapped); a decode of S > 1 tokens
+# per slot sends its B·S rows to the GEMM
 LAYER_CASES = {
     "gemv_layers_tasks_k4608": ("gemv", 32, 4608, 4608, 4, "nibble", 32, 0,
                                 3),
